@@ -11,11 +11,7 @@ Run:  python examples/advanced_models_example.py
 
 import numpy as np
 
-from spark_rapids_ml_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
-from spark_rapids_ml_tpu import (  # noqa: E402
+from spark_rapids_ml_tpu import (
     DBSCAN,
     CrossValidator,
     LinearRegression,
@@ -27,7 +23,7 @@ from spark_rapids_ml_tpu import (  # noqa: E402
     RegressionEvaluator,
     UMAP,
 )
-from spark_rapids_ml_tpu.data.frame import VectorFrame  # noqa: E402
+from spark_rapids_ml_tpu.data.frame import VectorFrame
 
 rng = np.random.default_rng(0)
 

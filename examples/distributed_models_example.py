@@ -11,10 +11,6 @@ python examples/distributed_models_example.py``
 
 import numpy as np
 
-from spark_rapids_ml_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
 
 def main() -> None:
     from spark_rapids_ml_tpu.parallel import (

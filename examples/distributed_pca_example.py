@@ -9,16 +9,11 @@ launch with a virtual 8-device CPU mesh:
       python examples/distributed_pca_example.py
 """
 
+import jax
 import numpy as np
 
-from spark_rapids_ml_tpu.utils.platform import force_cpu_if_requested
-
-force_cpu_if_requested()
-
-import jax  # noqa: E402
-
-from spark_rapids_ml_tpu.parallel.distributed_pca import distributed_pca_fit  # noqa: E402
-from spark_rapids_ml_tpu.parallel.mesh import data_mesh  # noqa: E402
+from spark_rapids_ml_tpu.parallel.distributed_pca import distributed_pca_fit
+from spark_rapids_ml_tpu.parallel.mesh import data_mesh
 
 mesh = data_mesh()
 print(f"devices: {jax.devices()}")

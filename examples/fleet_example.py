@@ -15,7 +15,9 @@ the fleet aggregator in THIS process:
    respawn the peer on the same host identity + port and watch the
    incident auto-resolve.
 
-CPU-safe: run with ``python examples/fleet_example.py``.
+CPU-only example: two serving peers run at once and a chip belongs to
+one process, so this process and its peers are pinned to
+``JAX_PLATFORMS=cpu``. Run with ``python examples/fleet_example.py``.
 """
 
 import json
@@ -32,7 +34,7 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 # fast cadences so the demo moves: 100 ms sweeps, 1-sweep incident
 # hysteresis (the shipping defaults are 1 s / 3 sweeps)
 os.environ["SPARK_RAPIDS_ML_TPU_OBS_SAMPLE_MS"] = "100"

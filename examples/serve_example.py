@@ -433,7 +433,7 @@ def main():
           f"{served_degraded} served DEGRADED from the CPU path "
           f"(bit-checked) — breaker {state()}")
 
-    plane.clear()                       # "the device tunnel recovers"
+    plane.clear()                       # "the device backend recovers"
     time.sleep(0.35)                    # wait out the cooldown
     r = engine2.predict_detailed("prod", x[:8])
     print(f"  fault cleared: half-open probe served degraded={r.degraded} "

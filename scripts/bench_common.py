@@ -1,28 +1,21 @@
-"""Shared helpers for the bench family: probe/log/record plumbing AND the
-single JSON-emission path.
+"""Shared helpers for the bench family: the status log and the single
+JSON-emission path.
 
-One copy of what bench_r04_once.py, bench_r04_wave2.py, and
-bench_r04_wave3.py previously each carried: the probe contract (exit 2 →
-wrapper retries) and the "capture bench.main() stdout → annotate last JSON
-line → write record" sequence. ``emit_record`` is the ONE way every bench
-(bench.py, bench_scale.py, bench_gram_sweep.py, the wave scripts) emits its
-final JSON line — it stamps the record and embeds a metrics-registry
-snapshot, so per-fit collective/phase accounting rides along with every
-bench number instead of each script hand-rolling ``json.dumps``.
+``emit_record`` is the ONE way every bench (bench.py, bench_scale.py,
+bench_gram_sweep.py, bench_serve.py, the harnesses) emits its final JSON
+line — it stamps the record and embeds a metrics-registry snapshot, so
+per-fit collective/phase accounting rides along with every bench number
+instead of each script hand-rolling ``json.dumps``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import datetime
-import io
 import json
 import os
 import sys
-import traceback
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-OUT = os.path.join(REPO, "records", "r04")
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
@@ -33,9 +26,10 @@ def stamp() -> str:
 
 
 def log(msg: str) -> None:
-    os.makedirs(OUT, exist_ok=True)
-    with open(os.path.join(OUT, "status.log"), "a") as f:
-        f.write(f"{msg}: {stamp()}\n")
+    """One status line on stderr — a bench run writes nothing into the
+    checkout (stdout stays the record channel)."""
+    sys.stderr.write(f"{msg}: {stamp()}\n")
+    sys.stderr.flush()
 
 
 def force_device_count_flags(n_devices: int, env: dict = None) -> str:
@@ -95,8 +89,8 @@ def required_platform() -> str | None:
 def enforce_required_platform(provenance: dict | None = None) -> dict:
     """Refuse to continue when the resolved backend is not the required
     one — a record measured on a silent CPU fallback is worse than no
-    record (the r04 lesson). Exit code 3 distinguishes the refusal from
-    a probe retry (2). Returns the provenance when the check passes."""
+    record. Exits with code 3. Returns the provenance when the check
+    passes."""
     want = required_platform()
     prov = provenance if provenance is not None else backend_provenance()
     if want is None:
@@ -128,8 +122,8 @@ def metrics_snapshot() -> dict:
 
 def emit_record(record: dict, *, stream=None, include_metrics: bool = True,
                 flush: bool = True) -> dict:
-    """Emit one bench record as a single JSON line (the LAST stdout line
-    contract run_bench_to_record parses). Stamps ``emitted_utc`` and embeds
+    """Emit one bench record as a single JSON line (the LAST stdout
+    line). Stamps ``emitted_utc`` and embeds
     the metrics-registry snapshot under ``"metrics"``. Returns the emitted
     dict. ``stream=None`` prints to stdout; pass an open file to append to
     a record file instead."""
@@ -170,87 +164,3 @@ def flight_dump(reason: str, **extra) -> str | None:
         return flight.dump(reason, extra=extra or None)
     except Exception:  # noqa: BLE001 - dumps must never break a bench
         return None
-
-
-def probe(tag: str):
-    """Claim the chip; return the device or None (caller exits 2 so the
-    wrapper loop retries). Forces the TPU backend — a silent CPU
-    fallback would burn the window measuring nothing. A failed probe
-    leaves a flight-recorder dump, not just a status-log line."""
-    os.environ.setdefault("JAX_PLATFORMS", "tpu")
-    log(f"{tag} probe start")
-    try:
-        import jax
-
-        device = jax.devices()[0]
-    except Exception as exc:  # noqa: BLE001
-        log(f"{tag} probe FAILED ({type(exc).__name__})")
-        flight_dump("bench_probe_failed", tag=tag,
-                    error=f"{type(exc).__name__}: {exc}")
-        return None
-    if device.platform == "cpu":
-        log(f"{tag} probe FAILED (cpu backend)")
-        flight_dump("bench_probe_cpu_fallback", tag=tag)
-        return None
-    want = required_platform()
-    if want is not None and device.platform.lower() != want:
-        log(f"{tag} probe FAILED (platform {device.platform} != "
-            f"required {want})")
-        flight_dump("bench_backend_mismatch", tag=tag, required=want,
-                    resolved=device.platform)
-        return None
-    log(f"{tag} probe ok")
-    return device
-
-
-def is_unavailable(exc: BaseException) -> bool:
-    """Chip-claim-lost errors (XLA UNAVAILABLE) — the caller should
-    abort and let the wrapper retry the whole window, NOT record the
-    failure as a per-step result."""
-    return "UNAVAILABLE" in f"{type(exc).__name__}: {exc}"
-
-
-def write_error(name: str, exc: BaseException) -> None:
-    with open(os.path.join(OUT, f"{name}.err"), "w") as f:
-        f.write(f"{type(exc).__name__}: {exc}\n")
-        f.write(traceback.format_exc())
-
-
-def run_bench_to_record(record_name: str, env: dict, annotate: dict,
-                        tag: str) -> bool:
-    """Run bench.main() under env overrides, annotate the final JSON
-    line, write records/r04/<record_name>. Returns success; raises
-    nothing (errors land in <record_name>.err). Chip-level UNAVAILABLE
-    re-raises so the caller can abort the window."""
-    import bench
-
-    saved = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    buf = io.StringIO()
-    try:
-        with contextlib.redirect_stdout(buf):
-            bench.main()
-    except Exception as exc:  # noqa: BLE001
-        if is_unavailable(exc):
-            raise
-        write_error(record_name.removesuffix(".json"), exc)
-        log(f"{tag} FAILED")
-        return False
-    finally:
-        for k, val in saved.items():
-            if val is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = val
-    lines = [ln for ln in buf.getvalue().splitlines() if ln.strip()]
-    try:
-        rec = json.loads(lines[-1])
-        rec.update(annotate)
-        rec["recorded_utc"] = stamp()
-        lines[-1] = json.dumps(rec)
-    except Exception:  # noqa: BLE001 - keep raw text on parse issues
-        pass
-    with open(os.path.join(OUT, record_name), "w") as f:
-        f.write("\n".join(lines) + "\n")
-    log(f"{tag} ok")
-    return True

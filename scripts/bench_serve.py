@@ -71,6 +71,13 @@ Scenarios (``SPARKML_BENCH_SERVE_SCENARIO``):
   measure true compute scaling. The modeled service time is stamped
   into the record so a baseline can never silently mix the two modes.
 
+CPU-only harness: the subprocess scenarios are simulations on forced
+host devices (``--xla_force_host_platform_device_count`` is honoured by
+the CPU backend only), so their children are pinned to
+``JAX_PLATFORMS=cpu`` — an inherited ``tpu`` would have every child
+fight the parent for the one chip. Nothing they print is a device
+number.
+
 Knobs (env): SPARKML_BENCH_SERVE_REQUESTS (default 512),
 SPARKML_BENCH_SERVE_FEATURES (64), SPARKML_BENCH_SERVE_K (16),
 SPARKML_BENCH_SERVE_THREADS (8), SPARKML_BENCH_SERVE_MAX_ROWS (512),
@@ -317,7 +324,7 @@ def scenario_coalesce() -> int:
     for mode, flag in (("concentrated", "1"), ("spread", "0")):
         env = dict(os.environ)
         env["SPARKML_BENCH_SERVE_SCENARIO"] = "_multidevice_child"
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = bench_common.force_device_count_flags(4)
         env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
         env["SPARK_RAPIDS_ML_TPU_SERVE_CONCENTRATE"] = flag
@@ -421,7 +428,7 @@ def scenario_coldstart() -> int:
         env["SPARKML_BENCH_COLDSTART_MODE"] = mode
         env["SPARKML_BENCH_COLDSTART_DIR"] = workdir
         env["SPARK_RAPIDS_ML_TPU_SERVE_MANIFEST"] = manifest
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         # a production-shaped bucket ladder (the finer steps the PR 9+
         # pipeline tier actually serves with) — the restart tax scales
         # with ladder size, which is exactly what the cache amortizes
@@ -644,7 +651,7 @@ def scenario_multidevice() -> int:
     for n in counts:
         env = dict(os.environ)
         env["SPARKML_BENCH_SERVE_SCENARIO"] = "_multidevice_child"
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = bench_common.force_device_count_flags(n)
         # the child replicates onto every device it sees
         env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
@@ -806,6 +813,15 @@ def main() -> int:
         return scenario_coalesce()
 
     import jax
+
+    if scenario != "_coldstart_child":
+        # (the cold-start scenario times the repo's own executable cache,
+        # obs/aotcache.py, against a compile from nothing)
+        from spark_rapids_ml_tpu.utils.platform import (
+            configure_compile_cache,
+        )
+
+        configure_compile_cache()
 
     if scenario == "pipeline":
         return scenario_pipeline(jax.devices()[0])

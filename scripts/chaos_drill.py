@@ -63,6 +63,11 @@ committed history:
   over the drill (opened counts everything the detectors saw,
   including cross-cutting ones like breaker flaps or SLO fast-burn).
 
+CPU-only harness: the replica_drain, canary_rollback and autoscale_flap
+phases run in subprocesses on forced host devices (CPU backend only),
+pinned to ``JAX_PLATFORMS=cpu`` — a chip belongs to one process, and a
+child that inherited ``tpu`` would collide with its parent.
+
 Knobs (env): SPARKML_CHAOS_REQUESTS (per phase, default 24),
 SPARKML_CHAOS_FEATURES (16), SPARKML_CHAOS_K (4).
 """
@@ -466,7 +471,7 @@ def run_autoscale_flap_phase() -> dict:
 
     env = dict(os.environ)
     env["SPARKML_CHAOS_PHASE"] = "autoscale_flap_child"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = bench_common.force_device_count_flags(4)
     env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
     proc = subprocess.run(
@@ -702,7 +707,7 @@ def run_canary_rollback_phase() -> dict:
 
     env = dict(os.environ)
     env["SPARKML_CHAOS_PHASE"] = "canary_rollback_child"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__)],
         env=env, capture_output=True, text=True, timeout=420,
@@ -726,7 +731,7 @@ def run_replica_drain_phase() -> dict:
 
     env = dict(os.environ)
     env["SPARKML_CHAOS_PHASE"] = "replica_drain_child"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = bench_common.force_device_count_flags(2)
     env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
     proc = subprocess.run(

@@ -45,6 +45,13 @@ Emits ONE ``bench_common.emit_record`` line the perf sentinel judges
 higher-is-better) — committed history lives in
 ``records/load_harness_r*.json``. Exit 0 = all gates pass.
 
+CPU-only harness: the device-scaling, ramp, accounting, fitmon, density
+and fleet phases spawn children on forced host devices
+(``--xla_force_host_platform_device_count``, CPU backend only) and the
+fleet phase runs two serving peers at once, so every child is pinned to
+``JAX_PLATFORMS=cpu`` — a chip belongs to one process. Nothing printed
+here is a device number.
+
 Knobs (env): SPARKML_LOAD_SOAK_SECONDS (60),
 SPARKML_LOAD_CALIBRATE_SECONDS (8), SPARKML_LOAD_FEATURES (32),
 SPARKML_LOAD_K (8), SPARKML_LOAD_GREEDY_THREADS (12),
@@ -322,7 +329,7 @@ def run_device_scaling_phase() -> dict:
     for n in (1, 2):
         env = dict(os.environ)
         env["SPARKML_LOAD_PHASE"] = "device_capacity_child"
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = bench_common.force_device_count_flags(n)
         env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
         bench_common.log(f"load_harness device scaling: child at "
@@ -524,7 +531,7 @@ def run_ramp_phase() -> int:
 
     env = dict(os.environ)
     env["SPARKML_LOAD_PHASE"] = "ramp_child"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = bench_common.force_device_count_flags(4)
     env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
     bench_common.log("load_harness ramp: child at 4 device(s), "
@@ -763,7 +770,7 @@ def run_accounting_phase() -> int:
 
     env = dict(os.environ)
     env["SPARKML_LOAD_PHASE"] = "accounting_child"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = bench_common.force_device_count_flags(2)
     env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
     bench_common.log("load_harness accounting: child at 2 device(s), "
@@ -1035,7 +1042,7 @@ def run_fitmon_phase() -> int:
     drift_bar = _env_float("SPARKML_LOAD_FITMON_DRIFT", 0.05)
     env = dict(os.environ)
     env["SPARKML_LOAD_PHASE"] = "fitmon_child"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = bench_common.force_device_count_flags(2)
     env["SPARK_RAPIDS_ML_TPU_OBS_SAMPLE_MS"] = "100"
     env["SPARK_RAPIDS_ML_TPU_FITMON_WATCHDOG_S"] = "0.2"
@@ -1380,7 +1387,7 @@ def run_density_phase() -> int:
             # label — the default 64-model fold would collapse the cold
             # tail into "(overflow)" and blind the eviction ranking
             env["SPARK_RAPIDS_ML_TPU_OBS_MODEL_MAX"] = "256"
-            env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"
             env["XLA_FLAGS"] = bench_common.force_device_count_flags(2)
             env.pop("SPARK_RAPIDS_ML_TPU_SERVE_REPLICAS", None)
             bench_common.log(
@@ -1568,7 +1575,7 @@ def run_fleet_phase() -> int:
     def spawn(host: str) -> None:
         env = dict(os.environ)
         env["SPARKML_LOAD_PHASE"] = "fleet_child"
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         env["SPARKML_LOAD_FLEET_PORT"] = str(ports[host])
         env["SPARK_RAPIDS_ML_TPU_FLEET_HOST"] = host
         procs[host] = subprocess.Popen(

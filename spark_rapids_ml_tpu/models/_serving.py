@@ -179,7 +179,6 @@ def build_serving_program(
     Raises ``ValueError`` for an unknown precision.
     """
     import jax
-    import jax.numpy as jnp
 
     from spark_rapids_ml_tpu.obs.serving import ServingProgram
 
@@ -191,7 +190,7 @@ def build_serving_program(
         )
 
     def put(matrix):
-        return jax.device_put(jnp.asarray(matrix, dtype=dtype), device)
+        return jax.device_put(np.asarray(matrix, dtype=dtype), device)
 
     def run(x_dev):
         return kernel(x_dev, *weights)
@@ -282,7 +281,6 @@ def build_fused_pipeline_program(
     re-stages from host rows).
     """
     import jax
-    import jax.numpy as jnp
 
     from spark_rapids_ml_tpu.obs.serving import ServingProgram
     from spark_rapids_ml_tpu.obs.xprof import tracked_jit
@@ -307,7 +305,7 @@ def build_fused_pipeline_program(
     )
 
     def put(matrix):
-        return jax.device_put(jnp.asarray(matrix, dtype=dtype), device)
+        return jax.device_put(np.asarray(matrix, dtype=dtype), device)
 
     def run(x_dev):
         return kernel(x_dev, *flat_weights)
@@ -373,7 +371,6 @@ def build_batch_sharded_program(
     if len(devices) < 2:
         return None
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_rapids_ml_tpu.obs.serving import ServingProgram
@@ -429,7 +426,7 @@ def build_batch_sharded_program(
     def put(matrix):
         # the host rows scatter straight into per-device shards — the
         # one host→device transfer a sharded request pays
-        return jax.device_put(jnp.asarray(matrix, dtype=dtype),
+        return jax.device_put(np.asarray(matrix, dtype=dtype),
                               row_sharded)
 
     def run(x_dev):

@@ -310,7 +310,6 @@ class PCA(PCAParams):
     def _fit_streamed(self, source, k, use_xla_dot, use_xla_svd, timer):
         if use_xla_dot:
             import jax
-            import jax.numpy as jnp
 
             from spark_rapids_ml_tpu.ops.streaming import stream_covariance
 
@@ -347,12 +346,11 @@ class PCA(PCAParams):
             raise ValueError("mean centering requires more than one row")
         if use_xla_svd:
             import jax
-            import jax.numpy as jnp
 
             device = _resolve_device(self.getDeviceId())
             dtype = _resolve_dtype(self.getDtype())
             with timer.phase("solve"), TraceRange("xla eigh", TraceColor.BLUE):
-                cov_dev = jax.device_put(jnp.asarray(cov, dtype=dtype), device)
+                cov_dev = jax.device_put(np.asarray(cov, dtype=dtype), device)
                 pc, evr = self._solve_cov_gated(cov_dev, k)
             return np.asarray(pc), np.asarray(evr), mean
         with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
@@ -376,8 +374,7 @@ class PCA(PCAParams):
             # Fused Pallas center+scale+mask+Gram (ops/pallas_gram.py):
             # X is read from HBM once per visited tile pair, no centered
             # copy materialized, and the symmetric folded grid does half
-            # the MXU/HBM work of a dot_general — the measured winner on
-            # a live v5e (see _pallas_gram_enabled). TPUML_PALLAS_GRAM=0
+            # the MXU/HBM work of a dot_general. TPUML_PALLAS_GRAM=0
             # restores the XLA path.
             from spark_rapids_ml_tpu.ops.pallas_gram import covariance_fused
 
@@ -412,7 +409,7 @@ class PCA(PCAParams):
                 # one; 'eigh'/'randomized' explicitly keep the fused
                 # single-program pipeline below
                 with timer.phase("h2d"):
-                    x = jax.device_put(jnp.asarray(x_host, dtype=dtype),
+                    x = jax.device_put(np.asarray(x_host, dtype=dtype),
                                        device)
                 with timer.phase("covariance"), TraceRange(
                     "compute cov", TraceColor.RED
@@ -431,7 +428,7 @@ class PCA(PCAParams):
 
             # Whole pipeline in ONE compiled program on device.
             with timer.phase("h2d"):
-                x = jax.device_put(jnp.asarray(x_host, dtype=dtype), device)
+                x = jax.device_put(np.asarray(x_host, dtype=dtype), device)
             with timer.phase("fit_kernel"), TraceRange("compute cov", TraceColor.RED):
                 result = pca_fit_kernel(
                     x, k, mean_centering=mean_centering, solver=solver,
@@ -448,7 +445,7 @@ class PCA(PCAParams):
             # Device covariance + host eigensolve (reference's
             # useGemm=true / useCuSolverSVD=false mode).
             with timer.phase("h2d"):
-                x = jax.device_put(jnp.asarray(x_host, dtype=dtype), device)
+                x = jax.device_put(np.asarray(x_host, dtype=dtype), device)
             with timer.phase("covariance"), TraceRange("compute cov", TraceColor.RED):
                 if mean_centering:
                     mean = column_means(x)
@@ -466,7 +463,7 @@ class PCA(PCAParams):
         with timer.phase("covariance"), TraceRange("host cov", TraceColor.ORANGE):
             cov, mean = _host_covariance(x_host, self.getMeanCentering())
         with timer.phase("solve"), TraceRange("xla eigh", TraceColor.BLUE):
-            cov_dev = jax.device_put(jnp.asarray(cov, dtype=dtype), device)
+            cov_dev = jax.device_put(np.asarray(cov, dtype=dtype), device)
             pc, evr = self._solve_cov_gated(cov_dev, k)
         return np.asarray(pc), np.asarray(evr), mean
 
@@ -483,19 +480,10 @@ def _pallas_gram_enabled(device, dtype, n_features) -> bool:
     """Whether the fused Pallas Gram path is selected for a one-shot fit.
 
     Policy lives in ``ops.pallas_gram.pallas_gram_preferred`` (flag
-    override, TPU-family backend, f32, padded-cost heuristic — it measured
-    2.29M rows/s vs 1.57M for ``lax.dot_general`` on a live v5e at
-    65536×4096). The env kill switch (TPUML_PALLAS_GRAM=0) is honored
-    BEFORE the pallas import so it also bypasses an import-broken pallas.
+    override, TPU backend, f32, padded-cost heuristic).
     """
-    import os
+    from spark_rapids_ml_tpu.ops.pallas_gram import pallas_gram_preferred
 
-    if os.environ.get("TPUML_PALLAS_GRAM") == "0":
-        return False
-    try:
-        from spark_rapids_ml_tpu.ops.pallas_gram import pallas_gram_preferred
-    except Exception:  # pallas unavailable on this JAX build
-        return False
     return pallas_gram_preferred(
         getattr(device, "platform", ""), dtype, n_features
     )
@@ -628,7 +616,6 @@ class PCAModel(PCAParams):
             )
         if self.getUseXlaDot():
             import jax
-            import jax.numpy as jnp
 
             from spark_rapids_ml_tpu.ops.pca_kernel import pca_transform_kernel
             from spark_rapids_ml_tpu.utils.padding import (
@@ -648,9 +635,9 @@ class PCAModel(PCAParams):
             with TraceRange("xla transform", TraceColor.GREEN):
                 with transform_phase("device_put"):
                     x = jax.device_put(
-                        jnp.asarray(x_host, dtype=dtype), device)
+                        np.asarray(x_host, dtype=dtype), device)
                     pc = jax.device_put(
-                        jnp.asarray(self.pc, dtype=dtype), device)
+                        np.asarray(self.pc, dtype=dtype), device)
                 with transform_phase("compute"):
                     out_dev = pca_transform_kernel(x, pc)
                 with transform_phase("host_sync"):
@@ -680,12 +667,12 @@ class PCAModel(PCAParams):
 
         if precision == "bf16":
             return (jax.device_put(
-                jnp.asarray(self.pc, dtype=jnp.bfloat16), device),)
+                np.asarray(self.pc, dtype=jnp.bfloat16), device),)
         if precision == "int8":
             q, scale = quantize_symmetric_host(self.pc)
-            return (jax.device_put(jnp.asarray(q), device), scale)
+            return (jax.device_put(q, device), scale)
         return (jax.device_put(
-            jnp.asarray(self.pc, dtype=dtype), device),)
+            np.asarray(self.pc, dtype=dtype), device),)
 
     def serving_stage(self, precision: str = "native", *,
                       device=None, dtype=None):

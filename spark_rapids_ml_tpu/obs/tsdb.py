@@ -41,6 +41,7 @@ moment of death.
 
 from __future__ import annotations
 
+import atexit
 import collections
 import os
 import threading
@@ -609,7 +610,15 @@ class MetricsSampler:
 
     def start(self) -> None:
         """Start the sampling thread (idempotent — two racing starts
-        must not spawn two sweep loops sampling at double cadence)."""
+        must not spawn two sweep loops sampling at double cadence).
+
+        The thread is a daemon nobody is obliged to stop, and its
+        collectors call into the device runtime (``memory_stats``): a
+        sweep still inside such a call when the interpreter tears the
+        backend down aborts the process after the program's work is
+        done. So a running sampler is stopped and joined at exit — the
+        hook runs before JAX's own (registered at import, atexit is
+        LIFO) while the backend is still alive."""
         from spark_rapids_ml_tpu.obs import tracectx
 
         with self._lifecycle:
@@ -621,6 +630,7 @@ class MetricsSampler:
                 fresh=True,
             )
             self._thread.start()
+            atexit.register(self.stop)
 
     def stop(self, timeout: float = 5.0) -> None:
         with self._lifecycle:
@@ -629,6 +639,7 @@ class MetricsSampler:
             self._stop.set()
             thread = self._thread
             self._thread = None
+            atexit.unregister(self.stop)
         if thread is not None:
             thread.join(timeout=timeout)
 

@@ -170,6 +170,20 @@ def clear_all_signature_caches() -> None:
         inst.clear_cache()
 
 
+def fallback_signatures() -> Dict[str, int]:
+    """``{label: count}`` of signatures, across live tracked functions,
+    that gave up on their AOT executable (``lower().compile()`` raised, or
+    the compiled call rejected its arguments) and run the plain jitted
+    function instead. Such a kernel still answers, but its compile is
+    invisible to the telemetry — the chip smoke asserts this is empty."""
+    out: Dict[str, int] = {}
+    for inst in list(_instances):
+        count = inst.stats()["fallbacks"]
+        if count:
+            out[inst.label] = out.get(inst.label, 0) + count
+    return out
+
+
 def _leaf_sig(x) -> Tuple:
     shape = getattr(x, "shape", None)
     dtype = getattr(x, "dtype", None)

@@ -153,6 +153,13 @@ def pca_from_covariance_gated(
     spectra is rotation within an eigenvalue cluster — a legitimate PCA
     basis capturing the same variance — and intentionally passes.
 
+    The residual alone is blind to a DROPPED direction: a zero column has
+    zero residual. The orthonormalization zeroes what float32 cannot
+    resolve (``ops.randomized._orthonormalize``), so the gate also requires
+    every returned component to have unit norm; a basis with a dropped
+    column — a spectrum steeper than the whitening's range, or k beyond
+    rank(Cov) — takes the dense fallback too.
+
     Returns ``(components, evr, solver_used)``.
     """
     import jax
@@ -174,9 +181,11 @@ def pca_from_covariance_gated(
     scale = jnp.sqrt(jnp.asarray(k, cov.dtype)) * jnp.maximum(
         jnp.mean(lam), jnp.finfo(cov.dtype).tiny
     )
-    # inverted comparison so NaN/inf residuals (overflowed solve) FAIL the
-    # gate rather than slipping through a `NaN > rtol` == False
-    if not (float(resid / scale) <= residual_rtol):
+    complete = jnp.min(jnp.sum(pc * pc, axis=0)) > 0.5
+    # one host read for both verdicts; the comparison is inverted so
+    # NaN/inf residuals (overflowed solve) FAIL the gate rather than
+    # slipping through a `NaN > rtol` == False
+    if not bool((resid / scale <= residual_rtol) & complete):
         pc, evr = pca_from_covariance(cov, k, flip_signs, "eigh")
         return pc, evr, "eigh(gated)"
     return pc, evr, "randomized"

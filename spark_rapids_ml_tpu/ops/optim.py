@@ -53,10 +53,11 @@ def minimize_kernel(params, data, *, loss_fn, solver: str, max_iter: int,
             ) from exc
 
         opt = optax.lbfgs()
-        # NOT optax.value_and_grad_from_state: its reuse cond compares the
-        # init state's weak-f64 inf against the objective's value and
-        # rejects float32 objectives under an x64 runtime (optax 0.2.3).
-        # Recomputing at p is the same math, one extra fwd+bwd per iter.
+        # NOT optax.value_and_grad_from_state: under optax 0.2.3 its reuse
+        # cond compared the init state's weak-f64 inf against the
+        # objective's value and rejected float32 objectives under an x64
+        # runtime; not re-checked against the installed 0.2.6. Recomputing
+        # at p is the same math, one extra fwd+bwd per iter.
         value_and_grad = jax.value_and_grad(objective)
 
         def body(carry):

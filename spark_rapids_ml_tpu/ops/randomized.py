@@ -43,22 +43,37 @@ def _orthonormalize(y: jnp.ndarray) -> jnp.ndarray:
     measured via a hung finalize); the Gram-eigh route is three MXU matmuls
     plus an l×l eigendecomposition (QDWH — the same primitive the dense
     solver already compiles): B = YᵀY, B = VΛVᵀ, Q = Y·V·Λ^(−1/2).
-    Like CholeskyQR this squares the condition number, so callers
-    re-orthonormalize EVERY iteration (which subspace iteration does
-    anyway) and tiny Λ entries are clamped. Clamped directions become
-    exactly-zero columns and STAY zero through subsequent matvecs (unlike
-    Householder QR, which would fill them with arbitrary orthonormal
-    vectors): Rayleigh-Ritz then assigns them eigenvalue 0 and they sort
-    last, so they only surface as zero component rows when the requested k
-    exceeds rank(Cov) — preferable to NaNs poisoning the whole basis.
+
+    Like CholeskyQR this squares the condition number: one pass resolves
+    only directions whose squared singular value clears the floor
+    ``λmax·eps·n`` — in float32 at n=4096 a singular-value range of 45.
+    Y = Cov·Q spans λ₁/λ_l, which is 266 for a 1/j spectrum at l=266, so a
+    single pass that DROPPED what it could not resolve zeroed four fifths
+    of the basis on the chip (PR 21). Hence two passes: the first clamps —
+    directions under the floor are scaled up by it, not dropped — and the
+    second, now facing a range of √floor-over-σ at most, resolves them
+    (45² ≈ 2000 in all). Only what is still under the floor after both is
+    zeroed: a zero column stays zero through later matvecs, Rayleigh-Ritz
+    gives it eigenvalue 0 and it sorts last — the honest answer when k
+    exceeds rank(Cov), and what ``pca_from_covariance_gated`` looks for
+    when it is not.
     """
-    b = y.T @ y
-    b = (b + b.T) / 2
-    evals, vecs = jnp.linalg.eigh(b)
     eps = jnp.asarray(jnp.finfo(y.dtype).eps, y.dtype)
-    floor = jnp.maximum(evals[-1], 0.0) * eps * y.shape[0]
-    inv_sqrt = jnp.where(evals > floor, 1.0 / jnp.sqrt(jnp.maximum(evals, floor)), 0.0)
-    return y @ (vecs * inv_sqrt[None, :])
+    tiny = jnp.asarray(jnp.finfo(y.dtype).tiny, y.dtype)
+
+    def whiten(y, drop_unresolved):
+        b = y.T @ y
+        b = (b + b.T) / 2
+        evals, vecs = jnp.linalg.eigh(b)
+        # never 0: an all-zero Y (constant data) must come out as zero
+        # columns, not 0·inf = NaN
+        floor = jnp.maximum(evals[-1] * eps * y.shape[0], tiny)
+        inv_sqrt = 1.0 / jnp.sqrt(jnp.maximum(evals, floor))
+        if drop_unresolved:
+            inv_sqrt = jnp.where(evals > floor, inv_sqrt, 0.0)
+        return y @ (vecs * inv_sqrt[None, :])
+
+    return whiten(whiten(y, False), True)
 
 
 def subspace_iteration(

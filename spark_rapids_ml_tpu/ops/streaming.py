@@ -20,6 +20,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_ml_tpu.obs.xprof import tracked_jit
 from spark_rapids_ml_tpu.ops.covariance import covariance_from_stats, partial_gram_stats
@@ -36,17 +37,16 @@ class GramStats(NamedTuple):
 
 
 def init_stats(n_features: int, dtype=jnp.float32, device=None) -> GramStats:
-    zeros = partial(jnp.zeros, dtype=dtype)
-    stats = GramStats(
+    # allocated ON ``device`` (None = the default device), not on device 0
+    # and copied over
+    zeros = partial(jnp.zeros, dtype=dtype, device=device)
+    return GramStats(
         gram=zeros((n_features, n_features)),
         col_sum=zeros((n_features,)),
         # int32, not the compute dtype: f32 counts lose exactness past 2^24
         # rows (see ops.covariance.row_count)
-        count=jnp.zeros((), dtype=jnp.int32),
+        count=jnp.zeros((), dtype=jnp.int32, device=device),
     )
-    if device is not None:
-        stats = jax.device_put(stats, device)
-    return stats
 
 
 @partial(tracked_jit, donate_argnums=(0,), static_argnames=("precision",))
@@ -133,35 +133,25 @@ def _gram_platform(gram_acc) -> str:
 def fused_update_applicable(gram_acc, batch, mask) -> bool:
     """Whether the Pallas Gram accumulator handles this (acc, batch, mask).
 
-    The policy (flag override, TPU family, f32, measured-cost heuristic)
+    The policy (flag override, TPU backend, f32, measured-cost heuristic)
     is ``ops.pallas_gram.pallas_gram_preferred`` — shared with the one-shot
     estimator gate. On top of it this path requires exact tile alignment
-    and no mask (``update_stats_fused`` does not pad). The env kill switch
-    (TPUML_PALLAS_GRAM=0) is honored BEFORE any pallas import so it also
-    bypasses a pallas module that fails to import.
+    and no mask (``update_stats_fused`` does not pad).
     """
-    import os
+    from spark_rapids_ml_tpu.ops.pallas_gram import (
+        gram_block_shape,
+        pallas_gram_preferred,
+    )
 
-    if os.environ.get("TPUML_PALLAS_GRAM") == "0":
-        return False
     if mask is not None or gram_acc.dtype != jnp.float32:
-        return False
-    try:
-        from spark_rapids_ml_tpu.ops.pallas_gram import (
-            gram_block_shape,
-            pallas_gram_preferred,
-        )
-    except Exception:  # pallas unavailable on this JAX build
         return False
     bn, br = gram_block_shape()
     rows, n = batch.shape
     if rows % br or n % bn or (n // bn) % 2:
         return False
-    try:
-        platform = _gram_platform(gram_acc)
-    except Exception:  # tracers / committed-less arrays: stay conservative
-        return False
-    return pallas_gram_preferred(platform, gram_acc.dtype, n)
+    if isinstance(gram_acc, jax.core.Tracer):
+        return False  # a traced accumulator has no device to ask
+    return pallas_gram_preferred(_gram_platform(gram_acc), gram_acc.dtype, n)
 
 
 def update_stats_auto(
@@ -286,28 +276,27 @@ def stream_covariance(
     requested; one-pass sufficient statistics otherwise. Returns device
     arrays; covariance is normalized by n−1 as everywhere in this package.
     """
+    def _put(batch, mask):
+        # host batch → ``device`` in one hop (None = the default device);
+        # jnp.asarray would land it on device 0 whatever ``device`` is
+        return (jax.device_put(np.asarray(batch, dtype=dtype), device),
+                None if mask is None else jax.device_put(mask, device))
+
     n = source.n_features
     if mean_centering and source.reiterable:
-        mstats = MeanStats(
-            jnp.zeros((n,), dtype=dtype), jnp.zeros((), dtype=jnp.int32)
-        )
-        if device is not None:
-            mstats = jax.device_put(mstats, device)
+        mstats = MeanStats(jnp.zeros((n,), dtype=dtype, device=device),
+                           jnp.zeros((), dtype=jnp.int32, device=device))
         for batch, mask in source.batches():
-            mstats = update_mean_stats(mstats, jnp.asarray(batch, dtype=dtype),
-                                       None if mask is None else jnp.asarray(mask))
+            mstats = update_mean_stats(mstats, *_put(batch, mask))
         count = mstats.count
         mean = mstats.col_sum / count
-        gram_acc = jnp.zeros((n, n), dtype=dtype)
-        if device is not None:
-            gram_acc = jax.device_put(gram_acc, device)
+        gram_acc = jnp.zeros((n, n), dtype=dtype, device=device)
         pass2_rows = 0
         for batch, mask in source.batches():
             pass2_rows += batch.shape[0] if mask is None else int(mask.sum())
+            x_dev, m_dev = _put(batch, mask)
             gram_acc = update_centered_gram_auto(
-                gram_acc, jnp.asarray(batch, dtype=dtype), mean,
-                None if mask is None else jnp.asarray(mask),
-                precision=precision)
+                gram_acc, x_dev, mean, m_dev, precision=precision)
         if pass2_rows != int(count):
             # A "re-iterable" factory that hands back a partially-consumed
             # iterator would silently zero the Gram; fail instead.
@@ -321,9 +310,8 @@ def stream_covariance(
 
     stats = init_stats(n, dtype=dtype, device=device)
     for batch, mask in source.batches():
-        stats = update_stats_auto(stats, jnp.asarray(batch, dtype=dtype),
-                                  None if mask is None else jnp.asarray(mask),
-                                  precision=precision)
+        x_dev, m_dev = _put(batch, mask)
+        stats = update_stats_auto(stats, x_dev, m_dev, precision=precision)
     cov = covariance_from_stats(
         stats.gram, stats.col_sum, stats.count, mean_centering=mean_centering
     )
@@ -332,5 +320,3 @@ def stream_covariance(
     else:
         mean = jnp.zeros_like(stats.col_sum)
     return cov, mean, stats.count
-
-

@@ -54,7 +54,8 @@ class DistributedPCAResult(NamedTuple):
     mean: jnp.ndarray
 
 
-def _shard_fit(x_shard, mask_shard, *, k, mean_centering, one_pass, flip_signs):
+def _shard_fit(x_shard, mask_shard, *, k, mean_centering, one_pass, flip_signs,
+               solver):
     """Per-device program (runs under shard_map over the ``data`` axis)."""
     dtype = x_shard.dtype
     if one_pass:
@@ -77,13 +78,17 @@ def _shard_fit(x_shard, mask_shard, *, k, mean_centering, one_pass, flip_signs):
         xc = (x_shard - mean[None, :]) * m * scale
         # collective 2: all-reduce of partial covariance
         cov = jax.lax.psum(gram(xc), DATA_AXIS)
-    components, evr = pca_from_covariance(cov, k, flip_signs=flip_signs)
+    components, evr = pca_from_covariance(
+        cov, k, flip_signs=flip_signs, solver=solver
+    )
     return components, evr, mean
 
 
 @partial(
     tracked_jit,
-    static_argnames=("mesh", "k", "mean_centering", "one_pass", "flip_signs"),
+    static_argnames=(
+        "mesh", "k", "mean_centering", "one_pass", "flip_signs", "solver"
+    ),
 )
 def distributed_pca_fit_kernel(
     x: jnp.ndarray,
@@ -94,11 +99,16 @@ def distributed_pca_fit_kernel(
     mean_centering: bool = True,
     one_pass: bool = False,
     flip_signs: bool = True,
+    solver: str = "eigh",
 ) -> DistributedPCAResult:
     """The full sharded fit as one jitted program.
 
     ``x``/``mask`` may live on host or be pre-sharded; the in_specs place
-    rows over the ``data`` axis, outputs are replicated.
+    rows over the ``data`` axis, outputs are replicated. ``solver`` is
+    ``ops.eigh.pca_from_covariance``'s, as in
+    ``distributed_streaming_pca_fit``: the eigensolve is part of this
+    program, so ``"eigh"`` at n=4096 makes its first call compile for
+    minutes on a TPU (PERF.md) where ``"randomized"`` takes seconds.
     """
     fn = jax.shard_map(
         partial(
@@ -107,6 +117,7 @@ def distributed_pca_fit_kernel(
             mean_centering=mean_centering,
             one_pass=one_pass,
             flip_signs=flip_signs,
+            solver=solver,
         ),
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),
@@ -125,6 +136,7 @@ def distributed_pca_fit(
     one_pass: bool = False,
     flip_signs: bool = True,
     dtype=None,
+    solver: str = "eigh",
 ) -> DistributedPCAResult:
     """Host-side driver: pad rows to the mesh, place shards, run the kernel.
 
@@ -148,6 +160,11 @@ def distributed_pca_fit(
         sharding = row_sharding(mesh)
         x_dev = jax.device_put(x_padded, sharding)
         mask_dev = jax.device_put(mask, NamedSharding(mesh, P(DATA_AXIS)))
+        # what each chip actually holds — the report's evidence that the
+        # rows were split over the mesh and not parked on the first device
+        ctx.note(rows_per_device={
+            str(s.device): int(s.data.shape[0])
+            for s in x_dev.addressable_shards})
     n = x_host.shape[1]
     dt = x_padded.dtype
     if one_pass:
@@ -175,6 +192,7 @@ def distributed_pca_fit(
                 mean_centering=mean_centering,
                 one_pass=one_pass,
                 flip_signs=flip_signs,
+                solver=solver,
             )
         )
         step.note(k=k, one_pass=int(one_pass))

@@ -26,41 +26,6 @@ DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
 
 
-def _install_shard_map_compat() -> None:
-    """Expose ``jax.shard_map`` and ``jax.lax.axis_size`` on older jax
-    (< 0.5), where shard_map lives at ``jax.experimental.shard_map`` and
-    the replication-check kwarg is ``check_rep`` rather than ``check_vma``.
-    Every driver in this package imports this module, so the aliases are
-    installed before any call site runs."""
-    if not hasattr(jax.lax, "axis_size"):
-        def axis_size(axis_name):
-            # old jax: the axis frame IS the (static) size
-            import jax.core as core
-
-            return core.axis_frame(axis_name)
-
-        jax.lax.axis_size = axis_size
-    if hasattr(jax, "shard_map"):
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError:  # pragma: no cover - very old jax; let call sites fail
-        return
-
-    def shard_map(f, *args, **kwargs):
-        kwargs.pop("check_vma", None)
-        # the old static replication checker lacks rules for while/argmax
-        # the kernels here rely on (newer jax proves them); disable it —
-        # out_specs still declare the contract
-        kwargs["check_rep"] = False
-        return _shard_map(f, *args, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-_install_shard_map_compat()
-
-
 def device_count() -> int:
     return len(jax.devices())
 

@@ -106,12 +106,13 @@ class DistributedStreamingPCA:
         shard3 = NamedSharding(mesh, P(DATA_AXIS, None, None))
         shard2 = NamedSharding(mesh, P(DATA_AXIS, None))
         shard1 = NamedSharding(mesh, P(DATA_AXIS))
+        # allocated sharded: each chip zero-fills its own (1, n, n) slice
+        # instead of device 0 building all D of them and scattering
         self._stats = GramStats(
-            gram=jax.device_put(
-                jnp.zeros((d, n_features, n_features), dtype=dtype), shard3
-            ),
-            col_sum=jax.device_put(jnp.zeros((d, n_features), dtype=dtype), shard2),
-            count=jax.device_put(jnp.zeros((d,), dtype=jnp.int32), shard1),
+            gram=jnp.zeros((d, n_features, n_features), dtype=dtype,
+                           device=shard3),
+            col_sum=jnp.zeros((d, n_features), dtype=dtype, device=shard2),
+            count=jnp.zeros((d,), dtype=jnp.int32, device=shard1),
         )
 
     def partial_fit(self, batch, mask=None) -> "DistributedStreamingPCA":
@@ -136,6 +137,13 @@ class DistributedStreamingPCA:
     @property
     def rows_seen(self) -> int:
         return int(np.asarray(jnp.sum(self._stats.count)))
+
+    @property
+    def rows_per_device(self) -> dict:
+        """Rows each chip has accumulated, read from its own slice of the
+        sharded count — ``{device label: rows}``."""
+        return {str(s.device): int(np.asarray(s.data).sum())
+                for s in self._stats.count.addressable_shards}
 
     def finalize(
         self, k: int, mean_centering: bool = True, solver: str = "eigh"
@@ -192,7 +200,7 @@ def distributed_streaming_pca_fit(
                 mon.note(fold=float(n_batches))
             n_batches += 1
     ctx.set_data(rows=acc.rows_seen, features=source.n_features)
-    ctx.note(batches_streamed=n_batches)
+    ctx.note(batches_streamed=n_batches, rows_per_device=acc.rows_per_device)
     if mean_centering and acc.rows_seen < 2:
         raise ValueError("mean centering requires more than one row")
     with ctx.phase("finalize"), current_run().step(

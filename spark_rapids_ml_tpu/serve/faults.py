@@ -1,11 +1,10 @@
 """Injectable fault plane: make the serving tier fail on purpose.
 
-The r04 outage (``OUTAGE_r04.log``: a wedged device tunnel that hung the
-transform path for ~20 hours) could not be rehearsed before it happened —
-there was no way to make the serving stack misbehave on demand, so the
-breaker/retry/fallback machinery this package adds would otherwise ship
-untested against the very failures it exists to absorb. This module is
-the chaos-engineering control plane for ``serve/``:
+A device backend hang that stalls the transform path for hours cannot be
+rehearsed unless the serving stack can be made to misbehave on demand —
+without that, the breaker/retry/fallback machinery this package adds
+would ship untested against the very failures it exists to absorb. This
+module is the chaos-engineering control plane for ``serve/``:
 
 * **programmatic API** — ``fault_plane().inject(model="pca", kind="raise",
   count=5)`` arms a fault; ``clear()`` disarms everything. Tests drive
@@ -70,7 +69,7 @@ _DEFAULT_SECONDS = {"stall": 30.0, "latency": 0.05}
 class InjectedBackendError(RuntimeError):
     """An injected device-backend failure — the engine classifies it
     exactly like an ``XlaRuntimeError``/``Unavailable`` from a real
-    wedged tunnel (retryable, breaker-counted)."""
+    backend fault (retryable, breaker-counted)."""
 
 
 class InjectedWorkerCrash(BaseException):

@@ -818,7 +818,8 @@ def start_serve_server(
     """Serve the engine on a daemon thread; returns the HTTPServer (bind
     ``port=0`` for ephemeral — read ``server.server_address[1]``; stop
     with ``server.shutdown()``, then ``engine.shutdown()`` to drain).
-    Also starts the background history sampler (``obs.tsdb``) so
+    Also starts the background history sampler (``obs.tsdb``;
+    process-wide, outlives this server and joins itself at exit) so
     ``/debug/history`` and the dashboard sparklines have data, and —
     unless ``SPARK_RAPIDS_ML_TPU_OBS_INCIDENTS=0`` — installs the
     auto-incident engine on it: detectors run at the sampling cadence

@@ -226,7 +226,7 @@ def _host_fitted_state(model) -> None:
     closure; a device-resident attribute (e.g. a forest's stacked
     ``ensemble_``) would force a device sync on the driver at pickle time
     and make every executor worker initialize an accelerator backend just
-    to deserialize — a hang risk on single-claim device tunnels. Models
+    to deserialize — and an accelerator belongs to one process. Models
     re-stage to their own device lazily on first use."""
     try:
         import jax

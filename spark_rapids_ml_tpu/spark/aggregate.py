@@ -1475,9 +1475,7 @@ def partition_lda_stats(
     import jax.numpy as jnp
 
     from spark_rapids_ml_tpu.ops.lda_kernel import e_step_kernel
-    from spark_rapids_ml_tpu.utils.platform import force_cpu_if_requested
 
-    force_cpu_if_requested()
     beta_dev = jnp.asarray(exp_elog_beta)
     alpha_dev = jnp.asarray(alpha, dtype=beta_dev.dtype)
     total = np.zeros(exp_elog_beta.shape, dtype=np.float64)

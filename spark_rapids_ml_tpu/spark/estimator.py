@@ -296,22 +296,20 @@ class PCAModel(Model, _TpuPCAParams):
         def project(v: pd.Series) -> pd.Series:
             x = np.stack([row.toArray() for row in v])
             if use_xla:
-                try:
-                    import jax
-                    import jax.numpy as jnp
+                # a device failure raises (Spark reschedules the task); it
+                # does not quietly turn the executor into a NumPy one
+                import jax
 
-                    from spark_rapids_ml_tpu.models.pca import _resolve_device
-                    from spark_rapids_ml_tpu.ops.pca_kernel import (
-                        pca_transform_kernel,
-                    )
+                from spark_rapids_ml_tpu.models.pca import _resolve_device
+                from spark_rapids_ml_tpu.ops.pca_kernel import (
+                    pca_transform_kernel,
+                )
 
-                    device = _resolve_device(device_id)
-                    y = np.asarray(pca_transform_kernel(
-                        jax.device_put(jnp.asarray(x, dtype=jnp.float32), device),
-                        jax.device_put(jnp.asarray(pc_np, dtype=jnp.float32), device),
-                    ))
-                except Exception:
-                    y = x @ pc_np
+                device = _resolve_device(device_id)
+                y = np.asarray(pca_transform_kernel(
+                    jax.device_put(np.asarray(x, dtype=np.float32), device),
+                    jax.device_put(np.asarray(pc_np, dtype=np.float32), device),
+                ))
             else:
                 y = x @ pc_np
             return pd.Series([DenseVector(row) for row in y])

@@ -58,6 +58,61 @@ def test_gate_falls_back_to_eigh_when_residual_bar_unmet(rng):
     )
 
 
+def test_float32_randomized_keeps_every_direction_on_a_power_law(rng):
+    """How the chip runs (float32, variances ∝ 1/j — chip_smoke.py's
+    spectrum): a single eigh-whitening pass resolves a singular-value
+    range of 1/√(eps·n) ≈ 90 at n=1024, the sketch spans λ₁/λ_l = 128, and
+    dropping the rest left zero columns that the residual gate waved
+    through (PR 21, found on the v5e at 4096/k=256)."""
+    import jax.numpy as jnp
+
+    n, k = 1024, 118
+    q, _ = np.linalg.qr(rng.normal(size=(n, n)))
+    lam = 1.0 / (1.0 + np.arange(n))
+    cov = (q * lam[None, :]) @ q.T
+    pc, evr, used = pca_from_covariance_gated(
+        jnp.asarray(cov, dtype=jnp.float32), k)
+    pc = np.asarray(pc, dtype=np.float64)
+    assert used == "randomized"
+    assert np.abs(pc.T @ pc - np.eye(k)).max() < 1e-4
+    # the fitted subspace holds all but a sliver of the top-k variance
+    assert 1.0 - np.trace(pc.T @ cov @ pc) / lam[:k].sum() < 5e-3
+    assert np.all(np.asarray(evr) > 0)
+
+
+def test_gate_falls_back_when_a_direction_was_dropped(rng):
+    """k beyond rank(Cov): the orthonormalization zeroes what is not
+    there, a zero column has zero residual, and the gate must still send
+    the solve to dense eigh."""
+    import jax.numpy as jnp
+
+    a = rng.normal(size=(1024, 40))
+    pc, _, used = pca_from_covariance_gated(
+        jnp.asarray(a @ a.T, dtype=jnp.float32), 64)
+    assert used == "eigh(gated)"
+    assert np.abs(np.asarray(pc)).max(axis=0).min() > 0
+
+
+def test_float32_zero_covariance_gives_zero_components_not_nan():
+    """Constant data: Cov = 0, so Y = Cov·Ω = 0, λmax = 0 and a floor of
+    λmax·eps·n is 0 — the clamping pass must not turn that into 0·inf.
+    The ungated callers (jitted kernels, the feature-sharded fit) return
+    whatever the solve returns."""
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.ops.randomized import (
+        _orthonormalize,
+        randomized_pca_from_covariance,
+    )
+
+    q = np.asarray(_orthonormalize(jnp.zeros((256, 12), jnp.float32)))
+    assert np.array_equal(q, np.zeros_like(q))
+    cov = jnp.zeros((256, 256), jnp.float32)
+    pc, evr = randomized_pca_from_covariance(cov, 8, jnp.trace(cov))
+    assert np.array_equal(np.asarray(pc), np.zeros((256, 8), np.float32))
+    assert np.array_equal(np.asarray(evr), np.zeros(8, np.float32))
+
+
 def test_small_covariance_auto_is_eigh(rng):
     import jax.numpy as jnp
 

@@ -11,8 +11,6 @@ mesh; the driver runs the same code at 8).
 
 import numpy as np
 
-from conftest import optax_lbfgs_x64_skip
-
 
 def test_entry_compiles_and_runs():
     import jax
@@ -27,7 +25,6 @@ def test_entry_compiles_and_runs():
     assert mean.shape == (128,)
 
 
-@optax_lbfgs_x64_skip  # the dryrun's AFT path hits the broken linesearch
 def test_dryrun_multichip_executes_every_path():
     import __graft_entry__ as g
 

@@ -119,10 +119,10 @@ def test_check_devices_subprocess_crash_verdict(monkeypatch):
     class FakeProc:
         returncode = 3
         stdout = ""
-        stderr = "boom: device tunnel fell over"
+        stderr = "boom: device backend fell over"
 
     monkeypatch.setattr(subprocess, "run", lambda *a, **k: FakeProc())
     verdict = check_devices_subprocess(timeout_seconds=5)
     assert verdict.healthy is False
     assert "rc=3" in verdict.error
-    assert "device tunnel fell over" in verdict.error
+    assert "device backend fell over" in verdict.error

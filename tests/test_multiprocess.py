@@ -27,9 +27,6 @@ _WORKER = textwrap.dedent(
     ).strip()
 
     import numpy as np
-    from spark_rapids_ml_tpu.utils.platform import force_cpu_if_requested
-
-    force_cpu_if_requested()
 
     from spark_rapids_ml_tpu.parallel.multihost import (
         global_data_mesh,

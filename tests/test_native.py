@@ -1,6 +1,6 @@
 """Native runtime (libtpuml.so) unit tests — the layer the reference never
-tested (SURVEY.md §4: "No unit tests of the native layer"). Builds on
-demand via make; skips if no toolchain.
+tested (SURVEY.md §4: "No unit tests of the native layer"). conftest.py
+builds the library with make; skips if there is no toolchain.
 """
 
 import os

@@ -389,7 +389,7 @@ def test_wedged_canary_exactly_one_auto_resolving_incident(
 
     def canary():
         if wedged["on"]:
-            release.wait(5.0)  # a wedged device tunnel: never returns
+            release.wait(5.0)  # a hung device backend: never returns
 
     wd = _watchdog(canary_fn=canary, canary_timeout_s=0.01)
     try:
@@ -399,7 +399,7 @@ def test_wedged_canary_exactly_one_auto_resolving_incident(
         assert wd.last_verdict()["reason"] == "canary_wedged"
         assert tick(wd) == []  # still wedged: update, not a duplicate
         assert engine.manager.opened_total == 1
-        wedged["on"] = False  # tunnel recovers
+        wedged["on"] = False  # the backend recovers
         tick(wd)
         tick(wd)
         assert engine.manager.open_incidents() == []

@@ -91,12 +91,12 @@ def test_hard_exception_dumps_fast_validation_does_not(dumps):
     # hard runtime error -> dump
     with pytest.raises(OSError):
         with obs.deadline("hard_error_test", budget_seconds=30.0):
-            raise OSError("device tunnel gone")
+            raise OSError("device backend gone")
     files = _dump_files(dumps)
     assert len(files) == 1
     doc = json.load(open(files[0]))
     assert doc["reason"] == "unhandled_exception:hard_error_test"
-    assert "device tunnel gone" in doc["extra"]["error"]
+    assert "device backend gone" in doc["extra"]["error"]
     # fast validation error -> no new dump
     with pytest.raises(ValueError):
         with obs.deadline("validation_error_test", budget_seconds=30.0):

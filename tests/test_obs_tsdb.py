@@ -6,6 +6,9 @@ sampling vs querying) because the store's lock discipline is exactly
 what the background sampler leans on.
 """
 
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -383,6 +386,102 @@ def test_sampler_background_thread_runs_and_stops():
     sweeps = sampler.sweeps
     time.sleep(0.05)
     assert sampler.sweeps == sweeps  # really stopped
+
+
+_EXIT_MID_SWEEP = """
+import atexit, threading, time
+from spark_rapids_ml_tpu.obs.metrics import MetricsRegistry
+from spark_rapids_ml_tpu.obs.tsdb import MetricsSampler, TimeSeriesStore
+
+state = {"in_call": False}
+
+def backend_teardown():  # registered first, so it runs last: JAX's clean_up
+    print("in_call", state["in_call"], "running", sampler.running)
+
+atexit.register(backend_teardown)
+entered = threading.Event()
+
+def collector():  # a sweep inside a device-runtime call (memory_stats)
+    state["in_call"] = True
+    entered.set()
+    time.sleep(0.5)
+    state["in_call"] = False
+
+sampler = MetricsSampler(TimeSeriesStore(), registry=MetricsRegistry(),
+                         interval_seconds=0.01)
+sampler.register_collector(collector)
+sampler.start()
+assert entered.wait(10)
+"""
+
+
+def test_running_sampler_is_joined_before_later_exit_hooks():
+    """Nobody stops the process-wide sampler ``start_serve_server``
+    starts. A daemon sweep still inside a PJRT call when the interpreter
+    tears the backend down aborts the process (seen on the v5e and, one
+    run in six, on the CPU — PR 21), so a started sampler stops and joins
+    itself at exit, ahead of the hooks registered before it."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo)
+    proc = subprocess.run([sys.executable, "-c", _EXIT_MID_SWEEP], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == ["in_call", "False", "running", "False"]
+
+
+_EXIT_WITH_A_LIVE_SERVER = """
+import http.client, json
+import numpy as np
+from spark_rapids_ml_tpu import PCA
+from spark_rapids_ml_tpu.serve import (
+    ModelRegistry, ServeEngine, start_serve_server)
+
+x = np.random.default_rng(0).standard_normal((512, 32)).astype(np.float32)
+registry = ModelRegistry()
+registry.register("m", PCA().setK(4).fit(x))
+engine = ServeEngine(registry, max_batch_rows=32, max_wait_ms=1.0)
+engine.warmup("m")
+server = start_serve_server(engine, port=0)
+conn = http.client.HTTPConnection(
+    "127.0.0.1", server.server_address[1], timeout=30)
+conn.request("POST", "/predict",
+             body=json.dumps({"model": "m", "rows": x[:3].tolist()}),
+             headers={"Content-Type": "application/json"})
+assert conn.getresponse().status == 200
+print("answered", flush=True)
+# exit with the server, the engine and the sampler all still running
+"""
+
+
+def test_process_with_a_live_serve_server_exits_clean():
+    """The symptom itself: at a 1 ms sweep cadence a process that leaves
+    ``start_serve_server`` running aborted at exit 12 times in 12 before
+    the sampler stopped itself (``FATAL: exception not rethrown`` from
+    inside the device-memory collector), 0 in 12 after (PR 21)."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo,
+               SPARK_RAPIDS_ML_TPU_OBS_SAMPLE_MS="1")
+    proc = subprocess.run([sys.executable, "-c", _EXIT_WITH_A_LIVE_SERVER],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.stdout.split() == ["answered"], proc.stderr[-2000:]
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_stopped_sampler_leaves_no_exit_hook_behind():
+    """stop() takes the hook back, so samplers that come and go (tests,
+    reset_tsdb) are not kept alive until exit with their stores."""
+    import gc
+    import weakref
+
+    sampler = MetricsSampler(TimeSeriesStore(), registry=MetricsRegistry(),
+                             interval_seconds=0.01)
+    sampler.start()
+    sampler.stop()
+    ref = weakref.ref(sampler)
+    del sampler
+    gc.collect()
+    assert ref() is None
 
 
 # -- history tail + flight dump integration ----------------------------------
