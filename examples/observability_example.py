@@ -65,10 +65,9 @@ def main() -> None:
     print("== compile report (obs.xprof via tracked_jit)")
     print(f"  compiles={report.compiles}  recompiles={report.recompiles}  "
           f"compile_seconds={report.compile_seconds:.3f}")
-    print(f"  analytic_flops={report.analytic_flops}  "
-          f"flops_by_phase={report.flops_by_phase}")
-    print(f"  analytic_mfu={report.analytic_mfu}  (None on CPU: no "
-          "published peak)")
+    print(f"  programs_compiled={report.programs_compiled}  "
+          f"programs_fetched={report.programs_fetched}  (every executable "
+          "JAX built, the eager solve's too)")
     agg = obs.compile_stats()
     for label in sorted(agg)[:4]:
         s = agg[label]

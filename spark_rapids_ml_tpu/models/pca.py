@@ -48,6 +48,16 @@ from spark_rapids_ml_tpu.utils.numeric import (
 from spark_rapids_ml_tpu.utils.timing import PhaseTimer
 from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
 
+# Host spans of the streamed fit, outermost first; the stages inside
+# SPAN_STREAMED_COV are ops.streaming.STREAM_SPANS. The benchmark reads a
+# trace by these names (benchmarks/work/spans.py).
+SPAN_FIT = "fit:pca"  # opened by @observed_fit("pca")
+SPAN_STREAMED_COV = "streamed cov"
+SPAN_XLA_EIGH = "xla eigh"
+# what follows the solve (``fit_timings_["fetch"]``): pc, explained variance
+# and mean to the host, float64 copies, the PCAModel
+SPAN_FETCH = "fit:fetch"
+
 
 class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
     """Shared params, mirroring ``RapidsPCAParams`` (``RapidsPCA.scala:30-75``)."""
@@ -283,13 +293,16 @@ class PCA(PCAParams):
         else:
             pc, evr, mean = self._fit_host(x_host, k, timer)
 
-        model = PCAModel(
-            pc=np.asarray(pc, dtype=np.float64),
-            explained_variance=np.asarray(evr, dtype=np.float64),
-            mean=np.asarray(mean, dtype=np.float64),
-        )
-        model.uid = self.uid
-        model.copy_values_from(self)
+        # device results cross to the host here (the streamed path hands
+        # pc, evr and mean back as device arrays)
+        with timer.phase("fetch"), TraceRange(SPAN_FETCH, TraceColor.CYAN):
+            model = PCAModel(
+                pc=np.asarray(pc, dtype=np.float64),
+                explained_variance=np.asarray(evr, dtype=np.float64),
+                mean=np.asarray(mean, dtype=np.float64),
+            )
+            model.uid = self.uid
+            model.copy_values_from(self)
         model.fit_timings_ = timer.as_dict()
         model.svd_solver_used_ = getattr(self, "_svd_solver_used", None)
         return model
@@ -311,27 +324,34 @@ class PCA(PCAParams):
         if use_xla_dot:
             import jax
 
-            from spark_rapids_ml_tpu.ops.streaming import stream_covariance
+            from spark_rapids_ml_tpu.ops.streaming import (
+                SPAN_SYNC_COV,
+                IngestTrace,
+                stream_covariance,
+            )
 
-            device = _resolve_device(self.getDeviceId())
             dtype = _resolve_dtype(self.getDtype())
+            ingest = IngestTrace(timer, _resolve_device(self.getDeviceId()))
             with timer.phase("covariance"), TraceRange(
-                "streamed cov", TraceColor.RED
+                SPAN_STREAMED_COV, TraceColor.RED
             ):
                 cov, mean, count = stream_covariance(
                     source,
                     mean_centering=self.getMeanCentering(),
                     dtype=dtype,
-                    device=device,
                     precision=self._gram_precision(),
+                    ingest=ingest,
                 )
-                cov = jax.block_until_ready(cov)
+                with ingest.sync(SPAN_SYNC_COV):
+                    cov = jax.block_until_ready(cov)
             if self.getMeanCentering() and float(count) < 2:
                 raise ValueError("mean centering requires more than one row")
             if use_xla_svd:
-                with timer.phase("solve"), TraceRange("xla eigh", TraceColor.BLUE):
+                ingest.hbm("solve:start")
+                with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
                     pc, evr = self._solve_cov_gated(cov, k)
-                return np.asarray(pc), np.asarray(evr), np.asarray(mean)
+                ingest.hbm("solve:end")
+                return pc, evr, mean  # on the device: fit() fetches them
             with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
                 pc, evr = _host_eig_topk(np.asarray(cov, dtype=np.float64), k)
             return pc, evr, np.asarray(mean)
@@ -349,7 +369,7 @@ class PCA(PCAParams):
 
             device = _resolve_device(self.getDeviceId())
             dtype = _resolve_dtype(self.getDtype())
-            with timer.phase("solve"), TraceRange("xla eigh", TraceColor.BLUE):
+            with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
                 cov_dev = jax.device_put(np.asarray(cov, dtype=dtype), device)
                 pc, evr = self._solve_cov_gated(cov_dev, k)
             return np.asarray(pc), np.asarray(evr), mean
@@ -389,7 +409,7 @@ class PCA(PCAParams):
                 )
                 cov = jax.block_until_ready(cov)
             if use_xla_svd:
-                with timer.phase("solve"), TraceRange("xla eigh", TraceColor.BLUE):
+                with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
                     pc, evr = self._solve_cov_gated(cov, k)
                 return np.asarray(pc), np.asarray(evr), np.asarray(mean)
             with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
@@ -421,7 +441,7 @@ class PCA(PCAParams):
                     else:
                         mean = jnp.zeros((x.shape[1],), dtype=x.dtype)
                         cov = covariance(x, precision=precision)
-                with timer.phase("solve"), TraceRange("xla eigh",
+                with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH,
                                                       TraceColor.BLUE):
                     pc, evr = self._solve_cov_gated(cov, k)
                 return pc, evr, jax.block_until_ready(mean)
@@ -462,7 +482,7 @@ class PCA(PCAParams):
         # useCuSolverSVD=true — the reference's "pca using cuSolver" test mode).
         with timer.phase("covariance"), TraceRange("host cov", TraceColor.ORANGE):
             cov, mean = _host_covariance(x_host, self.getMeanCentering())
-        with timer.phase("solve"), TraceRange("xla eigh", TraceColor.BLUE):
+        with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
             cov_dev = jax.device_put(np.asarray(cov, dtype=dtype), device)
             pc, evr = self._solve_cov_gated(cov_dev, k)
         return np.asarray(pc), np.asarray(evr), mean

@@ -50,6 +50,10 @@ def _escape_label_value(value: str) -> str:
 def _format_value(v: float) -> str:
     if math.isinf(v):
         return "+Inf" if v > 0 else "-Inf"
+    if math.isnan(v):
+        # a diverged fit publishes a NaN loss; the exposition format has a
+        # word for it, and int(nan) below would fail the whole scrape
+        return "NaN"
     if v == int(v) and abs(v) < 1e15:
         return str(int(v))
     return repr(float(v))

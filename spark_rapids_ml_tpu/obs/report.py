@@ -65,11 +65,11 @@ class FitReport:
     compiles: int = 0
     recompiles: int = 0
     compile_seconds: float = 0.0
-    # HLO cost-analysis accounting over every tracked program this fit ran
-    analytic_flops: Optional[float] = None
-    analytic_bytes: Optional[float] = None
-    flops_by_phase: Dict[str, float] = field(default_factory=dict)
-    analytic_mfu: Optional[float] = None
+    # every executable JAX built during the fit, tracked_jit or not (the
+    # eager solve): compiled by the backend / fetched from the persistent
+    # cache (obs.xprof's jax.monitoring listener)
+    programs_compiled: int = 0
+    programs_fetched: int = 0
     # Device-memory watermark (obs.memory; host RSS on statless backends)
     peak_device_bytes: Optional[int] = None
     memory: Optional[Dict[str, Any]] = None
@@ -89,24 +89,6 @@ class FitReport:
     def total_collective_calls(self) -> int:
         return sum(int(v.get("count", 0)) for v in self.collectives.values())
 
-    def phase_mfu(self, peak_flops: Optional[float] = None
-                  ) -> Dict[str, Optional[float]]:
-        """Per-phase analytic MFU: cost-analysis FLOPs attributed to each
-        phase over that phase's wall-clock over the chip peak (None entries
-        when the peak or the phase time is unknown)."""
-        if peak_flops is None:
-            from spark_rapids_ml_tpu.obs.xprof import peak_flops_per_second
-
-            peak_flops = peak_flops_per_second()
-        out: Dict[str, Optional[float]] = {}
-        for phase, flops in self.flops_by_phase.items():
-            seconds = self.phases.get(phase)
-            if peak_flops and seconds:
-                out[phase] = flops / seconds / peak_flops
-            else:
-                out[phase] = None
-        return out
-
 
 class FitContext:
     """Mutable accounting for one in-flight fit.
@@ -120,8 +102,7 @@ class FitContext:
         "algo", "trace_id", "timer", "collectives", "extra",
         "rows", "features", "bytes_processed", "n_iter", "_lock",
         "compiles", "recompiles", "compile_seconds",
-        "analytic_flops", "analytic_bytes", "flops_by_phase",
-        "_phase_stack",
+        "programs_built", "programs_fetched",
     )
 
     def __init__(self, algo: str, trace_id: Optional[str] = None):
@@ -137,10 +118,8 @@ class FitContext:
         self.compiles = 0
         self.recompiles = 0
         self.compile_seconds = 0.0
-        self.analytic_flops = 0.0
-        self.analytic_bytes = 0.0
-        self.flops_by_phase: Dict[str, float] = {}
-        self._phase_stack: Tuple[str, ...] = ()
+        self.programs_built = 0
+        self.programs_fetched = 0
         self._lock = threading.Lock()
 
     @contextlib.contextmanager
@@ -149,15 +128,7 @@ class FitContext:
         with self.timer.phase(name), spans.span(
             f"{self.algo}:{name}", TraceColor.CYAN
         ):
-            # NOTE: the phase stack attributes tracked-program FLOPs to the
-            # innermost phase of whichever thread entered it last; drivers
-            # run phases sequentially on one thread, which is the contract.
-            prev = self._phase_stack
-            self._phase_stack = prev + (name,)
-            try:
-                yield
-            finally:
-                self._phase_stack = prev
+            yield
 
     def record_compile(self, label: str, seconds: float, *,
                        recompile: bool = False) -> None:
@@ -169,21 +140,16 @@ class FitContext:
                 self.recompiles += 1
             self.compile_seconds += float(seconds)
 
-    def record_program(self, label: str, flops: Optional[float],
-                       nbytes: Optional[float]) -> None:
-        """Called by ``obs.xprof`` on every tracked-program execution:
-        accumulates HLO cost-analysis FLOPs/bytes, attributed to the
-        innermost active phase."""
+    def record_executable(self, fetched: bool) -> None:
+        """Called by ``obs.xprof``'s ``jax.monitoring`` listener: JAX built
+        an executable during this fit (every build ends in one such call
+        with ``fetched=False``; one that came from the persistent cache is
+        announced by a call with ``fetched=True`` first)."""
         with self._lock:
-            if flops:
-                self.analytic_flops += float(flops)
-                phase = self._phase_stack[-1] if self._phase_stack \
-                    else "_unphased"
-                self.flops_by_phase[phase] = (
-                    self.flops_by_phase.get(phase, 0.0) + float(flops)
-                )
-            if nbytes:
-                self.analytic_bytes += float(nbytes)
+            if fetched:
+                self.programs_fetched += 1
+            else:
+                self.programs_built += 1
 
     def record_collective(
         self,
@@ -259,7 +225,7 @@ class _NullFitContext(FitContext):
     def record_compile(self, *args, **kwargs) -> None:
         pass
 
-    def record_program(self, *args, **kwargs) -> None:
+    def record_executable(self, *args, **kwargs) -> None:
         pass
 
     def set_data(self, *args, **kwargs) -> None:
@@ -424,12 +390,6 @@ def _build_report(
         fields.setdefault("device_platform", health.get("platform"))
         fields.setdefault("device_count", health.get("device_count"))
     fields.update(_memory_fields())
-    try:
-        from spark_rapids_ml_tpu.obs.xprof import analytic_mfu
-
-        mfu = analytic_mfu(ctx.analytic_flops, wall)
-    except Exception:
-        mfu = None
     return FitReport(
         algo=ctx.algo,
         trace_id=ctx.trace_id,
@@ -446,10 +406,8 @@ def _build_report(
         compiles=ctx.compiles,
         recompiles=ctx.recompiles,
         compile_seconds=ctx.compile_seconds,
-        analytic_flops=ctx.analytic_flops or None,
-        analytic_bytes=ctx.analytic_bytes or None,
-        flops_by_phase=dict(ctx.flops_by_phase),
-        analytic_mfu=mfu,
+        programs_compiled=ctx.programs_built - ctx.programs_fetched,
+        programs_fetched=ctx.programs_fetched,
         extra=dict(ctx.extra),
         **fields,
     )
@@ -494,11 +452,6 @@ def _record_metrics(report: FitReport) -> None:
             "sparkml_fit_recompiles_total",
             "XLA re-compilations attributed to fits", ("algo",),
         ).inc(report.recompiles, algo=algo)
-    if report.analytic_flops:
-        reg.counter(
-            "sparkml_analytic_flops_total",
-            "HLO cost-analysis FLOPs executed by fits", ("algo",),
-        ).inc(report.analytic_flops, algo=algo)
     reg.histogram(
         "sparkml_fit_seconds", "fit wall-clock seconds", ("algo",)
     ).observe(report.wall_seconds, algo=algo)
@@ -634,7 +587,6 @@ def fit_instrumentation(algo: str, attach: bool = True):
                         "wall_seconds": report.wall_seconds,
                         "rows": report.rows,
                         "n_iter": report.n_iter,
-                        "analytic_mfu": report.analytic_mfu,
                         "collective_bytes":
                             report.total_collective_bytes(),
                     }

@@ -14,10 +14,13 @@ makes XLA compilation a first-class observable instead of an invisible tax:
   ``SPARK_RAPIDS_ML_TPU_RECOMPILE_STORM`` (default 8): the classic symptom
   of un-padded batch tails or a static arg that should be dynamic;
 * **HLO ``cost_analysis`` FLOPs / bytes-accessed and compiled memory
-  sizes** per signature, so every executed call can attribute *analytic*
-  FLOPs to the active fit (``FitReport.analytic_flops`` /
-  ``flops_by_phase`` → per-phase analytic MFU) instead of the bench-only
-  ``2·rows·cols²`` estimate.
+  sizes** per signature (``CompileEvent``), handed on every executed call
+  to the serving report and the fit-path monitor (``obs.fitmon``).
+
+What ``tracked_jit`` cannot see — programs JAX builds for eager ops, the
+PCA solve among them — reaches the active fit through one
+``jax.monitoring`` listener (``_on_executable_built``) as
+``FitReport.programs_compiled`` / ``programs_fetched``.
 
 Execution goes through the cached compiled executable, so tracking adds no
 extra compiles: signature miss → one lower+compile (exactly what ``jax.jit``
@@ -464,11 +467,6 @@ class TrackedJit:
 
     def _record_execution(self, entry: _CacheEntry) -> None:
         try:
-            from spark_rapids_ml_tpu.obs.report import current_fit
-
-            current_fit().record_program(
-                self.label, entry.flops, entry.bytes_accessed
-            )
             from spark_rapids_ml_tpu.obs.serving import current_transform
 
             current_transform().record_program(
@@ -639,3 +637,30 @@ def analytic_mfu(flops: Optional[float],
     if not peak:
         return None
     return flops / seconds / peak
+
+
+# -- every executable JAX builds, tracked or not ----------------------------
+
+_BUILT_EVENT = "/jax/core/compile/backend_compile_duration"
+_FETCHED_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _on_executable_built(event: str, duration: float, **_) -> None:
+    """``jax.monitoring`` duration listener. JAX reports ``_BUILT_EVENT``
+    once per executable it builds, compiled or read back from the persistent
+    cache, and ``_FETCHED_EVENT`` just before it for one that was read back;
+    both fire on the thread that asked, so the fit's context is the caller's."""
+    if event not in (_BUILT_EVENT, _FETCHED_EVENT):
+        return
+    try:
+        from spark_rapids_ml_tpu.obs.report import current_fit
+
+        current_fit().record_executable(fetched=event == _FETCHED_EVENT)
+    except Exception:
+        pass  # telemetry must never break a compile
+
+
+import jax.monitoring  # noqa: E402 - the package has imported jax by now
+
+# once: a module is imported once
+jax.monitoring.register_event_duration_secs_listener(_on_executable_built)

@@ -15,6 +15,8 @@ This is also the host data-loader contract: feed fixed-shape batches
 
 from __future__ import annotations
 
+import contextlib
+import time
 from functools import partial
 from typing import NamedTuple, Optional
 
@@ -22,10 +24,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_ml_tpu.obs.memory import device_memory_stats
+from spark_rapids_ml_tpu.obs.report import current_fit
 from spark_rapids_ml_tpu.obs.xprof import tracked_jit
 from spark_rapids_ml_tpu.ops.covariance import covariance_from_stats, partial_gram_stats
 from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance
 from spark_rapids_ml_tpu.ops.pca_kernel import PCAFitResult
+from spark_rapids_ml_tpu.utils.timing import PhaseTimer
+from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
 
 
 class GramStats(NamedTuple):
@@ -154,13 +160,19 @@ def fused_update_applicable(gram_acc, batch, mask) -> bool:
     return pallas_gram_preferred(_gram_platform(gram_acc), gram_acc.dtype, n)
 
 
+def accumulate_path(gram_acc, batch, mask) -> str:
+    """``"pallas"`` or ``"xla"``: the Gram kernel ``update_stats_auto`` /
+    ``update_centered_gram_auto`` pick for this (acc, batch, mask)."""
+    return "pallas" if fused_update_applicable(gram_acc, batch, mask) else "xla"
+
+
 def update_stats_auto(
     stats: GramStats, batch: jnp.ndarray, mask: Optional[jnp.ndarray] = None,
     precision: Optional[str] = None,
 ) -> GramStats:
     """The production accumulate step: picks the measured-fastest Gram
     kernel for this backend/shape (see ``fused_update_applicable``)."""
-    if fused_update_applicable(stats.gram, batch, mask):
+    if accumulate_path(stats.gram, batch, mask) == "pallas":
         return update_stats_fused(stats, batch, precision=precision)
     return update_stats(stats, batch, mask, precision=precision)
 
@@ -256,11 +268,132 @@ def update_centered_gram_auto(gram_acc, batch, mean, mask=None,
     """Centered-Gram accumulate via the measured-fastest kernel: the Pallas
     kernel centers in VMEM (no (X−μ) materialization at all), same policy
     gate as ``update_stats_auto``."""
-    if fused_update_applicable(gram_acc, batch, mask):
+    if accumulate_path(gram_acc, batch, mask) == "pallas":
         return _update_centered_gram_fused(gram_acc, batch, mean,
                                            precision=precision)
     return update_centered_gram(gram_acc, batch, mean, mask,
                                 precision=precision)
+
+
+# -- spans, sub-phases and counters of the streamed fit ---------------------
+#
+# Every stage of ``stream_covariance`` is a ``TraceRange`` (a host span in
+# the profiler's trace and in the ``obs.spans`` ring), seconds in the fit's
+# ``PhaseTimer`` and counters on ``current_fit()``. The names are the
+# benchmark's yardstick (``benchmarks/work/spans.py`` mirrors them); the
+# spans wrap what the loop does and add no sync, host read or program.
+
+SPAN_PASS_MEAN = "stream:pass/mean"
+SPAN_PASS_GRAM = "stream:pass/gram"
+SPAN_PASS_STATS = "stream:pass/stats"
+SPAN_NEXT = "stream:next"
+SPAN_PUT = "stream:put"
+SPAN_ACCUMULATE = {"mean": "stream:accumulate/mean",
+                   "pallas": "stream:accumulate/pallas",
+                   "xla": "stream:accumulate/xla"}
+SPAN_SYNC_COUNT = "stream:sync/count"
+SPAN_SYNC_COV = "stream:sync/cov"
+STREAM_SPANS = (SPAN_PASS_MEAN, SPAN_PASS_GRAM, SPAN_PASS_STATS, SPAN_NEXT,
+                SPAN_PUT, *SPAN_ACCUMULATE.values(), SPAN_SYNC_COUNT,
+                SPAN_SYNC_COV)
+
+PHASE_NEXT = "covariance/next"
+PHASE_PUT = "covariance/put"
+PHASE_DISPATCH = "covariance/dispatch"
+PHASE_SYNC = "covariance/sync"
+
+
+def _boundary(span: str) -> str:
+    """``hbm_bytes_in_use`` key of a span: its name without ``stream:``."""
+    return span.partition(":")[2]
+
+
+class IngestTrace:
+    """What one streamed fit tells about its ingest: the spans above, the
+    ``covariance/*`` seconds summed in ``timer``, and the counters that
+    reach ``fit_report_.extra["ingest"]``."""
+
+    def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
+        self.timer = timer if timer is not None else PhaseTimer()
+        self.device = device  # None = JAX's default device, uncommitted
+        self.pass_rows = 0  # valid rows put in the current pass
+        self.itemsize = 0
+        self.counters = {
+            "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
+            "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
+            "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
+            "hbm_bytes_in_use": {},
+        }
+        # noted now, filled as the fit goes: a fit that dies keeps its count
+        current_fit().note(ingest=self.counters)
+
+    @contextlib.contextmanager
+    def stage(self, span: str, phase: str, slowest: Optional[str] = None):
+        t0 = time.perf_counter()
+        with TraceRange(span, TraceColor.ORANGE):
+            yield
+        seconds = time.perf_counter() - t0
+        self.timer.add(phase, seconds)
+        if slowest is not None:
+            self.counters[slowest] = max(self.counters[slowest], seconds)
+
+    def hbm(self, boundary: str) -> None:
+        """Device bytes in use now, kept under ``boundary``; nothing where
+        the backend has no ``memory_stats()`` (the CPU)."""
+        stats = device_memory_stats(self.device or jax.local_devices()[0])
+        if stats is not None and "bytes_in_use" in stats:
+            self.counters["hbm_bytes_in_use"][boundary] = int(
+                stats["bytes_in_use"])
+
+    @contextlib.contextmanager
+    def walk(self, span: str):
+        """One pass over ``source.batches()``."""
+        self.counters["passes"] += 1
+        self.pass_rows = 0
+        with TraceRange(span, TraceColor.YELLOW):
+            yield
+        # the pass's last put has returned and its last program is queued
+        self.hbm(_boundary(span) + ":end")
+
+    def batches(self, source):
+        batches = source.batches()
+        while True:
+            with self.stage(SPAN_NEXT, PHASE_NEXT):
+                item = next(batches, None)
+            if item is None:
+                return
+            yield item
+
+    def put(self, batch, mask, dtype):
+        """Host batch → ``device`` in one hop; ``jnp.asarray`` would land
+        it on device 0 whatever ``device`` is."""
+        with self.stage(SPAN_PUT, PHASE_PUT, "put_seconds_max"):
+            x = np.asarray(batch, dtype=dtype)
+            x_dev = jax.device_put(x, self.device)
+            m_dev = None if mask is None else jax.device_put(mask, self.device)
+        valid = x.shape[0] if mask is None else int(mask.sum())
+        self.pass_rows += valid
+        self.itemsize = x.itemsize
+        self.counters["batches"] += 1
+        self.counters["rows_put"] += x.shape[0]  # padding crosses too
+        self.counters["bytes_put"] += x.nbytes
+        return x_dev, m_dev
+
+    def accumulate(self, path: str):
+        self.counters["accumulate_calls"][path] += 1
+        return self.stage(SPAN_ACCUMULATE[path], PHASE_DISPATCH)
+
+    def sync(self, span: str):
+        """A place where the host blocks on a device value."""
+        self.hbm(_boundary(span))
+        return self.stage(span, PHASE_SYNC, "sync_seconds_max")
+
+    def set_data(self, n_features: int) -> None:
+        """The dataset as the last pass counted it: rows without padding,
+        and their bytes once (``bytes_put`` counts every crossing)."""
+        current_fit().set_data(
+            rows=self.pass_rows, features=n_features,
+            nbytes=self.pass_rows * n_features * self.itemsize)
 
 
 def stream_covariance(
@@ -269,49 +402,62 @@ def stream_covariance(
     dtype=jnp.float32,
     device=None,
     precision: Optional[str] = None,
+    ingest: Optional[IngestTrace] = None,
 ):
     """Stream a ``data.batches.BatchSource`` into (covariance, mean, count).
 
     Two-pass (center → Gram) when the source is re-iterable and centering is
     requested; one-pass sufficient statistics otherwise. Returns device
     arrays; covariance is normalized by n−1 as everywhere in this package.
+    ``ingest`` (an ``IngestTrace`` on the fit's ``PhaseTimer``) records the
+    stages; without one they are traced and counted all the same. Each Gram
+    step asks ``accumulate_path`` for its span's name and then calls
+    ``update_*_auto``, which asks again: the step stays the one function
+    other callers and the benchmark's fault tests reach.
     """
-    def _put(batch, mask):
-        # host batch → ``device`` in one hop (None = the default device);
-        # jnp.asarray would land it on device 0 whatever ``device`` is
-        return (jax.device_put(np.asarray(batch, dtype=dtype), device),
-                None if mask is None else jax.device_put(mask, device))
-
+    if ingest is None:
+        ingest = IngestTrace(device=device)
+    device = ingest.device
     n = source.n_features
     if mean_centering and source.reiterable:
         mstats = MeanStats(jnp.zeros((n,), dtype=dtype, device=device),
                            jnp.zeros((), dtype=jnp.int32, device=device))
-        for batch, mask in source.batches():
-            mstats = update_mean_stats(mstats, *_put(batch, mask))
+        with ingest.walk(SPAN_PASS_MEAN):
+            for batch, mask in ingest.batches(source):
+                x_dev, m_dev = ingest.put(batch, mask, dtype)
+                with ingest.accumulate("mean"):
+                    mstats = update_mean_stats(mstats, x_dev, m_dev)
         count = mstats.count
         mean = mstats.col_sum / count
         gram_acc = jnp.zeros((n, n), dtype=dtype, device=device)
-        pass2_rows = 0
-        for batch, mask in source.batches():
-            pass2_rows += batch.shape[0] if mask is None else int(mask.sum())
-            x_dev, m_dev = _put(batch, mask)
-            gram_acc = update_centered_gram_auto(
-                gram_acc, x_dev, mean, m_dev, precision=precision)
-        if pass2_rows != int(count):
+        with ingest.walk(SPAN_PASS_GRAM):
+            for batch, mask in ingest.batches(source):
+                x_dev, m_dev = ingest.put(batch, mask, dtype)
+                with ingest.accumulate(accumulate_path(gram_acc, x_dev, m_dev)):
+                    gram_acc = update_centered_gram_auto(
+                        gram_acc, x_dev, mean, m_dev, precision=precision)
+        with ingest.sync(SPAN_SYNC_COUNT):
+            pass1_rows = int(count)
+        if ingest.pass_rows != pass1_rows:
             # A "re-iterable" factory that hands back a partially-consumed
             # iterator would silently zero the Gram; fail instead.
             raise RuntimeError(
-                f"two-pass streaming saw {int(count)} rows on pass 1 but "
-                f"{pass2_rows} on pass 2; the source factory must return a "
-                f"FRESH iterator on every call"
+                f"two-pass streaming saw {pass1_rows} rows on pass 1 but "
+                f"{ingest.pass_rows} on pass 2; the source factory must "
+                f"return a FRESH iterator on every call"
             )
+        ingest.set_data(n)
         denom = jnp.maximum(count - 1, 1)
         return gram_acc / denom, mean, count
 
     stats = init_stats(n, dtype=dtype, device=device)
-    for batch, mask in source.batches():
-        x_dev, m_dev = _put(batch, mask)
-        stats = update_stats_auto(stats, x_dev, m_dev, precision=precision)
+    with ingest.walk(SPAN_PASS_STATS):
+        for batch, mask in ingest.batches(source):
+            x_dev, m_dev = ingest.put(batch, mask, dtype)
+            with ingest.accumulate(accumulate_path(stats.gram, x_dev, m_dev)):
+                stats = update_stats_auto(stats, x_dev, m_dev,
+                                          precision=precision)
+    ingest.set_data(n)
     cov = covariance_from_stats(
         stats.gram, stats.col_sum, stats.count, mean_centering=mean_centering
     )

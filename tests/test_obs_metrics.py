@@ -89,6 +89,19 @@ def test_prometheus_text_format():
     assert "t_sec_count 1" in text
 
 
+def test_a_nan_gauge_does_not_take_the_scrape_down():
+    """A diverged fit publishes a NaN loss (``sparkml_fit_convergence``);
+    every other series of the process must still be scraped."""
+    reg = MetricsRegistry()
+    reg.gauge("t_loss", "loss").set(float("nan"))
+    reg.gauge("t_up", "up").set(float("-inf"))
+    reg.counter("t_total", "n").inc(3)
+    text = reg.prometheus_text()
+    assert "t_loss NaN" in text
+    assert "t_up -Inf" in text
+    assert "t_total 3" in text
+
+
 def test_thread_safety_concurrent_increments():
     reg = MetricsRegistry()
     c = reg.counter("t_conc", "x", ("t",))
