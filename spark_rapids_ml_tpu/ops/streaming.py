@@ -15,7 +15,9 @@ This is also the host data-loader contract: feed fixed-shape batches
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import time
 from functools import partial
 from typing import NamedTuple, Optional
@@ -206,8 +208,11 @@ class StreamingPCA:
 # The one-pass (Σxxᵀ, Σx, n) accumulator above loses accuracy to f32
 # cancellation in G − n·μμᵀ when |μ| ≫ σ. A re-iterable source affords the
 # reference's own schedule out-of-core: pass 1 streams (Σx, n) → μ, pass 2
-# streams the CENTERED Gram — numerically the two-pass fit kernel, with HBM
-# bounded at one batch + one n×n accumulator.
+# streams the CENTERED Gram — numerically the two-pass fit kernel. HBM is
+# NOT bounded at one batch: nothing in the loop waits for the chip, so the
+# puts run a whole pass ahead and fill the device until ``device_put`` itself
+# waits for room. Pass 1 therefore keeps its device batches for pass 2 while
+# ``keep_budget_bytes`` has room: rows that fit on the chip cross once.
 
 class MeanStats(NamedTuple):
     col_sum: jnp.ndarray
@@ -308,18 +313,39 @@ def _boundary(span: str) -> str:
     return span.partition(":")[2]
 
 
+def keep_budget_bytes(device, batch_nbytes: int, gram_nbytes: int) -> int:
+    """Bytes of pass-1 device batches a two-pass fit may keep on ``device``
+    for pass 2: what ``memory_stats()`` reports free now, less what the loop
+    needs when it keeps nothing — three batches (one landing, one being
+    summed, the XLA Gram's centred copy of one) and three n×n (the
+    accumulator, a step's product before it is added, the normalised
+    covariance). 0 where the backend reports no ``memory_stats()`` (the
+    CPU): nothing is kept. Tests patch this function to stand in for the
+    chip."""
+    stats = device_memory_stats(device)
+    if stats is None or not {"bytes_limit", "bytes_in_use"} <= set(stats):
+        return 0
+    free = int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+    return max(0, free - 3 * batch_nbytes - 3 * gram_nbytes)
+
+
 class IngestTrace:
     """What one streamed fit tells about its ingest: the spans above, the
     ``covariance/*`` seconds summed in ``timer``, and the counters that
-    reach ``fit_report_.extra["ingest"]``."""
+    reach ``fit_report_.extra["ingest"]``. It also holds the device batches
+    a two-pass fit keeps from pass 1 for pass 2 (``keep`` / ``replay``)."""
 
     def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
         self.timer = timer if timer is not None else PhaseTimer()
         self.device = device  # None = JAX's default device, uncommitted
-        self.pass_rows = 0  # valid rows put in the current pass
+        self.pass_rows = 0  # valid rows of the current pass, put or kept
         self.itemsize = 0
+        self.kept = collections.deque()  # (x_dev, m_dev) of pass 1, in order
+        self.kept_rows = 0  # valid rows in ``kept``
+        self.keep_room = 0  # bytes of the budget not taken yet
         self.counters = {
             "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
+            "batches_kept": 0, "bytes_kept": 0, "keep_budget_bytes": 0,
             "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
             "hbm_bytes_in_use": {},
@@ -337,10 +363,13 @@ class IngestTrace:
         if slowest is not None:
             self.counters[slowest] = max(self.counters[slowest], seconds)
 
+    def _stats_device(self):
+        return self.device or jax.local_devices()[0]
+
     def hbm(self, boundary: str) -> None:
         """Device bytes in use now, kept under ``boundary``; nothing where
         the backend has no ``memory_stats()`` (the CPU)."""
-        stats = device_memory_stats(self.device or jax.local_devices()[0])
+        stats = device_memory_stats(self._stats_device())
         if stats is not None and "bytes_in_use" in stats:
             self.counters["hbm_bytes_in_use"][boundary] = int(
                 stats["bytes_in_use"])
@@ -379,6 +408,41 @@ class IngestTrace:
         self.counters["bytes_put"] += x.nbytes
         return x_dev, m_dev
 
+    def allow_keep(self, batch_nbytes: int, gram_nbytes: int) -> None:
+        """Size the budget of ``keep`` from the device as it is now."""
+        self.keep_room = keep_budget_bytes(self._stats_device(),
+                                           batch_nbytes, gram_nbytes)
+        self.counters["keep_budget_bytes"] = self.keep_room
+
+    def keep(self, x_dev, m_dev) -> None:
+        """Hold the device batch just put for pass 2 if the budget has room
+        for it. Only a prefix of the pass is kept: the first batch that
+        finds no room closes the budget."""
+        if x_dev.nbytes > self.keep_room:
+            self.keep_room = 0
+            return
+        self.keep_room -= x_dev.nbytes
+        self.kept.append((x_dev, m_dev))
+        self.kept_rows = self.pass_rows
+        self.counters["batches_kept"] += 1
+        self.counters["bytes_kept"] += x_dev.nbytes
+
+    def replay(self, source, dtype):
+        """Pass 2's device batches in pass 1's order: the kept prefix from
+        the chip — each reference dropped as it is handed out, so HBM drains
+        as the Gram steps run — then the rest of the source, put again. With
+        everything kept the source is not walked; otherwise the kept prefix's
+        host batches are passed over with their rows untouched."""
+        skip = len(self.kept)
+        everything = skip == self.counters["batches"]  # pass 1's puts so far
+        self.pass_rows = self.kept_rows
+        while self.kept:
+            yield self.kept.popleft()
+        if everything:
+            return
+        for batch, mask in itertools.islice(self.batches(source), skip, None):
+            yield self.put(batch, mask, dtype)
+
     def accumulate(self, path: str):
         self.counters["accumulate_calls"][path] += 1
         return self.stage(SPAN_ACCUMULATE[path], PHASE_DISPATCH)
@@ -407,8 +471,13 @@ def stream_covariance(
     """Stream a ``data.batches.BatchSource`` into (covariance, mean, count).
 
     Two-pass (center → Gram) when the source is re-iterable and centering is
-    requested; one-pass sufficient statistics otherwise. Returns device
-    arrays; covariance is normalized by n−1 as everywhere in this package.
+    requested; one-pass sufficient statistics otherwise. Two passes over the
+    rows are not two crossings: pass 1 keeps its device batches while
+    ``keep_budget_bytes`` has room and pass 2 runs on those, so only the rows
+    past the budget are walked and put a second time (all of them where the
+    backend reports no memory, as on the CPU). The arithmetic is the same
+    either way. Returns device arrays; covariance is normalized by n−1 as
+    everywhere in this package.
     ``ingest`` (an ``IngestTrace`` on the fit's ``PhaseTimer``) records the
     stages; without one they are traced and counted all the same. Each Gram
     step asks ``accumulate_path`` for its span's name and then calls
@@ -422,20 +491,26 @@ def stream_covariance(
     if mean_centering and source.reiterable:
         mstats = MeanStats(jnp.zeros((n,), dtype=dtype, device=device),
                            jnp.zeros((), dtype=jnp.int32, device=device))
-        with ingest.walk(SPAN_PASS_MEAN):
-            for batch, mask in ingest.batches(source):
-                x_dev, m_dev = ingest.put(batch, mask, dtype)
-                with ingest.accumulate("mean"):
-                    mstats = update_mean_stats(mstats, x_dev, m_dev)
-        count = mstats.count
-        mean = mstats.col_sum / count
-        gram_acc = jnp.zeros((n, n), dtype=dtype, device=device)
-        with ingest.walk(SPAN_PASS_GRAM):
-            for batch, mask in ingest.batches(source):
-                x_dev, m_dev = ingest.put(batch, mask, dtype)
-                with ingest.accumulate(accumulate_path(gram_acc, x_dev, m_dev)):
-                    gram_acc = update_centered_gram_auto(
-                        gram_acc, x_dev, mean, m_dev, precision=precision)
+        itemsize = jnp.dtype(dtype).itemsize
+        ingest.allow_keep(source.batch_rows * n * itemsize, n * n * itemsize)
+        try:
+            with ingest.walk(SPAN_PASS_MEAN):
+                for batch, mask in ingest.batches(source):
+                    x_dev, m_dev = ingest.put(batch, mask, dtype)
+                    with ingest.accumulate("mean"):
+                        mstats = update_mean_stats(mstats, x_dev, m_dev)
+                    ingest.keep(x_dev, m_dev)
+            count = mstats.count
+            mean = mstats.col_sum / count
+            gram_acc = jnp.zeros((n, n), dtype=dtype, device=device)
+            with ingest.walk(SPAN_PASS_GRAM):
+                for x_dev, m_dev in ingest.replay(source, dtype):
+                    with ingest.accumulate(
+                            accumulate_path(gram_acc, x_dev, m_dev)):
+                        gram_acc = update_centered_gram_auto(
+                            gram_acc, x_dev, mean, m_dev, precision=precision)
+        finally:
+            ingest.kept.clear()  # no kept batch outlives the walk over it
         with ingest.sync(SPAN_SYNC_COUNT):
             pass1_rows = int(count)
         if ingest.pass_rows != pass1_rows:

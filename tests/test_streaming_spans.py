@@ -253,15 +253,19 @@ def test_hbm_is_read_at_the_boundaries_only(monkeypatch):
     monkeypatch.setattr(streaming, "device_memory_stats", stats)
     two = PCA().setK(K).set("batchRows", BATCH).fit(
         _dataset("callable", _chunks((128, 104)))).fit_report_
+    # the two-pass fit reads once more, before its first put: the budget of
+    # the batches it may keep (``keep_budget_bytes``; no ``bytes_limit`` in
+    # these stats, so it keeps none)
     assert list(two.extra["ingest"]["hbm_bytes_in_use"].items()) == [
-        ("pass/mean:end", 1001), ("pass/gram:end", 1002),
-        ("sync/count", 1003), ("sync/cov", 1004),
-        ("solve:start", 1005), ("solve:end", 1006)]
+        ("pass/mean:end", 1002), ("pass/gram:end", 1003),
+        ("sync/count", 1004), ("sync/cov", 1005),
+        ("solve:start", 1006), ("solve:end", 1007)]
+    assert two.extra["ingest"]["batches_kept"] == 0
     one = PCA().setK(K).set("batchRows", BATCH).fit(
         _dataset("iterator", _chunks((128, 104)))).fit_report_
     assert list(one.extra["ingest"]["hbm_bytes_in_use"]) == [
         "pass/stats:end", "sync/cov", "solve:start", "solve:end"]
-    assert len(reads) == 10  # one read a boundary, none a batch
+    assert len(reads) == 11  # one a boundary, one the budget, none a batch
 
 
 def test_counters_outside_a_fit_go_nowhere():
