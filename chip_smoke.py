@@ -581,12 +581,16 @@ def fence_check(checks: Checks, x: np.ndarray, shape: Shape,
 
 def multichip(checks: Checks, x: np.ndarray, one_shot, shape: Shape,
               n_devices: int, bars: dict) -> None:
-    """The mesh fits (XLA Gram under shard_map — not the Pallas kernel):
-    both all-reduce schedules and the streamed accumulator, each checked
-    for an even split of the rows and against the one-chip fit.
+    """The fits over all the chips, each checked for an even split of the
+    rows and against the one-chip fit: the streamed fit — the one loop
+    (``ops.streaming.stream_covariance``) with whole batches dealt to the
+    chips in turn, the Pallas Gram on every chip, two all-reduces and the
+    solve run eagerly on the first chip — and both all-reduce schedules of
+    the one-shot mesh program (XLA Gram under shard_map, not the Pallas
+    kernel).
 
-    The eigensolve is part of each mesh program, and the dense 4096²
-    ``eigh`` compiles for ≈4.5 min on this jax/libtpu: three default
+    The eigensolve is part of each one-shot mesh program, and the dense
+    4096² ``eigh`` compiles for ≈4.5 min on this jax/libtpu: three default
     solves took 838 of the 922 s the first four-chip run needed, against
     the driver's 1200 (PR 21). So the default solver — what a caller who
     passes nothing gets — runs once, on the two-pass schedule and last;
@@ -599,9 +603,14 @@ def multichip(checks: Checks, x: np.ndarray, one_shot, shape: Shape,
     )
 
     # As ordered below, on four v5e chips with a cold cache: 63 + 57 + 289 s
-    # for this phase, 491 s for the whole script (PR 21). The randomized
-    # ones are nearly all compile too (one jitted program with ten 266²
-    # eigh calls in it).
+    # for this phase, 491 s for the whole script (PR 21). The first of those
+    # was the streamed fit as a mesh program with the solve compiled into it
+    # (one jitted program with ten 266² eigh calls), which it no longer is:
+    # through the shared loop this check takes 7.5 s the first time (the
+    # per-chip programs' first dispatch; the eager solve's programs came
+    # with the one-chip fit before it) and 0.11 s the second (PR 28, this
+    # check alone on four chips). The one-pass mesh program still compiles
+    # its solve (57 s).
     mesh = data_mesh(n_devices)
     want_rows = x.shape[0] // n_devices
     top = min(shape.top, shape.k)

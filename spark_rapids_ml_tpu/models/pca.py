@@ -136,6 +136,18 @@ class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
         "auto",
         validator=lambda v: v == "auto" or v in _GRAM_PRECISIONS,
     )
+    numDevices = Param(
+        "numDevices",
+        "how many of the process's local chips a streamed fit may use: the "
+        "chip deviceId resolves to and the next numDevices-1 local ones "
+        "(the in-process form of spark.executor.resource.tpu.amount). "
+        "Host batches are dealt to the chips whole and in turn, each chip "
+        "accumulates its own with the one-chip programs, and two "
+        "all-reduces join them (ops.streaming.stream_covariance). "
+        "1 (default) = the one chip deviceId names, whatever the host has.",
+        1,
+        validator=lambda v: isinstance(v, int) and v >= 1,
+    )
 
 
 def _resolve_dtype(dtype_param: str):
@@ -203,6 +215,23 @@ def _resolve_device(device_id: int):
         f"deviceId {ordinal} matches none of the {len(devices)} visible "
         f"local devices (ids {[d.id for d in devices]})"
     )
+
+
+def _resolve_devices(device_id: int, num_devices: int):
+    """The chips of a streamed fit: ``_resolve_device(device_id)`` and the
+    ``num_devices - 1`` local chips after it."""
+    first = _resolve_device(device_id)
+    if num_devices == 1:
+        return (first,)
+    import jax
+
+    local = jax.local_devices()
+    start = local.index(first)
+    if start + num_devices > len(local):
+        raise ValueError(
+            f"numDevices {num_devices} from local chip {start} on: the "
+            f"process has {len(local)} local devices")
+    return tuple(local[start:start + num_devices])
 
 
 class PCA(PCAParams):
@@ -331,7 +360,8 @@ class PCA(PCAParams):
             )
 
             dtype = _resolve_dtype(self.getDtype())
-            ingest = IngestTrace(timer, _resolve_device(self.getDeviceId()))
+            ingest = IngestTrace(timer, _resolve_devices(
+                self.getDeviceId(), self.getNumDevices()))
             with timer.phase("covariance"), TraceRange(
                 SPAN_STREAMED_COV, TraceColor.RED
             ):

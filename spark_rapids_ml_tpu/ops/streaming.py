@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import itertools
 import time
 from functools import partial
 from typing import NamedTuple, Optional
@@ -285,8 +284,9 @@ def update_centered_gram_auto(gram_acc, batch, mean, mask=None,
 # Every stage of ``stream_covariance`` is a ``TraceRange`` (a host span in
 # the profiler's trace and in the ``obs.spans`` ring), seconds in the fit's
 # ``PhaseTimer`` and counters on ``current_fit()``. The names are the
-# benchmark's yardstick (``benchmarks/work/spans.py`` mirrors them); the
-# spans wrap what the loop does and add no sync, host read or program.
+# benchmark's yardstick (``benchmarks/work/spans.py`` mirrors the stream's,
+# ``benchmarks/work/collective.py`` the collectives'); the spans wrap what
+# the loop does and add no sync, host read or program.
 
 SPAN_PASS_MEAN = "stream:pass/mean"
 SPAN_PASS_GRAM = "stream:pass/gram"
@@ -301,11 +301,16 @@ SPAN_SYNC_COV = "stream:sync/cov"
 STREAM_SPANS = (SPAN_PASS_MEAN, SPAN_PASS_GRAM, SPAN_PASS_STATS, SPAN_NEXT,
                 SPAN_PUT, *SPAN_ACCUMULATE.values(), SPAN_SYNC_COUNT,
                 SPAN_SYNC_COV)
+# only a fit over several chips emits these: each wraps the dispatch of one
+# all-reduce (the chips' parts handed to the mesh program) and nothing else
+SPAN_COLLECTIVE = {"mean": "stream:collective/mean",
+                   "gram": "stream:collective/gram"}
 
 PHASE_NEXT = "covariance/next"
 PHASE_PUT = "covariance/put"
 PHASE_DISPATCH = "covariance/dispatch"
 PHASE_SYNC = "covariance/sync"
+PHASE_COLLECTIVE = "covariance/collective"
 
 
 def _boundary(span: str) -> str:
@@ -319,9 +324,9 @@ def keep_budget_bytes(device, batch_nbytes: int, gram_nbytes: int) -> int:
     needs when it keeps nothing — three batches (one landing, one being
     summed, the XLA Gram's centred copy of one) and three n×n (the
     accumulator, a step's product before it is added, the normalised
-    covariance). 0 where the backend reports no ``memory_stats()`` (the
-    CPU): nothing is kept. Tests patch this function to stand in for the
-    chip."""
+    covariance or, on several chips, the all-reduced Gram). 0 where the
+    backend reports no ``memory_stats()`` (the CPU): nothing is kept. Tests
+    patch this function to stand in for the chip."""
     stats = device_memory_stats(device)
     if stats is None or not {"bytes_limit", "bytes_in_use"} <= set(stats):
         return 0
@@ -329,29 +334,57 @@ def keep_budget_bytes(device, batch_nbytes: int, gram_nbytes: int) -> int:
     return max(0, free - 3 * batch_nbytes - 3 * gram_nbytes)
 
 
+class _Chip:
+    """One chip's share of a streamed fit: where its batches go, what of the
+    keep budget is left there, and its own counters."""
+
+    def __init__(self, device):
+        self.device = device  # None = JAX's default device, uncommitted
+        self.keep_room = 0  # bytes of the chip's budget not taken yet
+        self.counters = {
+            "device": str(self.stats_device()), "rows": 0, "rows_put": 0,
+            "bytes_put": 0, "batches_kept": 0, "bytes_kept": 0,
+            "keep_budget_bytes": 0, "hbm_bytes_in_use": {},
+        }
+
+    def stats_device(self):
+        return self.device or jax.local_devices()[0]
+
+
 class IngestTrace:
     """What one streamed fit tells about its ingest: the spans above, the
     ``covariance/*`` seconds summed in ``timer``, and the counters that
-    reach ``fit_report_.extra["ingest"]``. It also holds the device batches
-    a two-pass fit keeps from pass 1 for pass 2 (``keep`` / ``replay``)."""
+    reach ``fit_report_.extra["ingest"]``. It deals the host batches to the
+    fit's chips (``device``: one, or a sequence of them) whole and in turn,
+    and holds the device batches a two-pass fit keeps from pass 1 for
+    pass 2 (``keep`` / ``replay``), each chip under its own budget."""
 
     def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
         self.timer = timer if timer is not None else PhaseTimer()
-        self.device = device  # None = JAX's default device, uncommitted
+        devices = device if isinstance(device, (list, tuple)) else (device,)
+        self.chips = [_Chip(d) for d in devices]
+        self.turn = 0  # batches dealt in this pass: the next goes to
+        #                chip ``turn % len(chips)``
         self.pass_rows = 0  # valid rows of the current pass, put or kept
+        self.put_rows = 0  # valid rows of the batch last put
         self.itemsize = 0
-        self.kept = collections.deque()  # (x_dev, m_dev) of pass 1, in order
-        self.kept_rows = 0  # valid rows in ``kept``
-        self.keep_room = 0  # bytes of the budget not taken yet
+        # (turn, chip index, valid rows, x_dev, m_dev) of pass 1, in order
+        self.kept = collections.deque()
         self.counters = {
             "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
             "batches_kept": 0, "bytes_kept": 0, "keep_budget_bytes": 0,
             "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
             "hbm_bytes_in_use": {},
+            "chips": len(self.chips), "collective_bytes": {},
+            "per_chip": [chip.counters for chip in self.chips],
         }
         # noted now, filled as the fit goes: a fit that dies keeps its count
         current_fit().note(ingest=self.counters)
+
+    @property
+    def devices(self) -> tuple:
+        return tuple(chip.device for chip in self.chips)
 
     @contextlib.contextmanager
     def stage(self, span: str, phase: str, slowest: Optional[str] = None):
@@ -363,22 +396,25 @@ class IngestTrace:
         if slowest is not None:
             self.counters[slowest] = max(self.counters[slowest], seconds)
 
-    def _stats_device(self):
-        return self.device or jax.local_devices()[0]
-
     def hbm(self, boundary: str) -> None:
-        """Device bytes in use now, kept under ``boundary``; nothing where
-        the backend has no ``memory_stats()`` (the CPU)."""
-        stats = device_memory_stats(self._stats_device())
-        if stats is not None and "bytes_in_use" in stats:
-            self.counters["hbm_bytes_in_use"][boundary] = int(
-                stats["bytes_in_use"])
+        """Device bytes in use now on every chip of the fit, kept under
+        ``boundary`` (the fit's own key holds the fullest chip's); nothing
+        where the backend has no ``memory_stats()`` (the CPU)."""
+        for chip in self.chips:
+            stats = device_memory_stats(chip.stats_device())
+            if stats is not None and "bytes_in_use" in stats:
+                in_use = int(stats["bytes_in_use"])
+                chip.counters["hbm_bytes_in_use"][boundary] = in_use
+                fullest = self.counters["hbm_bytes_in_use"]
+                fullest[boundary] = max(fullest.get(boundary, 0), in_use)
 
     @contextlib.contextmanager
     def walk(self, span: str):
         """One pass over ``source.batches()``."""
         self.counters["passes"] += 1
-        self.pass_rows = 0
+        self.pass_rows = self.turn = 0
+        for chip in self.chips:
+            chip.counters["rows"] = 0
         with TraceRange(span, TraceColor.YELLOW):
             yield
         # the pass's last put has returned and its last program is queued
@@ -393,59 +429,85 @@ class IngestTrace:
                 return
             yield item
 
+    def _count_rows(self, c: int, valid: int) -> None:
+        self.pass_rows += valid
+        self.chips[c].counters["rows"] += valid
+
     def put(self, batch, mask, dtype):
-        """Host batch → ``device`` in one hop; ``jnp.asarray`` would land
-        it on device 0 whatever ``device`` is."""
+        """The next host batch → the chip whose turn it is, whole and in
+        one hop (``jnp.asarray`` would land it on device 0 whatever the
+        chip is). (chip index, device batch, device mask)."""
+        c = self.turn % len(self.chips)
+        chip = self.chips[c]
+        self.turn += 1
         with self.stage(SPAN_PUT, PHASE_PUT, "put_seconds_max"):
             x = np.asarray(batch, dtype=dtype)
-            x_dev = jax.device_put(x, self.device)
-            m_dev = None if mask is None else jax.device_put(mask, self.device)
-        valid = x.shape[0] if mask is None else int(mask.sum())
-        self.pass_rows += valid
+            x_dev = jax.device_put(x, chip.device)
+            m_dev = None if mask is None else jax.device_put(mask, chip.device)
+        self.put_rows = x.shape[0] if mask is None else int(mask.sum())
+        self._count_rows(c, self.put_rows)
         self.itemsize = x.itemsize
         self.counters["batches"] += 1
-        self.counters["rows_put"] += x.shape[0]  # padding crosses too
-        self.counters["bytes_put"] += x.nbytes
-        return x_dev, m_dev
+        for counters in (self.counters, chip.counters):
+            counters["rows_put"] += x.shape[0]  # padding crosses too
+            counters["bytes_put"] += x.nbytes
+        return c, x_dev, m_dev
 
     def allow_keep(self, batch_nbytes: int, gram_nbytes: int) -> None:
-        """Size the budget of ``keep`` from the device as it is now."""
-        self.keep_room = keep_budget_bytes(self._stats_device(),
-                                           batch_nbytes, gram_nbytes)
-        self.counters["keep_budget_bytes"] = self.keep_room
+        """Size each chip's budget of ``keep`` from the chip as it is now."""
+        for chip in self.chips:
+            chip.keep_room = keep_budget_bytes(chip.stats_device(),
+                                               batch_nbytes, gram_nbytes)
+            chip.counters["keep_budget_bytes"] = chip.keep_room
+        self.counters["keep_budget_bytes"] = sum(
+            chip.keep_room for chip in self.chips)
 
-    def keep(self, x_dev, m_dev) -> None:
-        """Hold the device batch just put for pass 2 if the budget has room
-        for it. Only a prefix of the pass is kept: the first batch that
-        finds no room closes the budget."""
-        if x_dev.nbytes > self.keep_room:
-            self.keep_room = 0
+    def keep(self, c: int, x_dev, m_dev) -> None:
+        """Hold the device batch just put on chip ``c`` for pass 2 if the
+        chip's budget has room for it. Only a prefix of a chip's batches is
+        kept: the first that finds no room closes that chip's budget."""
+        chip = self.chips[c]
+        if x_dev.nbytes > chip.keep_room:
+            chip.keep_room = 0
             return
-        self.keep_room -= x_dev.nbytes
-        self.kept.append((x_dev, m_dev))
-        self.kept_rows = self.pass_rows
-        self.counters["batches_kept"] += 1
-        self.counters["bytes_kept"] += x_dev.nbytes
+        chip.keep_room -= x_dev.nbytes
+        self.kept.append((self.turn - 1, c, self.put_rows, x_dev, m_dev))
+        for counters in (self.counters, chip.counters):
+            counters["batches_kept"] += 1
+            counters["bytes_kept"] += x_dev.nbytes
 
     def replay(self, source, dtype):
-        """Pass 2's device batches in pass 1's order: the kept prefix from
-        the chip — each reference dropped as it is handed out, so HBM drains
-        as the Gram steps run — then the rest of the source, put again. With
-        everything kept the source is not walked; otherwise the kept prefix's
-        host batches are passed over with their rows untouched."""
-        skip = len(self.kept)
-        everything = skip == self.counters["batches"]  # pass 1's puts so far
-        self.pass_rows = self.kept_rows
+        """Pass 2's device batches, each with its chip: the kept ones from
+        the chips in pass 1's order — each reference dropped as it is handed
+        out, so HBM drains as the Gram steps run — then the rest of the
+        source, dealt as in pass 1 and put again. With everything kept the
+        source is not walked; otherwise the kept host batches are passed
+        over with their rows untouched."""
+        kept_turns = {k[0] for k in self.kept}
+        everything = len(kept_turns) == self.counters["batches"]  # pass 1's
         while self.kept:
-            yield self.kept.popleft()
+            _, c, valid, x_dev, m_dev = self.kept.popleft()
+            self._count_rows(c, valid)
+            yield c, x_dev, m_dev
         if everything:
             return
-        for batch, mask in itertools.islice(self.batches(source), skip, None):
-            yield self.put(batch, mask, dtype)
+        for batch, mask in self.batches(source):
+            if self.turn in kept_turns:
+                self.turn += 1
+            else:
+                yield self.put(batch, mask, dtype)
 
     def accumulate(self, path: str):
         self.counters["accumulate_calls"][path] += 1
         return self.stage(SPAN_ACCUMULATE[path], PHASE_DISPATCH)
+
+    def collective(self, kind: str, nbytes: int):
+        """The dispatch of one all-reduce whose operand is ``nbytes`` a
+        chip."""
+        sent = self.counters["collective_bytes"]
+        sent[kind] = sent.get(kind, 0) + nbytes
+        current_fit().record_collective("all_reduce", nbytes=nbytes)
+        return self.stage(SPAN_COLLECTIVE[kind], PHASE_COLLECTIVE)
 
     def sync(self, span: str):
         """A place where the host blocks on a device value."""
@@ -458,6 +520,52 @@ class IngestTrace:
         current_fit().set_data(
             rows=self.pass_rows, features=n_features,
             nbytes=self.pass_rows * n_features * self.itemsize)
+
+
+# -- the two collectives of a fit over several chips ------------------------
+#
+# Each chip sums its own batches with the one-chip programs; the chips meet
+# twice (once in a one-pass fit). The mesh programs live in
+# ``parallel.mesh`` and are imported only by a fit that has several chips.
+
+def collective_mean(ingest: IngestTrace, mstats: list):
+    """Collective (a): the chips' (Σx, n) → (the mean of all rows on every
+    chip, the row count on the first)."""
+    from spark_rapids_ml_tpu.parallel import mesh as pm
+
+    devices = ingest.devices
+    mesh = pm.data_mesh(devices=devices)
+    col_sum = mstats[0].col_sum
+    with ingest.collective("mean", pm.collective_nbytes(
+            (col_sum.shape[0] + 1,), col_sum.dtype)):
+        mean, count = pm.all_reduce_mean(
+            pm.sharded_over(mesh, [s.col_sum for s in mstats]),
+            pm.sharded_over(mesh, [s.count.reshape(1) for s in mstats]),
+            mesh=mesh)
+    return pm.on_each_chip(mean, devices), pm.on_each_chip(count, devices)[0]
+
+
+def collective_sum(ingest: IngestTrace, parts: list):
+    """Collective (b): the sum over the chips of each chip's tuple of
+    accumulators (leaves with a leading axis), on the first chip."""
+    from spark_rapids_ml_tpu.parallel import mesh as pm
+
+    devices = ingest.devices
+    mesh = pm.data_mesh(devices=devices)
+    nbytes = sum(pm.collective_nbytes(a.shape, a.dtype) for a in parts[0])
+    with ingest.collective("gram", nbytes):
+        total = pm.all_reduce_sum(
+            tuple(pm.sharded_over(mesh, leaves) for leaves in zip(*parts)),
+            mesh=mesh)
+    return tuple(pm.on_each_chip(a, devices[:1])[0] for a in total)
+
+
+def collective_stats(ingest: IngestTrace, stats: list) -> GramStats:
+    """Collective (b) of one-pass accumulators: the chips' (Σxxᵀ, Σx, n)
+    summed, on the first chip."""
+    gram, col_sum, count = collective_sum(
+        ingest, [(s.gram, s.col_sum, s.count.reshape(1)) for s in stats])
+    return GramStats(gram, col_sum, count[0])
 
 
 def stream_covariance(
@@ -478,39 +586,58 @@ def stream_covariance(
     backend reports no memory, as on the CPU). The arithmetic is the same
     either way. Returns device arrays; covariance is normalized by n−1 as
     everywhere in this package.
-    ``ingest`` (an ``IngestTrace`` on the fit's ``PhaseTimer``) records the
-    stages; without one they are traced and counted all the same. Each Gram
-    step asks ``accumulate_path`` for its span's name and then calls
-    ``update_*_auto``, which asks again: the step stays the one function
-    other callers and the benchmark's fault tests reach.
+    ``device`` is one chip or a sequence of them. Over several, the host
+    batches are dealt to the chips whole and in turn; each chip sums its own
+    with the programs the one-chip fit runs, keeps its own batches under its
+    own budget, and the chips meet in two all-reduces: after pass 1 the
+    column sums and counts (every chip gets the mean of all rows), after
+    pass 2 the Grams (one covariance, on the first chip, where the results
+    are returned). A one-pass fit has the second only. One chip runs no mesh
+    program at all.
+    ``ingest`` (an ``IngestTrace`` on the fit's ``PhaseTimer``; it then names
+    the chips) records the stages; without one they are traced and counted
+    all the same. Each Gram step asks ``accumulate_path`` for its span's
+    name and then calls ``update_*_auto``, which asks again: the step stays
+    the one function other callers and the benchmark's fault tests reach.
     """
     if ingest is None:
         ingest = IngestTrace(device=device)
-    device = ingest.device
+    devices = ingest.devices
+    several = len(devices) > 1
     n = source.n_features
     if mean_centering and source.reiterable:
-        mstats = MeanStats(jnp.zeros((n,), dtype=dtype, device=device),
-                           jnp.zeros((), dtype=jnp.int32, device=device))
+        mstats = [MeanStats(jnp.zeros((n,), dtype=dtype, device=d),
+                            jnp.zeros((), dtype=jnp.int32, device=d))
+                  for d in devices]
         itemsize = jnp.dtype(dtype).itemsize
         ingest.allow_keep(source.batch_rows * n * itemsize, n * n * itemsize)
         try:
             with ingest.walk(SPAN_PASS_MEAN):
                 for batch, mask in ingest.batches(source):
-                    x_dev, m_dev = ingest.put(batch, mask, dtype)
+                    c, x_dev, m_dev = ingest.put(batch, mask, dtype)
                     with ingest.accumulate("mean"):
-                        mstats = update_mean_stats(mstats, x_dev, m_dev)
-                    ingest.keep(x_dev, m_dev)
-            count = mstats.count
-            mean = mstats.col_sum / count
-            gram_acc = jnp.zeros((n, n), dtype=dtype, device=device)
+                        mstats[c] = update_mean_stats(mstats[c], x_dev, m_dev)
+                    ingest.keep(c, x_dev, m_dev)
+            if several:
+                means, count = collective_mean(ingest, mstats)
+            else:
+                count = mstats[0].count
+                means = [mstats[0].col_sum / count]
+            grams = [jnp.zeros((n, n), dtype=dtype, device=d)
+                     for d in devices]
             with ingest.walk(SPAN_PASS_GRAM):
-                for x_dev, m_dev in ingest.replay(source, dtype):
+                for c, x_dev, m_dev in ingest.replay(source, dtype):
                     with ingest.accumulate(
-                            accumulate_path(gram_acc, x_dev, m_dev)):
-                        gram_acc = update_centered_gram_auto(
-                            gram_acc, x_dev, mean, m_dev, precision=precision)
+                            accumulate_path(grams[c], x_dev, m_dev)):
+                        grams[c] = update_centered_gram_auto(
+                            grams[c], x_dev, means[c], m_dev,
+                            precision=precision)
         finally:
             ingest.kept.clear()  # no kept batch outlives the walk over it
+        if several:
+            (gram_acc,) = collective_sum(ingest, [(g,) for g in grams])
+        else:
+            (gram_acc,) = grams
         with ingest.sync(SPAN_SYNC_COUNT):
             pass1_rows = int(count)
         if ingest.pass_rows != pass1_rows:
@@ -523,21 +650,26 @@ def stream_covariance(
             )
         ingest.set_data(n)
         denom = jnp.maximum(count - 1, 1)
-        return gram_acc / denom, mean, count
+        return gram_acc / denom, means[0], count
 
-    stats = init_stats(n, dtype=dtype, device=device)
+    stats = [init_stats(n, dtype=dtype, device=d) for d in devices]
     with ingest.walk(SPAN_PASS_STATS):
         for batch, mask in ingest.batches(source):
-            x_dev, m_dev = ingest.put(batch, mask, dtype)
-            with ingest.accumulate(accumulate_path(stats.gram, x_dev, m_dev)):
-                stats = update_stats_auto(stats, x_dev, m_dev,
-                                          precision=precision)
+            c, x_dev, m_dev = ingest.put(batch, mask, dtype)
+            with ingest.accumulate(
+                    accumulate_path(stats[c].gram, x_dev, m_dev)):
+                stats[c] = update_stats_auto(stats[c], x_dev, m_dev,
+                                             precision=precision)
     ingest.set_data(n)
+    if several:
+        total = collective_stats(ingest, stats)
+    else:
+        (total,) = stats
     cov = covariance_from_stats(
-        stats.gram, stats.col_sum, stats.count, mean_centering=mean_centering
+        total.gram, total.col_sum, total.count, mean_centering=mean_centering
     )
     if mean_centering:
-        mean = stats.col_sum / stats.count
+        mean = total.col_sum / total.count
     else:
-        mean = jnp.zeros_like(stats.col_sum)
-    return cov, mean, stats.count
+        mean = jnp.zeros_like(total.col_sum)
+    return cov, mean, total.count

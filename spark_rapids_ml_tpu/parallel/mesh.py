@@ -16,11 +16,14 @@ reserved for sharding the n×n Gram when n is too large for one device
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from spark_rapids_ml_tpu.obs.xprof import tracked_jit
 
 DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
@@ -84,6 +87,53 @@ def collective_nbytes(shape, dtype) -> int:
     return int(np.prod([int(s) for s in shape], dtype=np.int64)) * np.dtype(
         dtype
     ).itemsize
+
+
+# -- all-reduce of what each chip accumulated alone -------------------------
+#
+# A streamed fit over several chips (``ops.streaming.stream_covariance``)
+# runs one-chip programs on arrays committed to each chip and meets the
+# other chips only here. ``sharded_over`` hands the chips' arrays to a mesh
+# program as they stand (no copy: chip i's array IS shard i);
+# ``on_each_chip`` takes the replicated answer apart again.
+
+
+def sharded_over(mesh: Mesh, per_chip: Sequence) -> jax.Array:
+    """One array sharded over ``data`` on its leading axis whose shard on
+    the mesh's i-th device is ``per_chip[i]``, which lives there."""
+    first = per_chip[0]
+    shape = (len(per_chip) * first.shape[0],) + tuple(first.shape[1:])
+    return jax.make_array_from_single_device_arrays(
+        shape, NamedSharding(mesh, P(DATA_AXIS)), list(per_chip))
+
+
+def on_each_chip(replicated: jax.Array, devices: Sequence) -> list:
+    """The replicas of ``replicated`` on ``devices``, in their order."""
+    by_device = {s.device: s.data for s in replicated.addressable_shards}
+    return [by_device[d] for d in devices]
+
+
+@partial(tracked_jit, static_argnames=("mesh",))
+def all_reduce_sum(parts, *, mesh: Mesh):
+    """Sum of every chip's block of each leaf of ``parts``, on every chip:
+    one ``psum`` over ``data`` a leaf."""
+    fn = jax.shard_map(lambda t: jax.lax.psum(t, DATA_AXIS), mesh=mesh,
+                       in_specs=P(DATA_AXIS), out_specs=P())
+    return fn(parts)
+
+
+@partial(tracked_jit, static_argnames=("mesh",))
+def all_reduce_mean(col_sums, counts, *, mesh: Mesh):
+    """Every chip's (Σx, n) → the mean and the row count of all the
+    chips' rows, on every chip."""
+
+    def shard_fn(s, c):
+        total = jax.lax.psum(c, DATA_AXIS)[0]
+        return jax.lax.psum(s, DATA_AXIS) / total, total
+
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=P(DATA_AXIS),
+                       out_specs=P())
+    return fn(col_sums, counts)
 
 
 def pad_rows_to_multiple(x: np.ndarray, multiple: int):

@@ -307,12 +307,13 @@ def test_the_budget_leaves_three_batches_and_three_grams():
     assert streaming.keep_budget_bytes(device, 100, 10) == 900 - 300 - 30
     # and what is kept never passes it: 5 batches of 100 fit in 570
     ingest = streaming.IngestTrace()
-    ingest.keep_room = streaming.keep_budget_bytes(device, 100, 10)
+    ingest.chips[0].keep_room = streaming.keep_budget_bytes(device, 100, 10)
     batch = np.zeros((25,), dtype=np.float32)  # 100 bytes
     for _ in range(8):
-        ingest.pass_rows += 25
-        ingest.keep(batch, None)
-    assert len(ingest.kept) == 5 and ingest.kept_rows == 125
+        ingest.put_rows = 25
+        ingest.keep(0, batch, None)
+    assert len(ingest.kept) == 5
+    assert sum(rows for _, _, rows, _, _ in ingest.kept) == 125
     assert ingest.counters["bytes_kept"] == 500
 
 
