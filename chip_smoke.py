@@ -173,7 +173,10 @@ def report_fit(checks: Checks, name: str, model, platform: str,
     report = model.fit_report_
     memory = report.memory or {}
     log(f"  {name}: timings {json.dumps(model.fit_timings_)} "
-        f"solver {model.svd_solver_used_} compiles {report.compiles} "
+        f"solver {model.svd_solver_used_} "
+        f"solve {json.dumps(report.extra.get('solve'))} "
+        f"compiles {report.compiles} "
+        f"programs {report.programs_compiled}+{report.programs_fetched} "
         f"compile_seconds {report.compile_seconds:.2f} "
         f"wall {report.wall_seconds:.2f}s "
         f"memory {memory.get('source')} peak {memory.get('peak_bytes')}")
@@ -192,10 +195,11 @@ def report_fit(checks: Checks, name: str, model, platform: str,
 
 
 def report_kernels(checks: Checks, platform: str) -> None:
-    """Which Gram kernels compiled. On the TPU the Pallas kernel must have
+    """Which kernels compiled. On the TPU the Pallas kernel must have
     compiled for both fits and the XLA ``dot_general`` accumulate must not
-    (every batch is full and tile-aligned); no tracked kernel may have
-    fallen off its AOT executable."""
+    (every batch is full and tile-aligned); both fits' solves are one
+    randomized program whose gate passed, so no dense ``eigh``; no tracked
+    kernel may have fallen off its AOT executable."""
     from spark_rapids_ml_tpu import obs
     from spark_rapids_ml_tpu.obs.xprof import fallback_signatures
 
@@ -215,6 +219,10 @@ def report_kernels(checks: Checks, platform: str) -> None:
         checks.that("XLA Gram accumulate did not compile",
                     compiles("update_centered_gram") == 0
                     and compiles("update_stats") == 0)
+        checks.that("the gated solve compiled as one program, and no "
+                    "dense eigh beside it",
+                    compiles("_randomized_solve_program") == 1
+                    and compiles("_dense_solve_program") == 0)
     else:
         checks.that("streamed fit compiled the XLA accumulate",
                     compiles("update_centered_gram") >= 1)
@@ -585,7 +593,7 @@ def multichip(checks: Checks, x: np.ndarray, one_shot, shape: Shape,
     rows and against the one-chip fit: the streamed fit — the one loop
     (``ops.streaming.stream_covariance``) with whole batches dealt to the
     chips in turn, the Pallas Gram on every chip, two all-reduces and the
-    solve run eagerly on the first chip — and both all-reduce schedules of
+    solve's one program on the first chip — and both all-reduce schedules of
     the one-shot mesh program (XLA Gram under shard_map, not the Pallas
     kernel).
 
@@ -607,8 +615,8 @@ def multichip(checks: Checks, x: np.ndarray, one_shot, shape: Shape,
     # was the streamed fit as a mesh program with the solve compiled into it
     # (one jitted program with ten 266² eigh calls), which it no longer is:
     # through the shared loop this check takes 7.5 s the first time (the
-    # per-chip programs' first dispatch; the eager solve's programs came
-    # with the one-chip fit before it) and 0.11 s the second (PR 28, this
+    # per-chip programs' first dispatch; the solve's programs came with
+    # the one-chip fit before it) and 0.11 s the second (PR 28, this
     # check alone on four chips). The one-pass mesh program still compiles
     # its solve (57 s).
     mesh = data_mesh(n_devices)

@@ -65,9 +65,9 @@ class FitReport:
     compiles: int = 0
     recompiles: int = 0
     compile_seconds: float = 0.0
-    # every executable JAX built during the fit, tracked_jit or not (the
-    # eager solve): compiled by the backend / fetched from the persistent
-    # cache (obs.xprof's jax.monitoring listener)
+    # every executable JAX built during the fit, tracked_jit or not (eager
+    # ops): compiled by the backend / fetched from the persistent cache
+    # (obs.xprof's jax.monitoring listener)
     programs_compiled: int = 0
     programs_fetched: int = 0
     # Device-memory watermark (obs.memory; host RSS on statless backends)

@@ -17,8 +17,8 @@ makes XLA compilation a first-class observable instead of an invisible tax:
   sizes** per signature (``CompileEvent``), handed on every executed call
   to the serving report and the fit-path monitor (``obs.fitmon``).
 
-What ``tracked_jit`` cannot see — programs JAX builds for eager ops, the
-PCA solve among them — reaches the active fit through one
+What ``tracked_jit`` cannot see — programs JAX builds for eager ops —
+reaches the active fit through one
 ``jax.monitoring`` listener (``_on_executable_built``) as
 ``FitReport.programs_compiled`` / ``programs_fetched``.
 

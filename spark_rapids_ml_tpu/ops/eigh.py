@@ -6,6 +6,15 @@ Replaces the reference's driver-GPU ``calSVD`` native kernel
 whole chain — ``eigh``, descending reorder, sign-flip, explained-variance —
 is one jitted program; XLA fuses the postprocessing into a few vector ops.
 
+Two ways in. ``pca_from_covariance`` is the plain traceable function that
+kernels inline under their own ``jit``. ``pca_from_covariance_gated`` is
+what the fit paths call with a concrete covariance: whichever solver it
+settles on runs as ONE tracked program (``_randomized_solve_program`` with
+the residual gate's arithmetic inside, or ``_dense_solve_program``); the
+host reads the gate's two scalars once and only then, on failure, calls
+the dense program — so a fit whose gate passes never compiles the dense
+``eigh`` (≈4.5 min at n = 4096 on the v5e).
+
 Semantic corrections vs the reference (SURVEY.md §3.6):
 * explained variance is λ/Σλ (Spark CPU semantics), not √λ/Σ√λ
   (the reference GPU path's known inconsistency,
@@ -17,9 +26,14 @@ Semantic corrections vs the reference (SURVEY.md §3.6):
 
 from __future__ import annotations
 
-from typing import Tuple
+from functools import partial
+from typing import Callable, Tuple
 
+import jax
 import jax.numpy as jnp
+
+from spark_rapids_ml_tpu.obs.report import current_fit
+from spark_rapids_ml_tpu.obs.xprof import tracked_jit
 
 
 def eigh_descending(cov: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -134,6 +148,31 @@ def pca_from_covariance(
     return evecs[:, :k], evr[:k]
 
 
+@partial(tracked_jit, static_argnames=("k", "flip_signs"))
+def _dense_solve_program(cov, k, flip_signs):
+    """The dense branch as one program: ``eigh``, reorder, sign-flip,
+    λ/Σλ and the top-k slice."""
+    return pca_from_covariance(cov, k, flip_signs, "eigh")
+
+
+@partial(tracked_jit, static_argnames=("k", "flip_signs", "solve"))
+def _randomized_solve_program(cov, k, flip_signs, solve: Callable):
+    """The randomized branch and the gate's arithmetic as one program:
+    ``(pc, evr, residual_ratio, min_column_norm²)``. ``solve`` is
+    ``ops.randomized.randomized_pca_from_covariance`` as the caller found
+    it in the module; being static it keys the program, so a replaced
+    solver (``benchmarks/sweep.py`` plants one) is traced anew instead of
+    answered from the cache under the replacement's name."""
+    trace = jnp.trace(cov)
+    pc, evr = solve(cov, k, trace, flip_signs=flip_signs)
+    lam = evr * trace
+    resid = jnp.linalg.norm(cov @ pc - pc * lam[None, :])
+    scale = jnp.sqrt(jnp.asarray(k, cov.dtype)) * jnp.maximum(
+        jnp.mean(lam), jnp.finfo(cov.dtype).tiny
+    )
+    return pc, evr, resid / scale, jnp.min(jnp.sum(pc * pc, axis=0))
+
+
 def pca_from_covariance_gated(
     cov: jnp.ndarray,
     k: int,
@@ -143,15 +182,16 @@ def pca_from_covariance_gated(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, str]:
     """``pca_from_covariance`` with the eigh-vs-randomized residual gate.
 
-    Host-driven (one scalar D2H read), so only for eager call sites — the
-    model fit paths and ``finalize_stats``, not jitted kernels. When the
-    shape heuristic picks randomized, the eigenpair residual
-    ``‖Cov·V − V·Λ‖_F / (√k · mean(λ))`` is checked on device; if it
-    exceeds ``residual_rtol`` (catastrophic non-convergence — a slow-decay
-    tail the subspace iteration didn't capture), the dense eigh result is
-    computed and returned instead. Sub-threshold wobble on near-degenerate
-    spectra is rotation within an eigenvalue cluster — a legitimate PCA
-    basis capturing the same variance — and intentionally passes.
+    For call sites that hold a concrete covariance — the model fit paths,
+    not jitted kernels: the verdict is read on the host (one read of two
+    scalars). When the shape heuristic picks randomized, the eigenpair
+    residual ``‖Cov·V − V·Λ‖_F / (√k · mean(λ))`` is computed in the
+    solve's own program; if it exceeds ``residual_rtol`` (catastrophic
+    non-convergence — a slow-decay tail the subspace iteration didn't
+    capture), the dense eigh program is run and its result returned
+    instead. Sub-threshold wobble on near-degenerate spectra is rotation
+    within an eigenvalue cluster — a legitimate PCA basis capturing the
+    same variance — and intentionally passes.
 
     The residual alone is blind to a DROPPED direction: a zero column has
     zero residual. The orthonormalization zeroes what float32 cannot
@@ -160,10 +200,10 @@ def pca_from_covariance_gated(
     column — a spectrum steeper than the whitening's range, or k beyond
     rank(Cov) — takes the dense fallback too.
 
-    Returns ``(components, evr, solver_used)``.
+    Returns ``(components, evr, solver_used)`` and notes
+    ``solve={solver, gate, residual_ratio, programs}`` on the fit's report
+    (``programs``: tracked programs called, 2 after a fallback).
     """
-    import jax
-
     if solver == "auto":
         solver = resolve_auto_solver(cov.shape[0], k)
     if isinstance(cov, jax.core.Tracer):
@@ -171,21 +211,28 @@ def pca_from_covariance_gated(
         # choice ungated (same behavior as pca_from_covariance('auto'))
         pc, evr = pca_from_covariance(cov, k, flip_signs, solver)
         return pc, evr, solver
-    if solver != "randomized":
-        pc, evr = pca_from_covariance(cov, k, flip_signs, solver)
-        return pc, evr, solver
-    pc, evr = pca_from_covariance(cov, k, flip_signs, "randomized")
-    trace = jnp.trace(cov)
-    lam = evr * trace
-    resid = jnp.linalg.norm(cov @ pc - pc * lam[None, :])
-    scale = jnp.sqrt(jnp.asarray(k, cov.dtype)) * jnp.maximum(
-        jnp.mean(lam), jnp.finfo(cov.dtype).tiny
-    )
-    complete = jnp.min(jnp.sum(pc * pc, axis=0)) > 0.5
-    # one host read for both verdicts; the comparison is inverted so
-    # NaN/inf residuals (overflowed solve) FAIL the gate rather than
-    # slipping through a `NaN > rtol` == False
-    if not bool((resid / scale <= residual_rtol) & complete):
-        pc, evr = pca_from_covariance(cov, k, flip_signs, "eigh")
-        return pc, evr, "eigh(gated)"
-    return pc, evr, "randomized"
+    if solver not in ("eigh", "randomized"):
+        # the plain function's to refuse, before any program
+        return (*pca_from_covariance(cov, k, flip_signs, solver), solver)
+    ratio, programs = None, 1
+    if solver == "eigh":
+        pc, evr = _dense_solve_program(cov, k, flip_signs)
+        used, gate = "eigh", "ungated"
+    else:
+        from spark_rapids_ml_tpu.ops import randomized
+
+        pc, evr, ratio, min_norm2 = _randomized_solve_program(
+            cov, k, flip_signs, randomized.randomized_pca_from_covariance
+        )
+        ratio, min_norm2 = (
+            float(v) for v in jax.device_get((ratio, min_norm2)))
+        used, gate = "randomized", "passed"
+        # the comparison is inverted so NaN/inf residuals (overflowed
+        # solve) FAIL the gate rather than slipping through a
+        # `NaN > rtol` == False
+        if not (ratio <= residual_rtol and min_norm2 > 0.5):
+            pc, evr = _dense_solve_program(cov, k, flip_signs)
+            used, gate, programs = "eigh(gated)", "fallback", 2
+    current_fit().note(solve={"solver": used, "gate": gate,
+                              "residual_ratio": ratio, "programs": programs})
+    return pc, evr, used

@@ -12,7 +12,15 @@ device ever holds the full n×n (``parallel/feature_sharded.py``) — the
 "feature-dimension scaling" answer sketched in SURVEY.md §5.
 
 All iteration counts are static, so the whole solve jit-compiles into one
-XLA program (QR + matmul chain) with no host round trips.
+XLA program with no host round trips; on the fit path
+``ops.eigh.pca_from_covariance_gated`` runs it, the gate's arithmetic
+included, as one tracked program. The TPU compiler expands
+every ``eigh`` call site on its own, so the iteration is written rolled:
+the power iterations are one ``lax.fori_loop`` and the two whitening
+passes inside it another, which leaves two ``eigh`` sites (the
+whitening's and Rayleigh-Ritz) where the unrolled form had eleven —
+compiled for a v5e at n = 4096, k = 256: 23 s and 50 MB of code against
+78 s and 267 MB (PERF.md §6, PR 30). Same operations in the same order.
 
 Accuracy caveat (inherent to randomized methods, same as sklearn's
 ``svd_solver='randomized'``): individual eigenvectors converge at a rate set
@@ -31,6 +39,7 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from spark_rapids_ml_tpu.ops.eigh import eigh_descending, sign_flip
 
@@ -61,7 +70,7 @@ def _orthonormalize(y: jnp.ndarray) -> jnp.ndarray:
     eps = jnp.asarray(jnp.finfo(y.dtype).eps, y.dtype)
     tiny = jnp.asarray(jnp.finfo(y.dtype).tiny, y.dtype)
 
-    def whiten(y, drop_unresolved):
+    def whiten(i, y):
         b = y.T @ y
         b = (b + b.T) / 2
         evals, vecs = jnp.linalg.eigh(b)
@@ -69,11 +78,12 @@ def _orthonormalize(y: jnp.ndarray) -> jnp.ndarray:
         # columns, not 0·inf = NaN
         floor = jnp.maximum(evals[-1] * eps * y.shape[0], tiny)
         inv_sqrt = 1.0 / jnp.sqrt(jnp.maximum(evals, floor))
-        if drop_unresolved:
-            inv_sqrt = jnp.where(evals > floor, inv_sqrt, 0.0)
+        # pass 0 clamps, pass 1 drops what is still unresolved
+        inv_sqrt = jnp.where((i == 0) | (evals > floor), inv_sqrt, 0.0)
         return y @ (vecs * inv_sqrt[None, :])
 
-    return whiten(whiten(y, False), True)
+    # a loop of two, not two calls: one eigh site to compile
+    return lax.fori_loop(0, 2, whiten, y)
 
 
 def subspace_iteration(
@@ -98,11 +108,16 @@ def subspace_iteration(
     with jax.default_matmul_precision("highest"):
         omega = jax.random.normal(key, (n, l), dtype=dtype)
         y = matvec(omega)
-        for _ in range(max(n_iter, 0)):
-            q = _orthonormalize(y)
-            y = matvec(q)
-        q = _orthonormalize(y)
-        b = q.T @ matvec(q)
+
+        def step(_, carry):
+            q = _orthonormalize(carry[1])
+            return q, matvec(q)
+
+        # n_iter power iterations and the closing orthonormalization in
+        # one loop; its last Cov·Q is the product Rayleigh-Ritz needs
+        q, y = lax.fori_loop(
+            0, max(n_iter, 0) + 1, step, (jnp.zeros_like(y), y))
+        b = q.T @ y
         b = (b + b.T) / 2  # exact symmetry for eigh
         evals, vecs = eigh_descending(b)
         return evals, q @ vecs

@@ -30,7 +30,7 @@ from spark_rapids_ml_tpu.obs import (
     current_run,
     fit_instrumentation,
 )
-from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance
+from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance_gated
 from spark_rapids_ml_tpu.ops.pca_kernel import PCAFitResult
 from spark_rapids_ml_tpu.ops.streaming import (
     GramStats,
@@ -112,7 +112,8 @@ def distributed_streaming_pca_fit(
     """Out-of-core fit of a ``data.batches.BatchSource`` over a mesh's
     chips through ``stream_covariance``: two passes and two all-reduces for
     a re-iterable source that is centred, one of each otherwise. The solve
-    (``solver``, not gated) runs eagerly on the first chip.
+    (``solver``, through the residual gate as in ``PCA.fit``) is one
+    program on the first chip.
     """
     d = mesh.devices.size
     if source.batch_rows % d:  # the same contract as ``partial_fit``'s
@@ -131,6 +132,6 @@ def distributed_streaming_pca_fit(
     if mean_centering and rows < 2:
         raise ValueError("mean centering requires more than one row")
     with ctx.phase("finalize"), current_run().step("finalize", rows=rows):
-        components, evr = jax.block_until_ready(
-            pca_from_covariance(cov, k, flip_signs=True, solver=solver))
+        components, evr, _ = pca_from_covariance_gated(cov, k, solver=solver)
+        jax.block_until_ready((components, evr))
     return PCAFitResult(components, evr, mean)
