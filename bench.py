@@ -189,8 +189,8 @@ def main() -> None:
     xla_rows_per_sec = None
     if os.environ.get("BENCH_COMPARE_PALLAS", "1") == "1":
         from spark_rapids_ml_tpu.ops.streaming import (
-            fused_update_applicable,
-            update_stats_fused,
+            _update_stats_fused_blocked,
+            accumulate_path,
         )
 
         def _arm_rate(step_fn):
@@ -206,11 +206,11 @@ def main() -> None:
             return round(asteps * batch / (time.perf_counter() - t0), 1)
 
         probe_stats = init_stats(cols, dtype=jnp.float32, device=device)
-        if fused_update_applicable(probe_stats.gram, x_batch, None):
-            pallas_rows_per_sec = _arm_rate(update_stats_fused)
+        if accumulate_path(probe_stats.gram, x_batch, None) == "pallas":
+            pallas_rows_per_sec = _arm_rate(_update_stats_fused_blocked)
         else:
             print("# pallas gram arm skipped: shape not applicable "
-                  "(update_stats_fused needs tile-aligned f32 batches)",
+                  "(the Pallas Gram needs tile-aligned f32 batches)",
                   flush=True)
         xla_rows_per_sec = _arm_rate(update_stats)
 

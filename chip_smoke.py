@@ -5,9 +5,9 @@ One process drives the repo's main path once, through the entry points a
 user calls, at the full width of the model every chip record so far is
 about — PCA at 4096 features, k=256:
 
-    PCA().fit (one-shot, Pallas fused Gram)  →  PCA().fit (streamed, 16
-    donated Pallas accumulate steps)  →  PCAModel.transform  →  the same
-    model behind ServeEngine and the HTTP server (JSON and binary wire)
+    PCA().fit (in-memory matrix, 4 donated Pallas accumulate steps)  →
+    PCA().fit (streamed chunks, 16 of them)  →  PCAModel.transform  →  the
+    same model behind ServeEngine and the HTTP server (JSON and binary wire)
 
 and checks what comes out against a NumPy float64 oracle, outside any
 timing. Weights are whatever the fit finds on seeded random rows. With
@@ -46,13 +46,13 @@ import numpy as np
 class Shape:
     n_features: int = 4096
     k: int = 256
-    # 32768×4096 f32 = 512 MiB: under the 1 GiB stream threshold
-    # (data/batches.py), so PCA.fit takes the one-shot branch, and a
-    # multiple of the kernel's 1024-row block, so nothing is padded.
-    one_shot_rows: int = 32_768
-    # auto_batch_rows(4096) = 8192, tile-aligned → the Pallas accumulate.
+    # 32768×4096 f32 = 512 MiB, which PCA.fit walks as four views of
+    # auto_batch_rows(4096) = 8192 rows: whole batches of the kernel's
+    # 1024-row block, so nothing is padded or masked.
+    in_memory_rows: int = 32_768
+    # the same 8192, tile-aligned → the Pallas accumulate, the one program
     stream_batch_rows: int = 8_192
-    # The stream is the one-shot rows cycled this many times: 4 blocks × 4
+    # The stream is the in-memory rows cycled this many times: 4 blocks × 4
     # = 16 accumulate steps over 131072 rows. Every block carries the same
     # weight, so the two fits have the same components and variance ratios
     # by construction (the covariances differ by the factor
@@ -148,7 +148,9 @@ def oracle_pca(x: np.ndarray, k: int):
 # -- trainer ------------------------------------------------------------------
 
 
-def fit_one_shot(x: np.ndarray, k: int):
+def fit_in_memory(x: np.ndarray, k: int):
+    """``fit(matrix)``: the same ``stream_covariance`` over views of ``x``,
+    ``batchRows`` left to size itself."""
     from spark_rapids_ml_tpu import PCA
 
     return PCA().setK(k).fit(x)
@@ -194,12 +196,13 @@ def report_fit(checks: Checks, name: str, model, platform: str,
                     f"peak_bytes={memory.get('peak_bytes')}")
 
 
-def report_kernels(checks: Checks, platform: str) -> None:
-    """Which kernels compiled. On the TPU the Pallas kernel must have
-    compiled for both fits and the XLA ``dot_general`` accumulate must not
-    (every batch is full and tile-aligned); both fits' solves are one
-    randomized program whose gate passed, so no dense ``eigh``; no tracked
-    kernel may have fallen off its AOT executable."""
+def report_kernels(checks: Checks, platform: str, in_memory) -> None:
+    """Which kernels compiled. On the TPU the Pallas accumulate must have
+    compiled, the in-memory fit's Gram steps must all have gone through it
+    (it is the streamed loop) and the XLA ``dot_general`` accumulate must
+    not have compiled (every batch is full and tile-aligned); both fits'
+    solves are one randomized program whose gate passed, so no dense
+    ``eigh``; no tracked kernel may have fallen off its AOT executable."""
     from spark_rapids_ml_tpu import obs
     from spark_rapids_ml_tpu.obs.xprof import fallback_signatures
 
@@ -211,9 +214,12 @@ def report_kernels(checks: Checks, platform: str) -> None:
     def compiles(label: str) -> int:
         return stats.get(label, {}).get("compiles", 0)
 
+    calls = in_memory.fit_report_.extra["ingest"]["accumulate_calls"]
     if platform == "tpu":
-        checks.that("one-shot fit compiled the Pallas fused Gram",
-                    compiles("_fused_centered_gram") >= 1)
+        checks.that("in-memory fit ran the streamed Pallas accumulate",
+                    calls["pallas"] > 0 and calls["xla"] == 0
+                    and compiles("_update_centered_gram_fused_blocked") >= 1,
+                    json.dumps(calls))
         checks.that("streamed fit compiled the Pallas accumulate",
                     compiles("_update_centered_gram_fused_blocked") >= 1)
         checks.that("XLA Gram accumulate did not compile",
@@ -224,6 +230,9 @@ def report_kernels(checks: Checks, platform: str) -> None:
                     compiles("_randomized_solve_program") == 1
                     and compiles("_dense_solve_program") == 0)
     else:
+        checks.that("in-memory fit ran the streamed XLA accumulate",
+                    calls["xla"] > 0 and calls["pallas"] == 0,
+                    json.dumps(calls))
         checks.that("streamed fit compiled the XLA accumulate",
                     compiles("update_centered_gram") >= 1)
     fallbacks = fallback_signatures()
@@ -302,22 +311,22 @@ def check_against_oracle(checks: Checks, model, oracle, shape: Shape,
                    1.0 - captured, bars["missed"])
 
 
-def check_fits_agree(checks: Checks, one_shot, streamed, shape: Shape,
+def check_fits_agree(checks: Checks, in_memory, streamed, shape: Shape,
                      bars: dict) -> None:
-    a, b = np.asarray(one_shot.pc), np.asarray(streamed.pc)
+    a, b = np.asarray(in_memory.pc), np.asarray(streamed.pc)
     top = min(shape.top, shape.k)
-    checks.at_most(f"streamed vs one-shot: top-{top} components max abs diff",
+    checks.at_most(f"streamed vs in-memory: top-{top} components max abs diff",
                    _aligned_diff(a[:, :top], b[:, :top]), bars["pc_top"])
     checks.at_most(
-        "streamed vs one-shot: variance ratio relative diff",
+        "streamed vs in-memory: variance ratio relative diff",
         float(np.max(np.abs(np.asarray(streamed.explained_variance)
-                            / np.asarray(one_shot.explained_variance) - 1.0))),
+                            / np.asarray(in_memory.explained_variance) - 1.0))),
         bars["evr_all"])
     overlap = float(np.linalg.norm(a.T @ b) ** 2 / shape.k)
-    checks.at_most("streamed vs one-shot: |1 − subspace overlap|",
+    checks.at_most("streamed vs in-memory: |1 − subspace overlap|",
                    abs(1.0 - overlap), bars["subspace"])
-    checks.at_most("streamed vs one-shot: mean abs diff",
-                   float(np.max(np.abs(one_shot.mean - streamed.mean))),
+    checks.at_most("streamed vs in-memory: mean abs diff",
+                   float(np.max(np.abs(in_memory.mean - streamed.mean))),
                    bars["mean"])
 
 
@@ -587,7 +596,7 @@ def fence_check(checks: Checks, x: np.ndarray, shape: Shape,
 # -- several chips ------------------------------------------------------------
 
 
-def multichip(checks: Checks, x: np.ndarray, one_shot, shape: Shape,
+def multichip(checks: Checks, x: np.ndarray, in_memory, shape: Shape,
               n_devices: int, bars: dict) -> None:
     """The fits over all the chips, each checked for an even split of the
     rows and against the one-chip fit: the streamed fit — the one loop
@@ -622,7 +631,7 @@ def multichip(checks: Checks, x: np.ndarray, one_shot, shape: Shape,
     mesh = data_mesh(n_devices)
     want_rows = x.shape[0] // n_devices
     top = min(shape.top, shape.k)
-    ref = np.asarray(one_shot.pc)[:, :top]
+    ref = np.asarray(in_memory.pc)[:, :top]
 
     def check(name: str, result) -> None:
         report = result.fit_report_
@@ -728,27 +737,27 @@ def main() -> int:
         seconds[name] = round(time.perf_counter() - t0, 2)
 
     with phase("rows"):
-        x = make_rows(shape.one_shot_rows, shape.n_features)
+        x = make_rows(shape.in_memory_rows, shape.n_features)
 
-    with phase("fit one-shot"):
-        one_shot = fit_one_shot(x, shape.k)
-    report_fit(checks, "one-shot", one_shot, "tpu", shape.n_features)
+    with phase("fit in-memory"):
+        in_memory = fit_in_memory(x, shape.k)
+    report_fit(checks, "in-memory", in_memory, "tpu", shape.n_features)
 
     with phase("fit streamed"):
         streamed = fit_streamed(x, shape.k, shape.stream_batch_rows,
                                 shape.stream_cycles)
     report_fit(checks, "streamed", streamed, "tpu", shape.n_features)
-    report_kernels(checks, "tpu")
+    report_kernels(checks, "tpu", in_memory)
 
     with phase("oracle (NumPy float64, host)"):
         oracle = oracle_pca(x, shape.k)
     with phase("correctness"):
-        check_against_oracle(checks, one_shot, oracle, shape, ORACLE_BARS)
-        check_fits_agree(checks, one_shot, streamed, shape, AGREE_BARS)
-        check_transform(checks, one_shot, x, PROJECTION_BAR)
+        check_against_oracle(checks, in_memory, oracle, shape, ORACLE_BARS)
+        check_fits_agree(checks, in_memory, streamed, shape, AGREE_BARS)
+        check_transform(checks, in_memory, x, PROJECTION_BAR)
 
     with phase("serve"):
-        serve_requests(checks, one_shot, x, shape, "tpu", device["count"],
+        serve_requests(checks, in_memory, x, shape, "tpu", device["count"],
                        PROJECTION_BAR)
 
     with phase("fence"):
@@ -756,7 +765,7 @@ def main() -> int:
 
     if device["count"] > 1:
         with phase(f"multichip ({device['count']} devices)"):
-            multichip(checks, x, one_shot, shape, device["count"],
+            multichip(checks, x, in_memory, shape, device["count"],
                       AGREE_BARS)
     else:
         log("multichip: skipped (1 device)")

@@ -55,5 +55,6 @@ print("logreg n_iter:", log.n_iter_)
 km = KMeans().setK(4).fit(chunks)
 print("kmeans cost:", round(km.training_cost_, 1))
 
-# Oversized IN-MEMORY inputs stream automatically once they exceed
-# TPUML_STREAM_THRESHOLD_BYTES (default 1 GiB) — no API change needed.
+# An IN-MEMORY input needs no API change either: PCA.fit walks a matrix in
+# the same batches whatever its size; LinearRegression and KMeans stream one
+# once it exceeds TPUML_STREAM_THRESHOLD_BYTES (default 1 GiB).

@@ -26,7 +26,8 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 # In-memory inputs larger than this stream through the device accumulator in
-# batch_rows buckets instead of one whole-matrix device_put. Default 1 GiB:
+# batch_rows buckets instead of one whole-matrix device_put (LinearRegression
+# and KMeans; PCA streams every input and does not ask). Default 1 GiB:
 # comfortably under a v5e chip's HBM while keeping small fits single-shot.
 STREAM_THRESHOLD_ENV = "TPUML_STREAM_THRESHOLD_BYTES"
 DEFAULT_STREAM_THRESHOLD = 1 << 30

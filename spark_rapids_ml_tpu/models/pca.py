@@ -9,12 +9,21 @@ require k ≤ numFeatures → covariance → eigensolve → model,
 subtraction, ``RapidsPCA.scala:187-189``), same persistence layout
 (metadata JSON + Parquet payload, ``RapidsPCA.scala:218-254``).
 
+``fit`` has one body for every input. A callable or an iterator of chunks
+is a stream as it comes; an array, a frame or a list of vectors is made a
+matrix and walked as views of it (``data.batches.BatchSource``: one batch
+of exactly its rows when it is under ``batchRows``). The stream goes through
+``ops.streaming.stream_covariance`` on the chips of ``numDevices`` and the
+covariance through ``ops.eigh.pca_from_covariance_gated``; ``useXlaDot`` and
+``useXlaSvd`` move either stage to the host, nothing else forks.
+
 TPU-first differences (all documented in SURVEY.md §3.6/§7):
 * ``useGemm``/``useCuSolverSVD`` become ``useXlaDot``/``useXlaSvd``: True
-  runs the jit-compiled XLA path on the selected accelerator; False runs the
-  host fallback (native C++ ``libtpuml`` when built, NumPy/LAPACK otherwise)
-  — mirroring the reference's GPU/CPU path toggles but never requiring the
-  native library for CPU-only runs (fixes the §3.4 coupling).
+  runs that stage's jit-compiled programs on the selected accelerator; False
+  runs it on the host in float64 (NumPy, and native C++ ``libtpuml`` LAPACK
+  for a small eigensolve when built) — mirroring the reference's GPU/CPU
+  path toggles but never requiring the native library for CPU-only runs
+  (fixes the §3.4 coupling).
 * batched on-device transform is ENABLED (the reference left it commented
   out pending perf work, ``RapidsPCA.scala:172-190``).
 * covariance normalizes by numRows−1 on every path and ``meanCentering=False``
@@ -48,7 +57,7 @@ from spark_rapids_ml_tpu.utils.numeric import (
 from spark_rapids_ml_tpu.utils.timing import PhaseTimer
 from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
 
-# Host spans of the streamed fit, outermost first; the stages inside
+# Host spans of the fit, outermost first; the stages inside
 # SPAN_STREAMED_COV are ops.streaming.STREAM_SPANS. The benchmark reads a
 # trace by these names (benchmarks/work/spans.py).
 SPAN_FIT = "fit:pca"  # opened by @observed_fit("pca")
@@ -77,8 +86,9 @@ class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
     )
     useXlaDot = Param(
         "useXlaDot",
-        "covariance via XLA on the accelerator (True) or host fallback "
-        "(False); analogue of the reference's useGemm",
+        "covariance accumulated on the accelerator, batch by batch (True), "
+        "or on the host in float64 (False); analogue of the reference's "
+        "useGemm",
         True,
         validator=lambda v: isinstance(v, bool),
     )
@@ -105,17 +115,18 @@ class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
         "matmuls instead of O(n^3) — ~100x faster at n=4096 k=256, "
         "per-vector accuracy depends on spectral gaps; see "
         "ops/randomized.py) or 'auto' (randomized when k<<n on large "
-        "covariances, residual-gated with dense-eigh fallback on eager "
-        "paths — see ops.eigh.pca_from_covariance_gated; the model "
-        "records the choice in svd_solver_used_). Host fallbacks "
-        "(useXlaSvd=False) always use dense LAPACK regardless.",
+        "covariances, residual-gated with dense-eigh fallback — see "
+        "ops.eigh.pca_from_covariance_gated; the model records the choice "
+        "in svd_solver_used_). The host solve (useXlaSvd=False) always "
+        "uses dense LAPACK regardless.",
         "auto",
         validator=lambda v: v in ("auto", "eigh", "randomized"),
     )
     batchRows = Param(
         "batchRows",
-        "rows per streamed device batch for out-of-core fits; 0 = auto-size "
-        "so one f32 batch is ~128 MiB",
+        "rows per device batch of the fit's stream, whatever form the rows "
+        "come in; 0 = auto-size so one f32 batch is ~128 MiB (a matrix "
+        "under that is one batch of exactly its rows)",
         0,
         validator=lambda v: isinstance(v, int) and v >= 0,
     )
@@ -127,9 +138,8 @@ class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
         "'auto' (default) defers to TPUML_GRAM_PRECISION (bfloat16_3x: "
         "3-pass bf16 split with f32 accumulation — measured numerically "
         "indistinguishable from 'highest' on the covariance oracle, "
-        "~1.3x faster). 'bfloat16' opts into the single-pass bf16 arm — "
-        "the chip's measured ceiling (records/r04/gram_sweep.json: "
-        "MFU 0.92) with a RELAXED accuracy contract: covariance error "
+        "~1.3x faster). 'bfloat16' opts into the single-pass bf16 arm, "
+        "with a RELAXED accuracy contract: covariance error "
         "grows with conditioning, so use it when the spectrum is "
         "well-separated and ~1e-2 relative component error is "
         "acceptable. 'float32'/'highest' force full-precision passes.",
@@ -138,7 +148,7 @@ class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
     )
     numDevices = Param(
         "numDevices",
-        "how many of the process's local chips a streamed fit may use: the "
+        "how many of the process's local chips a fit may use: the "
         "chip deviceId resolves to and the next numDevices-1 local ones "
         "(the in-process form of spark.executor.resource.tpu.amount). "
         "Host batches are dealt to the chips whole and in turn, each chip "
@@ -218,7 +228,7 @@ def _resolve_device(device_id: int):
 
 
 def _resolve_devices(device_id: int, num_devices: int):
-    """The chips of a streamed fit: ``_resolve_device(device_id)`` and the
+    """The chips of a fit: ``_resolve_device(device_id)`` and the
     ``num_devices - 1`` local chips after it."""
     first = _resolve_device(device_id)
     if num_devices == 1:
@@ -272,58 +282,33 @@ class PCA(PCAParams):
         if k is None:
             raise ValueError("k must be set before fit()")
 
-        use_xla_dot = self.getUseXlaDot()
-        use_xla_svd = self.getUseXlaSvd()
+        from spark_rapids_ml_tpu.data.batches import BatchSource, streaming_source
 
-        from spark_rapids_ml_tpu.data.batches import streaming_source
-
+        # Every input is a stream of fixed-shape batches: a callable or an
+        # iterator of chunks as it comes, anything else densified and walked
+        # as views of the matrix (one batch of exactly its rows when it is
+        # under batchRows) — the analogue of the reference's per-partition
+        # chunking (RapidsRowMatrix.scala:168-202).
         source = streaming_source(dataset, self.getBatchRows())
         if source is None:
             frame = as_vector_frame(dataset, self.getInputCol())
             with timer.phase("densify"):
                 x_host = frame.vectors_as_matrix(self.getInputCol())
-            n_rows, n_features = x_host.shape
-            if k > n_features:
-                raise ValueError(
-                    f"k = {k} must be at most the number of features "
-                    f"{n_features}"
-                )
-            if n_rows < 2 and self.getMeanCentering():
-                # matches `require(count > 1)` (RapidsRowMatrix.scala:160)
-                raise ValueError("mean centering requires more than one row")
-            from spark_rapids_ml_tpu.data.batches import (
-                BatchSource,
-                stream_threshold_bytes,
+            source = BatchSource(x_host, batch_rows=self.getBatchRows())
+        if k > source.n_features:
+            raise ValueError(
+                f"k = {k} must be at most the number of features "
+                f"{source.n_features}"
             )
 
-            if (
-                use_xla_dot
-                and x_host.nbytes > stream_threshold_bytes()
-            ):
-                # Out-of-HBM: stream buckets through the device accumulator
-                # instead of one whole-matrix device_put — the analogue of
-                # the reference's per-partition chunking
-                # (RapidsRowMatrix.scala:168-202).
-                source = BatchSource(x_host, batch_rows=self.getBatchRows())
+        cov, mean, count, ingest = self._covariance(source, timer)
+        if self.getMeanCentering() and float(count) < 2:
+            # matches `require(count > 1)` (RapidsRowMatrix.scala:160)
+            raise ValueError("mean centering requires more than one row")
+        pc, evr = self._solve(cov, k, timer, ingest)
 
-        if source is not None:
-            if k > source.n_features:
-                raise ValueError(
-                    f"k = {k} must be at most the number of features "
-                    f"{source.n_features}"
-                )
-            pc, evr, mean = self._fit_streamed(
-                source, k, use_xla_dot, use_xla_svd, timer
-            )
-        elif use_xla_dot or use_xla_svd:
-            pc, evr, mean = self._fit_xla(
-                x_host, k, use_xla_dot, use_xla_svd, timer
-            )
-        else:
-            pc, evr, mean = self._fit_host(x_host, k, timer)
-
-        # device results cross to the host here (the streamed path hands
-        # pc, evr and mean back as device arrays)
+        # device results cross to the host here (a device stage hands its
+        # arrays back where they are)
         with timer.phase("fetch"), TraceRange(SPAN_FETCH, TraceColor.CYAN):
             model = PCAModel(
                 pc=np.asarray(pc, dtype=np.float64),
@@ -348,195 +333,60 @@ class PCA(PCAParams):
 
         return resolve_gram_precision(value)
 
-    # -- streamed (out-of-core) path -------------------------------------
-    def _fit_streamed(self, source, k, use_xla_dot, use_xla_svd, timer):
-        if use_xla_dot:
-            import jax
-
-            from spark_rapids_ml_tpu.ops.streaming import (
-                SPAN_SYNC_COV,
-                IngestTrace,
-                stream_covariance,
-            )
-
-            dtype = _resolve_dtype(self.getDtype())
-            ingest = IngestTrace(timer, _resolve_devices(
-                self.getDeviceId(), self.getNumDevices()))
+    def _covariance(self, source, timer):
+        """(covariance, mean, row count, the stream's ``IngestTrace``): on
+        the fit's chips (``useXlaDot``; device arrays on the first of them)
+        or, with no trace, on the host in float64."""
+        if not self.getUseXlaDot():
             with timer.phase("covariance"), TraceRange(
-                SPAN_STREAMED_COV, TraceColor.RED
+                "host cov", TraceColor.ORANGE
             ):
-                cov, mean, count = stream_covariance(
-                    source,
-                    mean_centering=self.getMeanCentering(),
-                    dtype=dtype,
-                    precision=self._gram_precision(),
-                    ingest=ingest,
-                )
-                with ingest.sync(SPAN_SYNC_COV):
-                    cov = jax.block_until_ready(cov)
-            if self.getMeanCentering() and float(count) < 2:
-                raise ValueError("mean centering requires more than one row")
-            if use_xla_svd:
-                ingest.hbm("solve:start")
-                with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
-                    pc, evr = self._solve_cov_gated(cov, k)
-                ingest.hbm("solve:end")
-                return pc, evr, mean  # on the device: fit() fetches them
-            with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
-                pc, evr = _host_eig_topk(np.asarray(cov, dtype=np.float64), k)
-            return pc, evr, np.asarray(mean)
-
-        # Host accumulation (useXlaDot=False) — out-of-core on the host in
-        # float64, then device or host eigensolve per useXlaSvd.
-        with timer.phase("covariance"), TraceRange("host cov", TraceColor.ORANGE):
-            cov, mean, count = _host_covariance_streamed(
-                source, self.getMeanCentering()
-            )
-        if self.getMeanCentering() and count < 2:
-            raise ValueError("mean centering requires more than one row")
-        if use_xla_svd:
-            import jax
-
-            device = _resolve_device(self.getDeviceId())
-            dtype = _resolve_dtype(self.getDtype())
-            with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
-                cov_dev = jax.device_put(np.asarray(cov, dtype=dtype), device)
-                pc, evr = self._solve_cov_gated(cov_dev, k)
-            return np.asarray(pc), np.asarray(evr), mean
-        with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
-            pc, evr = _host_eig_topk(cov, k)
-        return pc, evr, mean
-
-    # -- XLA (accelerator) path ------------------------------------------
-    def _fit_xla(self, x_host, k, use_xla_dot, use_xla_svd, timer):
+                return *_host_covariance_streamed(
+                    source, self.getMeanCentering()), None
         import jax
-        import jax.numpy as jnp
 
-        from spark_rapids_ml_tpu.ops.covariance import column_means, covariance
-        from spark_rapids_ml_tpu.ops.pca_kernel import pca_fit_kernel
+        from spark_rapids_ml_tpu.ops.streaming import (
+            SPAN_SYNC_COV,
+            IngestTrace,
+            stream_covariance,
+        )
 
-        device = _resolve_device(self.getDeviceId())
-        dtype = _resolve_dtype(self.getDtype())
-        mean_centering = self.getMeanCentering()
-        precision = self._gram_precision()
-
-        if use_xla_dot and _pallas_gram_enabled(device, dtype, x_host.shape[1]):
-            # Fused Pallas center+scale+mask+Gram (ops/pallas_gram.py):
-            # X is read from HBM once per visited tile pair, no centered
-            # copy materialized, and the symmetric folded grid does half
-            # the MXU/HBM work of a dot_general. TPUML_PALLAS_GRAM=0
-            # restores the XLA path.
-            from spark_rapids_ml_tpu.ops.pallas_gram import covariance_fused
-
-            with timer.phase("covariance"), TraceRange(
-                "pallas fused gram", TraceColor.RED
-            ):
-                cov, mean = covariance_fused(
-                    x_host,
-                    mean_centering=mean_centering,
-                    device=device,
-                    precision=precision,
-                )
-                cov = jax.block_until_ready(cov)
-            if use_xla_svd:
-                with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
-                    pc, evr = self._solve_cov_gated(cov, k)
-                return np.asarray(pc), np.asarray(evr), np.asarray(mean)
-            with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
-                pc, evr = _host_eig_topk(np.asarray(cov, dtype=np.float64), k)
-            return pc, evr, np.asarray(mean)
-
-        if use_xla_dot and use_xla_svd:
-            solver = self.getSvdSolver()
-            from spark_rapids_ml_tpu.ops.eigh import resolve_auto_solver
-
-            if (solver == "auto"
-                    and resolve_auto_solver(x_host.shape[1], k)
-                    == "randomized"):
-                # 'auto' promises the residual-gated randomized solve, and
-                # the gate needs one host read — so this path runs TWO
-                # compiled programs (covariance, gated solve) instead of
-                # one; 'eigh'/'randomized' explicitly keep the fused
-                # single-program pipeline below
-                with timer.phase("h2d"):
-                    x = jax.device_put(np.asarray(x_host, dtype=dtype),
-                                       device)
-                with timer.phase("covariance"), TraceRange(
-                    "compute cov", TraceColor.RED
-                ):
-                    if mean_centering:
-                        mean = column_means(x)
-                        cov = covariance(x, mean=mean,
-                                         precision=precision)
-                    else:
-                        mean = jnp.zeros((x.shape[1],), dtype=x.dtype)
-                        cov = covariance(x, precision=precision)
-                with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH,
-                                                      TraceColor.BLUE):
-                    pc, evr = self._solve_cov_gated(cov, k)
-                return pc, evr, jax.block_until_ready(mean)
-
-            # Whole pipeline in ONE compiled program on device.
-            with timer.phase("h2d"):
-                x = jax.device_put(np.asarray(x_host, dtype=dtype), device)
-            with timer.phase("fit_kernel"), TraceRange("compute cov", TraceColor.RED):
-                result = pca_fit_kernel(
-                    x, k, mean_centering=mean_centering, solver=solver,
-                    precision=precision,
-                )
-                result = jax.block_until_ready(result)
-            self._svd_solver_used = (
-                resolve_auto_solver(x_host.shape[1], k)
-                if solver == "auto" else solver
+        ingest = IngestTrace(timer, _resolve_devices(
+            self.getDeviceId(), self.getNumDevices()))
+        with timer.phase("covariance"), TraceRange(
+            SPAN_STREAMED_COV, TraceColor.RED
+        ):
+            cov, mean, count = stream_covariance(
+                source,
+                mean_centering=self.getMeanCentering(),
+                dtype=_resolve_dtype(self.getDtype()),
+                precision=self._gram_precision(),
+                ingest=ingest,
             )
-            return result.components, result.explained_variance, result.mean
-
-        if use_xla_dot:
-            # Device covariance + host eigensolve (reference's
-            # useGemm=true / useCuSolverSVD=false mode).
-            with timer.phase("h2d"):
-                x = jax.device_put(np.asarray(x_host, dtype=dtype), device)
-            with timer.phase("covariance"), TraceRange("compute cov", TraceColor.RED):
-                if mean_centering:
-                    mean = column_means(x)
-                    cov = covariance(x, mean=mean, precision=precision)
-                else:
-                    mean = jnp.zeros((x.shape[1],), dtype=x.dtype)
-                    cov = covariance(x, precision=precision)
+            with ingest.sync(SPAN_SYNC_COV):
                 cov = jax.block_until_ready(cov)
+        return cov, mean, count, ingest
+
+    def _solve(self, cov, k, timer, ingest):
+        """Top-k (components, explained variance) of ``cov``: on the chip
+        (``useXlaSvd``; a host covariance, which has no ``ingest``, is put
+        there first) or on the host in float64."""
+        if not self.getUseXlaSvd():
             with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
-                pc, evr = _host_eig_topk(np.asarray(cov, dtype=np.float64), k)
-            return pc, evr, np.asarray(mean)
-
-        # Host covariance + device eigensolve (useGemm=false /
-        # useCuSolverSVD=true — the reference's "pca using cuSolver" test mode).
-        with timer.phase("covariance"), TraceRange("host cov", TraceColor.ORANGE):
-            cov, mean = _host_covariance(x_host, self.getMeanCentering())
+                return _host_eig_topk(np.asarray(cov, dtype=np.float64), k)
+        if ingest is not None:
+            ingest.hbm("solve:start")
         with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
-            cov_dev = jax.device_put(np.asarray(cov, dtype=dtype), device)
-            pc, evr = self._solve_cov_gated(cov_dev, k)
-        return np.asarray(pc), np.asarray(evr), mean
+            if ingest is None:
+                import jax
 
-    # -- host fallback path ----------------------------------------------
-    def _fit_host(self, x_host, k, timer):
-        with timer.phase("covariance"), TraceRange("host cov", TraceColor.ORANGE):
-            cov, mean = _host_covariance(x_host, self.getMeanCentering())
-        with timer.phase("solve"), TraceRange("host eigh", TraceColor.BLUE):
-            pc, evr = _host_eig_topk(cov, k)
-        return pc, evr, mean
-
-
-def _pallas_gram_enabled(device, dtype, n_features) -> bool:
-    """Whether the fused Pallas Gram path is selected for a one-shot fit.
-
-    Policy lives in ``ops.pallas_gram.pallas_gram_preferred`` (flag
-    override, TPU backend, f32, padded-cost heuristic).
-    """
-    from spark_rapids_ml_tpu.ops.pallas_gram import pallas_gram_preferred
-
-    return pallas_gram_preferred(
-        getattr(device, "platform", ""), dtype, n_features
-    )
+                cov = jax.device_put(
+                    np.asarray(cov, dtype=_resolve_dtype(self.getDtype())),
+                    _resolve_device(self.getDeviceId()))
+            pc, evr = self._solve_cov_gated(cov, k)
+        if ingest is not None:
+            ingest.hbm("solve:end")
+        return pc, evr
 
 
 def _host_covariance_streamed(source, mean_centering: bool):
@@ -576,27 +426,6 @@ def _host_covariance_streamed(source, mean_centering: bool):
     mean = col_sum / max(count, 1)
     cov = (g - count * np.outer(mean, mean)) / denom
     return cov, mean, count
-
-
-def _host_covariance(x: np.ndarray, mean_centering: bool):
-    """Host covariance via the native C++ runtime when built, NumPy otherwise.
-
-    Functional equivalent of the reference's spr CPU path
-    (``RapidsRowMatrix.scala:203-252``) minus its bugs: normalizes by
-    numRows−1 and supports meanCentering=False.
-    """
-    from spark_rapids_ml_tpu import native
-
-    x = np.asarray(x, dtype=np.float64)
-    n_rows = x.shape[0]
-    mean = x.mean(axis=0) if mean_centering else np.zeros(x.shape[1])
-    xc = x - mean if mean_centering else x
-    denom = max(n_rows - 1, 1)
-    if native.is_loaded():
-        cov = native.gram(np.ascontiguousarray(xc)) / denom
-    else:
-        cov = xc.T @ xc / denom
-    return cov, mean
 
 
 # Above this n the host eigensolve routes to NumPy's threaded OpenBLAS:

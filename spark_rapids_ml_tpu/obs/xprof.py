@@ -173,6 +173,16 @@ def clear_all_signature_caches() -> None:
         inst.clear_cache()
 
 
+def forget_fallback_signatures() -> None:
+    """Drop, from every live tracked function, the signatures that gave up
+    on their AOT executable: ``fallback_signatures()`` then counts only
+    what falls back from here on (a dropped signature tries its AOT
+    compile again at its next call). For a reader of that process-wide
+    count that shares its process, as a test shares a worker."""
+    for inst in list(_instances):
+        inst.forget_fallbacks()
+
+
 def fallback_signatures() -> Dict[str, int]:
     """``{label: count}`` of signatures, across live tracked functions,
     that gave up on their AOT executable (``lower().compile()`` raised, or
@@ -330,6 +340,11 @@ class TrackedJit:
         with self._lock:
             self._cache.clear()
             self._storm_warned = False
+
+    def forget_fallbacks(self) -> None:
+        with self._lock:
+            for key in [k for k, e in self._cache.items() if e.fallback]:
+                del self._cache[key]
 
     # AOT passthroughs so call sites that reach for the raw jit still work.
     def lower(self, *args, **kwargs):

@@ -6,7 +6,7 @@ from spark_rapids_ml_tpu.ops.eigh import (
     resolve_auto_solver,
     sign_flip,
 )
-from spark_rapids_ml_tpu.ops.pca_kernel import pca_fit_kernel, pca_transform_kernel
+from spark_rapids_ml_tpu.ops.pca_kernel import pca_transform_kernel
 
 __all__ = [
     "column_means",
@@ -17,6 +17,5 @@ __all__ = [
     "pca_from_covariance",
     "pca_from_covariance_gated",
     "resolve_auto_solver",
-    "pca_fit_kernel",
     "pca_transform_kernel",
 ]

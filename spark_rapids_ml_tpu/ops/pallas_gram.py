@@ -20,7 +20,6 @@ interpret mode).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -39,58 +38,8 @@ from spark_rapids_ml_tpu.obs.xprof import tracked_jit
 # hi/lo split temps 4×(1024×512×2B) = 4 MB, f32 acc + output staging
 # ≈ 2 MB, mean/rowmul slivers — ≈ 17 MB total, past the 16 MB default
 # scoped limit, hence the vmem_limit_bytes override on the pallas_call.
-_BLOCK_N = int(os.environ.get("TPUML_GRAM_BLOCK_N", "512"))
-_BLOCK_R = int(os.environ.get("TPUML_GRAM_BLOCK_R", "1024"))
-
-
-def gram_block_shape() -> "tuple[int, int]":
-    """Current production (block_n, block_r), read at call time so env
-    overrides (TPUML_GRAM_BLOCK_N/R) and bench monkeypatches reach the
-    streaming dispatch — Python binds keyword defaults at def time, so
-    callers that want the live constants must ask here."""
-    return _BLOCK_N, _BLOCK_R
-
-
-# One policy for "should this Gram use the Pallas kernel?" — shared by the
-# one-shot estimator gate (models/pca.py) and the streaming dispatch
-# (ops/streaming.py) so the two paths can never silently diverge.
-
-def pallas_gram_flag() -> str:
-    """TPUML_PALLAS_GRAM: '0' = force XLA, '1' = force Pallas (where it can
-    lower at all), unset/other = 'auto' (measured-cost heuristic)."""
-    value = os.environ.get("TPUML_PALLAS_GRAM")
-    return value if value in ("0", "1") else "auto"
-
-
-def symmetric_cost_wins(n_features: int) -> bool:
-    """Whether the folded symmetric kernel beats XLA at this width.
-
-    The kernel pads features to an even number of _BLOCK_N tiles and then
-    does half the padded work: cost ≈ padded² / 2 vs the XLA dot_general's
-    n². Selecting on a flat width threshold regresses in the bands just
-    above each tile boundary (e.g. n=1100 pads to 2048: 2048²/2 ≈ 2× the
-    XLA FLOPs *plus* a padded host copy), so compare actual costs.
-    """
-    block = 2 * _BLOCK_N
-    padded = -(-n_features // block) * block
-    return padded * padded <= 2 * n_features * n_features
-
-
-def pallas_gram_preferred(platform: str, dtype, n_features: int) -> bool:
-    """The shared policy gate: flag override, TPU backend, f32
-    compute, and the padded-cost heuristic. Callers add their own shape
-    constraints on top (the streaming path requires exact tile alignment;
-    the one-shot path pads)."""
-    flag = pallas_gram_flag()
-    if flag == "0":
-        return False
-    if platform != "tpu":
-        return False  # the kernel is Mosaic-only
-    if jnp.dtype(dtype) != jnp.float32:
-        return False
-    if flag == "1":
-        return True
-    return symmetric_cost_wins(n_features)
+_BLOCK_N = 512
+_BLOCK_R = 1024
 
 
 def _make_gram_kernel(precision, symmetric):
@@ -167,30 +116,6 @@ def _folded_triangle_maps(n_tiles):
     return _ij
 
 
-def fused_centered_gram(
-    x: jnp.ndarray,
-    mean: jnp.ndarray,
-    rowmul: jnp.ndarray,
-    interpret: bool = False,
-    precision=None,
-    symmetric: bool = True,
-    block_n: "int | None" = None,
-    block_r: "int | None" = None,
-) -> jnp.ndarray:
-    """Eager shim resolving block defaults at CALL time (None →
-    ``gram_block_shape()``) — def-time keyword defaults would freeze the
-    import-time constants and ignore env/bench overrides, the staleness
-    class the streaming wrappers guard against. See `_fused_centered_gram`
-    for the kernel contract."""
-    if block_n is None or block_r is None:
-        bn, br = gram_block_shape()
-        block_n = bn if block_n is None else block_n
-        block_r = br if block_r is None else block_r
-    return _fused_centered_gram(
-        x, mean, rowmul, interpret=interpret, precision=precision,
-        symmetric=symmetric, block_n=block_n, block_r=block_r)
-
-
 @functools.partial(
     tracked_jit,
     static_argnames=(
@@ -204,30 +129,29 @@ def _fused_centered_gram(
     interpret: bool = False,
     precision=None,
     symmetric: bool = True,
-    block_n: int = 512,
-    block_r: int = 1024,
+    block_n: int = _BLOCK_N,
+    block_r: int = _BLOCK_R,
 ) -> jnp.ndarray:
     """``(diag(rowmul)·(X − mean))ᵀ (diag(rowmul)·(X − mean))`` in one pass.
 
     ``rowmul`` is the per-row multiplier (mask × global 1/√(n−1) scaling —
     the reference folded the same normalizer into rows before its GEMM,
     ``RapidsRowMatrix.scala:169,179-181``). Requires row/col extents padded
-    to the tile grid (use ``pad_for_fused_gram``); padding rows carry
-    rowmul=0 so they contribute nothing.
+    to the tile grid; padding rows carry rowmul=0 so they contribute
+    nothing.
 
     ``symmetric=True`` (default) exploits Gram symmetry: a folded
     triangular grid visits only upper block tiles — half the MXU FLOPs and
     half the HBM block fetches, a structural advantage a generic
     ``dot_general`` cannot express — then the result is mirrored with an
-    elementwise triu + transpose. Requires an even feature-tile count
-    (``pad_for_fused_gram`` guarantees it); odd tile counts fall back to
-    the full grid.
+    elementwise triu + transpose. Requires an even feature-tile count;
+    odd tile counts fall back to the full grid.
     """
     rows, n = x.shape
     if rows % block_r or n % block_n:
         raise ValueError(
             f"shape {(rows, n)} must be padded to multiples of "
-            f"({block_r}, {block_n}); use pad_for_fused_gram"
+            f"({block_r}, {block_n})"
         )
     from spark_rapids_ml_tpu.ops.covariance import default_gram_precision
 
@@ -305,68 +229,6 @@ def _fused_centered_gram(
     return out
 
 
-def pad_for_fused_gram(x, mask=None, dtype=None,
-                       block_n: "int | None" = None,
-                       block_r: "int | None" = None):
-    """Pad rows to ``block_r`` and features to ``block_n`` (the same
-    block arguments ``fused_centered_gram`` takes); returns
-    (x_padded, rowmask_padded, n_features_original).
-
-    One allocation + one copy total (dtype cast included): at the 1M×4096
-    target a concatenate-per-axis implementation would transiently hold
-    2-3 full copies of X on the host.
-    """
-    import numpy as np
-
-    if block_n is None or block_r is None:
-        bn, br = gram_block_shape()
-        block_n = bn if block_n is None else block_n
-        block_r = br if block_r is None else block_r
-    x = np.asarray(x)
-    dtype = x.dtype if dtype is None else np.dtype(dtype)
-    rows, n = x.shape
-    pr = (-rows) % block_r
-    # Pad features to an EVEN number of block_n tiles so the symmetric
-    # folded-triangle grid applies (an odd tile count can't fold).
-    pn = (-n) % (2 * block_n)
-    rowmask = (
-        np.ones(rows, dtype=dtype) if mask is None
-        else np.asarray(mask, dtype=dtype)
-    )
-    if pr:
-        rowmask = np.concatenate([rowmask, np.zeros(pr, dtype=dtype)])
-    if pr == 0 and pn == 0 and x.dtype == dtype:
-        return x, rowmask, n
-    out = np.zeros((rows + pr, n + pn), dtype=dtype)
-    out[:rows, :n] = x
-    return out, rowmask, n
-
-
-def covariance_fused(x, mask=None, mean_centering: bool = True,
-                     interpret: bool = False, device=None,
-                     dtype=jnp.float32, precision=None):
-    """Covariance via the fused kernel: host-side padding + on-device
-    mean pass + single fused Gram. Returns (cov[n,n], mean[n]); arrays land
-    on ``device`` when given (the estimator's resolved chip), else the
-    default device. Padding + dtype cast happen in a single host copy."""
-    import numpy as np
-
-    bn, br = gram_block_shape()  # resolve ONCE so pad + kernel agree
-    x_p, rowmask, n = pad_for_fused_gram(x, mask, dtype=np.dtype(dtype),
-                                         block_n=bn, block_r=br)
-    # device_put of the HOST array commits straight to ``device`` (None =
-    # the default device); going through jnp.asarray first would land the
-    # whole matrix on device 0 and copy it chip-to-chip from there.
-    x_dev = jax.device_put(x_p, device)
-    rowmask_dev = jax.device_put(rowmask, device)
-    cnt = jnp.sum(rowmask_dev)
-    if mean_centering:
-        mean = jnp.sum(x_dev * rowmask_dev[:, None], axis=0) / cnt
-    else:
-        mean = jnp.zeros((x_p.shape[1],), dtype=x_dev.dtype)
-    scale = 1.0 / jnp.sqrt(jnp.maximum(cnt - 1.0, 1.0))
-    cov_full = fused_centered_gram(
-        x_dev, mean, rowmask_dev * scale, interpret=interpret,
-        precision=precision, block_n=bn, block_r=br,
-    )
-    return cov_full[:n, :n], mean[:n]
+# the callers' name; the underscore is the program's name in every trace and
+# in ``obs.compile_stats()``, which the benchmark's ledger and the smoke read
+fused_centered_gram = _fused_centered_gram

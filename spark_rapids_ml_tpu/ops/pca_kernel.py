@@ -1,9 +1,4 @@
-"""The end-to-end single-device PCA programs.
-
-One jitted XLA program covers the reference's whole fit pipeline —
-mean pass (``RapidsRowMatrix.scala:152-162``), centered Gram
-(``:168-202``), eigendecomposition + postprocess
-(``rapidsml_jni.cu:338-392``) — with zero host round trips between stages.
+"""The single-device PCA projection programs, and the fit's result type.
 
 ``pca_transform_kernel`` enables the batched on-device transform the
 reference declared but left disabled ("TODO(rongou): make this faster",
@@ -14,16 +9,13 @@ batch.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from spark_rapids_ml_tpu.obs.xprof import tracked_jit
-from spark_rapids_ml_tpu.ops.covariance import column_means, covariance
-from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance
 from spark_rapids_ml_tpu.ops.quantize import quantize_symmetric
 
 
@@ -31,41 +23,6 @@ class PCAFitResult(NamedTuple):
     components: jnp.ndarray          # (n_features, k), column j = j-th PC
     explained_variance: jnp.ndarray  # (k,) ratios λᵢ/Σλ
     mean: jnp.ndarray                # (n_features,) column means (or zeros)
-
-
-@partial(
-    tracked_jit,
-    static_argnames=("k", "mean_centering", "flip_signs", "solver",
-                     "precision"),
-)
-def pca_fit_kernel(
-    x: jnp.ndarray,
-    k: int,
-    mask: Optional[jnp.ndarray] = None,
-    mean_centering: bool = True,
-    flip_signs: bool = True,
-    solver: str = "eigh",
-    precision: Optional[str] = None,
-) -> PCAFitResult:
-    """Full PCA fit on one device: mean → centered Gram → eigh → top-k.
-
-    Two-pass (explicit centering before the Gram) for parity with the
-    reference's semantics; the distributed path offers a one-pass variant.
-    ``mask`` marks valid rows when the batch is padded to a static shape.
-    ``precision`` is STATIC — part of the jit cache key, so switching the
-    Gram precision between fits retraces instead of silently reusing the
-    old executable.
-    """
-    if mean_centering:
-        mean = column_means(x, mask)
-        cov = covariance(x, mean=mean, mask=mask, precision=precision)
-    else:
-        mean = jnp.zeros((x.shape[1],), dtype=x.dtype)
-        cov = covariance(x, mean=None, mask=mask, precision=precision)
-    components, evr = pca_from_covariance(
-        cov, k, flip_signs=flip_signs, solver=solver
-    )
-    return PCAFitResult(components, evr, mean)
 
 
 def _project(x: jnp.ndarray, components: jnp.ndarray) -> jnp.ndarray:

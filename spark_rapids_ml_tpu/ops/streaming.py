@@ -5,8 +5,7 @@ per partition, ``RapidsRowMatrix.scala:168-202``). The TPU-native analogue:
 an on-device sufficient-statistics accumulator ``(Σxxᵀ, Σx, n)`` updated by
 a jitted, buffer-donating step per batch — HBM usage is one batch + one
 n×n Gram regardless of total rows, and batches stream through while the
-MXU stays busy. Finalization (covariance → eigh → postprocess) is the same
-program the one-shot kernel uses.
+MXU stays busy.
 
 This is also the host data-loader contract: feed fixed-shape batches
 (pad + mask the tail — no recompilation), call ``update``, then
@@ -96,40 +95,23 @@ def finalize_stats(
     return PCAFitResult(components, evr, mean)
 
 
-@partial(tracked_jit, donate_argnums=(0,),
-         static_argnames=("bn", "br", "precision"))
+@partial(tracked_jit, donate_argnums=(0,), static_argnames=("precision",))
 def _update_stats_fused_blocked(stats: GramStats, batch: jnp.ndarray,
-                                *, bn: int, br: int,
                                 precision: Optional[str] = None
                                 ) -> GramStats:
+    """``update_stats`` with the Gram computed by the Pallas symmetric
+    folded-grid kernel (``ops.pallas_gram``) instead of ``lax.dot_general``.
+    Takes what ``accumulate_path`` calls ``"pallas"``: tile-aligned batches
+    and no mask."""
     from spark_rapids_ml_tpu.ops.pallas_gram import fused_centered_gram
 
     b = batch.astype(stats.gram.dtype)
     zero_mean = jnp.zeros((b.shape[1],), dtype=b.dtype)
     ones = jnp.ones((b.shape[0],), dtype=b.dtype)
-    g = fused_centered_gram(b, zero_mean, ones, precision=precision,
-                            block_n=bn, block_r=br)
+    g = fused_centered_gram(b, zero_mean, ones, precision=precision)
     s = jnp.sum(b, axis=0)
     cnt = jnp.asarray(b.shape[0], dtype=jnp.int32)
     return GramStats(stats.gram + g, stats.col_sum + s, stats.count + cnt)
-
-
-def update_stats_fused(stats: GramStats, batch: jnp.ndarray,
-                       precision: Optional[str] = None) -> GramStats:
-    """``update_stats`` with the Gram computed by the Pallas symmetric
-    folded-grid kernel (``ops.pallas_gram``) instead of ``lax.dot_general``.
-    Requires tile-aligned batches (rows % block_r == 0, an even number of
-    block_n feature tiles) and no mask.
-
-    The block shape is read EAGERLY (outside jit) and passed as static
-    args — a `gram_block_shape()` call inside the traced body would bake
-    the first compile's shape into the jit cache and silently ignore
-    later env/bench overrides."""
-    from spark_rapids_ml_tpu.ops.pallas_gram import gram_block_shape
-
-    bn, br = gram_block_shape()
-    return _update_stats_fused_blocked(stats, batch, bn=bn, br=br,
-                                       precision=precision)
 
 
 def _gram_platform(gram_acc) -> str:
@@ -137,44 +119,38 @@ def _gram_platform(gram_acc) -> str:
     return next(iter(gram_acc.devices())).platform
 
 
-def fused_update_applicable(gram_acc, batch, mask) -> bool:
-    """Whether the Pallas Gram accumulator handles this (acc, batch, mask).
-
-    The policy (flag override, TPU backend, f32, measured-cost heuristic)
-    is ``ops.pallas_gram.pallas_gram_preferred`` — shared with the one-shot
-    estimator gate. On top of it this path requires exact tile alignment
-    and no mask (``update_stats_fused`` does not pad).
-    """
-    from spark_rapids_ml_tpu.ops.pallas_gram import (
-        gram_block_shape,
-        pallas_gram_preferred,
-    )
-
-    if mask is not None or gram_acc.dtype != jnp.float32:
-        return False
-    bn, br = gram_block_shape()
-    rows, n = batch.shape
-    if rows % br or n % bn or (n // bn) % 2:
-        return False
-    if isinstance(gram_acc, jax.core.Tracer):
-        return False  # a traced accumulator has no device to ask
-    return pallas_gram_preferred(_gram_platform(gram_acc), gram_acc.dtype, n)
-
-
 def accumulate_path(gram_acc, batch, mask) -> str:
     """``"pallas"`` or ``"xla"``: the Gram kernel ``update_stats_auto`` /
-    ``update_centered_gram_auto`` pick for this (acc, batch, mask)."""
-    return "pallas" if fused_update_applicable(gram_acc, batch, mask) else "xla"
+    ``update_centered_gram_auto`` pick for this (acc, batch, mask) — the one
+    place that chooses. The Pallas kernel visits only the upper block tiles,
+    half the MXU work and half the block fetches of a ``dot_general``, so it
+    runs wherever it can run as it is; nothing here pads a batch to make it
+    fit. The benchmark holds a cell on each side (4096 wide → Pallas, 784 →
+    XLA)."""
+    from spark_rapids_ml_tpu.ops.pallas_gram import _BLOCK_N, _BLOCK_R
+
+    rows, n = batch.shape
+    pallas = (
+        mask is None  # the fused steps feed the kernel a rowmul of ones
+        and gram_acc.dtype == jnp.float32
+        and rows % _BLOCK_R == 0
+        # an even number of feature tiles: an odd one cannot fold
+        and n % (2 * _BLOCK_N) == 0
+        # a traced accumulator has no device to ask
+        and not isinstance(gram_acc, jax.core.Tracer)
+        and _gram_platform(gram_acc) == "tpu"  # the kernel is Mosaic-only
+    )
+    return "pallas" if pallas else "xla"
 
 
 def update_stats_auto(
     stats: GramStats, batch: jnp.ndarray, mask: Optional[jnp.ndarray] = None,
     precision: Optional[str] = None,
 ) -> GramStats:
-    """The production accumulate step: picks the measured-fastest Gram
-    kernel for this backend/shape (see ``fused_update_applicable``)."""
+    """The production accumulate step, by the kernel ``accumulate_path``
+    names."""
     if accumulate_path(stats.gram, batch, mask) == "pallas":
-        return update_stats_fused(stats, batch, precision=precision)
+        return _update_stats_fused_blocked(stats, batch, precision=precision)
     return update_stats(stats, batch, mask, precision=precision)
 
 
@@ -245,36 +221,23 @@ def update_centered_gram(
     return gram_acc + gram(_masked(b, mask), precision=precision)
 
 
-@partial(tracked_jit, donate_argnums=(0,),
-         static_argnames=("bn", "br", "precision"))
-def _update_centered_gram_fused_blocked(gram_acc, batch, mean, *, bn, br,
-                                        precision=None):
+@partial(tracked_jit, donate_argnums=(0,), static_argnames=("precision",))
+def _update_centered_gram_fused_blocked(gram_acc, batch, mean, precision=None):
     from spark_rapids_ml_tpu.ops.pallas_gram import fused_centered_gram
 
     b = batch.astype(gram_acc.dtype)
     ones = jnp.ones((b.shape[0],), dtype=b.dtype)
     return gram_acc + fused_centered_gram(b, mean.astype(b.dtype), ones,
-                                          precision=precision,
-                                          block_n=bn, block_r=br)
-
-
-def _update_centered_gram_fused(gram_acc, batch, mean, precision=None):
-    from spark_rapids_ml_tpu.ops.pallas_gram import gram_block_shape
-
-    bn, br = gram_block_shape()
-    return _update_centered_gram_fused_blocked(gram_acc, batch, mean,
-                                               bn=bn, br=br,
-                                               precision=precision)
+                                          precision=precision)
 
 
 def update_centered_gram_auto(gram_acc, batch, mean, mask=None,
                               precision=None):
-    """Centered-Gram accumulate via the measured-fastest kernel: the Pallas
-    kernel centers in VMEM (no (X−μ) materialization at all), same policy
-    gate as ``update_stats_auto``."""
+    """Centered-Gram accumulate by the kernel ``accumulate_path`` names: the
+    Pallas kernel centers in VMEM (no (X−μ) materialization at all)."""
     if accumulate_path(gram_acc, batch, mask) == "pallas":
-        return _update_centered_gram_fused(gram_acc, batch, mean,
-                                           precision=precision)
+        return _update_centered_gram_fused_blocked(gram_acc, batch, mean,
+                                                   precision=precision)
     return update_centered_gram(gram_acc, batch, mean, mask,
                                 precision=precision)
 

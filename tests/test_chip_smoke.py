@@ -13,7 +13,7 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-TOY = chip_smoke.Shape(n_features=64, k=8, one_shot_rows=1024,
+TOY = chip_smoke.Shape(n_features=64, k=8, in_memory_rows=1024,
                        stream_batch_rows=256, stream_cycles=2,
                        serve_max_batch_rows=32, top=4)
 # x64 CPU arithmetic against a float64 oracle: everything is rounding error
@@ -34,31 +34,38 @@ def test_exits_nonzero_and_names_the_platform_without_a_tpu():
 
 @pytest.fixture(scope="module")
 def toy_fits():
-    x = chip_smoke.make_rows(TOY.one_shot_rows, TOY.n_features)
-    one_shot = chip_smoke.fit_one_shot(x, TOY.k)
+    # report_kernels reads the process-wide fallback count, as it must on
+    # the chip; here the process is a worker other test files share, so the
+    # count starts from these fits
+    from spark_rapids_ml_tpu.obs.xprof import forget_fallback_signatures
+
+    forget_fallback_signatures()
+    x = chip_smoke.make_rows(TOY.in_memory_rows, TOY.n_features)
+    in_memory = chip_smoke.fit_in_memory(x, TOY.k)
     streamed = chip_smoke.fit_streamed(x, TOY.k, TOY.stream_batch_rows,
                                        TOY.stream_cycles)
-    return x, one_shot, streamed
+    return x, in_memory, streamed
 
 
 def test_fit_phases_at_toy_shape(toy_fits):
-    x, one_shot, streamed = toy_fits
+    x, in_memory, streamed = toy_fits
     checks = chip_smoke.Checks()
-    chip_smoke.report_fit(checks, "one-shot", one_shot, "cpu", TOY.n_features)
+    chip_smoke.report_fit(checks, "in-memory", in_memory, "cpu",
+                          TOY.n_features)
     chip_smoke.report_fit(checks, "streamed", streamed, "cpu", TOY.n_features)
-    chip_smoke.report_kernels(checks, "cpu")
+    chip_smoke.report_kernels(checks, "cpu", in_memory)
     oracle = chip_smoke.oracle_pca(x, TOY.k)
-    chip_smoke.check_against_oracle(checks, one_shot, oracle, TOY, TIGHT)
-    chip_smoke.check_fits_agree(checks, one_shot, streamed, TOY, TIGHT)
-    chip_smoke.check_transform(checks, one_shot, x, 1e-9)
+    chip_smoke.check_against_oracle(checks, in_memory, oracle, TOY, TIGHT)
+    chip_smoke.check_fits_agree(checks, in_memory, streamed, TOY, TIGHT)
+    chip_smoke.check_transform(checks, in_memory, x, 1e-9)
     assert checks.failed == []
 
 
 def test_a_missed_bar_is_recorded(toy_fits):
-    x, one_shot, _ = toy_fits
+    x, in_memory, _ = toy_fits
     checks = chip_smoke.Checks()
     wrong = chip_smoke.oracle_pca(x[::-1] * 2.0 + 1.0, TOY.k)
-    chip_smoke.check_against_oracle(checks, one_shot, wrong, TOY, TIGHT)
+    chip_smoke.check_against_oracle(checks, in_memory, wrong, TOY, TIGHT)
     assert checks.failed
     checks.at_most("nan", float("nan"), 1.0)
     assert checks.failed[-1] == "nan"
@@ -77,9 +84,9 @@ def test_tail_check_tells_a_lost_iteration_from_the_design():
         randomized_pca_from_covariance,
     )
 
-    shape = chip_smoke.Shape(n_features=512, k=64, one_shot_rows=4096, top=8)
+    shape = chip_smoke.Shape(n_features=512, k=64, in_memory_rows=4096, top=8)
     oracle = chip_smoke.oracle_pca(
-        chip_smoke.make_rows(shape.one_shot_rows, shape.n_features), shape.k)
+        chip_smoke.make_rows(shape.in_memory_rows, shape.n_features), shape.k)
     cov = jnp.asarray(oracle[3])
     # convergence, not rounding, is on trial: measured 0.94 of the envelope
     # with the default 4 iterations, 8.5 with 3
@@ -102,9 +109,9 @@ def test_tail_check_tells_a_lost_iteration_from_the_design():
 
 
 def test_serve_phase_at_toy_shape(toy_fits):
-    x, one_shot, _ = toy_fits
+    x, in_memory, _ = toy_fits
     checks = chip_smoke.Checks()
-    batches = chip_smoke.serve_requests(checks, one_shot, x, TOY, "cpu", 1,
+    batches = chip_smoke.serve_requests(checks, in_memory, x, TOY, "cpu", 1,
                                         1e-9)
     assert checks.failed == []
     assert list(batches) == ["TFRT_CPU_0"]

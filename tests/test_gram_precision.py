@@ -78,19 +78,25 @@ def test_every_precision_fits_and_matches_oracle_on_cpu(rng, precision):
                                atol=1e-5)
 
 
-def test_precision_reaches_streamed_path(rng):
-    from spark_rapids_ml_tpu.data.batches import BatchSource
+def test_precision_reaches_every_accumulate_step(rng, monkeypatch):
+    """The Param is the static ``precision`` of each Gram step of the
+    fit's stream, whole batches and the masked tail alike."""
+    from spark_rapids_ml_tpu.ops import streaming
 
-    x = rng.normal(size=(1024, 32))
+    seen = []
+    real = streaming.update_centered_gram_auto
+
+    def recording(gram_acc, batch, mean, mask=None, precision=None):
+        seen.append((mask is not None, precision))
+        return real(gram_acc, batch, mean, mask, precision=precision)
+
+    monkeypatch.setattr(streaming, "update_centered_gram_auto", recording)
+    x = rng.normal(size=(1000, 32))
     pc_exp, evr_exp = _oracle(x, 3)
-    est = (PCA().setK(3).setInputCol("features")
-           .set("gramPrecision", "bfloat16").set("batchRows", 256))
-    source = BatchSource(x, batch_rows=256)
-    pc, evr, mean = est._fit_streamed(
-        source, 3, True, True, __import__(
-            "spark_rapids_ml_tpu.utils.timing",
-            fromlist=["PhaseTimer"]).PhaseTimer())
-    np.testing.assert_allclose(np.abs(pc), np.abs(pc_exp), atol=1e-5)
+    model = (PCA().setK(3).setInputCol("features")
+             .set("gramPrecision", "bfloat16").set("batchRows", 256).fit(x))
+    assert seen == [(False, "bfloat16")] * 3 + [(True, "bfloat16")]
+    np.testing.assert_allclose(np.abs(model.pc), np.abs(pc_exp), atol=1e-5)
 
 
 def test_param_persists_and_roundtrips(rng, tmp_path):

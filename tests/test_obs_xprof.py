@@ -242,3 +242,36 @@ def test_eager_solve_is_counted_where_tracked_jit_is_blind(rng):
     assert built > 1 and built > first.compiles
     assert first.programs_compiled > 1 or first.programs_fetched > 1
     assert second.programs_compiled == 0 and second.programs_fetched == 0
+
+
+def test_forgotten_fallbacks_are_counted_again_only_when_they_recur():
+    """``fallback_signatures()`` is process-wide; ``forget_fallback_signatures``
+    lets a reader that shares its process count from now on."""
+    from spark_rapids_ml_tpu.obs import xprof
+
+    class NoAot:
+        """A jitted function whose AOT path is not there."""
+
+        def __init__(self, jitted):
+            self._jitted = jitted
+
+        def lower(self, *args, **kwargs):
+            raise RuntimeError("no ahead-of-time compile here")
+
+        def __call__(self, *args, **kwargs):
+            return self._jitted(*args, **kwargs)
+
+    @tracked_jit(label="xprof_falls_back")
+    def f(x):
+        return x + 1.0
+
+    f._jitted = NoAot(f._jitted)
+    a = jnp.ones((3,))
+    np.testing.assert_allclose(np.asarray(f(a)), 2.0)  # still answers
+    assert xprof.fallback_signatures()["xprof_falls_back"] == 1
+    xprof.forget_fallback_signatures()
+    assert xprof.fallback_signatures() == {}
+    assert f.stats()["signatures"] == 0  # only the fallen signature went
+    np.testing.assert_allclose(np.asarray(f(a)), 2.0)  # tries again
+    assert xprof.fallback_signatures() == {"xprof_falls_back": 1}
+    xprof.forget_fallback_signatures()

@@ -9,9 +9,7 @@ from spark_rapids_ml_tpu.ops.covariance import covariance
 from spark_rapids_ml_tpu.ops.pallas_gram import (
     _BLOCK_N,
     _BLOCK_R,
-    covariance_fused,
     fused_centered_gram,
-    pad_for_fused_gram,
 )
 
 
@@ -27,48 +25,26 @@ def test_fused_matches_xla_exact_tiles(rng):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
-def test_fused_covariance_padded_and_masked(rng):
-    # 700×37: both axes need padding; padded rows/cols must not leak.
-    x = rng.normal(loc=2.0, size=(700, 37)).astype(np.float32)
-    cov, mean = covariance_fused(x, interpret=True)
-    x64 = x.astype(np.float64)
-    want = np.cov(x64, rowvar=False)
-    np.testing.assert_allclose(np.asarray(cov), want, atol=5e-3)
-    np.testing.assert_allclose(np.asarray(mean), x64.mean(0), atol=1e-5)
-    assert cov.shape == (37, 37)
-
-
-def test_fused_covariance_no_centering(rng):
-    x = rng.normal(size=(600, 40)).astype(np.float32)
-    cov, mean = covariance_fused(x, mean_centering=False, interpret=True)
-    want = x.astype(np.float64).T @ x.astype(np.float64) / (600 - 1)
-    np.testing.assert_allclose(np.asarray(cov), want, atol=5e-3)
-    np.testing.assert_allclose(np.asarray(mean), np.zeros(40), atol=0)
-
-
 def test_fused_respects_row_mask(rng):
-    x = rng.normal(size=(520, 30)).astype(np.float32)
-    mask = np.ones(520, dtype=np.float32)
-    mask[500:] = 0.0  # rows beyond 500 are garbage
-    x[500:] = 1e6
-    cov, _ = covariance_fused(x, mask=mask, interpret=True)
-    want = np.cov(x[:500].astype(np.float64), rowvar=False)
-    np.testing.assert_allclose(np.asarray(cov), want, atol=5e-3)
+    """Rows whose rowmul is 0 contribute nothing, whatever they hold."""
+    rows, n, valid = _BLOCK_R, 2 * _BLOCK_N, 500
+    x = rng.normal(size=(rows, n)).astype(np.float32)
+    x[valid:] = 1e6  # rows beyond 500 are garbage
+    rowmul = np.zeros(rows, dtype=np.float32)
+    rowmul[:valid] = 1.0
+    mean = x[:valid].mean(axis=0)
+    got = fused_centered_gram(
+        jnp.asarray(x), jnp.asarray(mean), jnp.asarray(rowmul),
+        interpret=True, precision="highest",
+    )
+    xc = x[:valid].astype(np.float64) - mean.astype(np.float64)
+    np.testing.assert_allclose(np.asarray(got), xc.T @ xc, atol=5e-3)
 
 
 def test_unpadded_shape_rejected(rng):
     x = jnp.asarray(rng.normal(size=(100, 37)).astype(np.float32))
     with pytest.raises(ValueError, match="padded"):
         fused_centered_gram(x, jnp.zeros(37), jnp.ones(100), interpret=True)
-
-
-def test_pad_helper():
-    x = np.ones((10, 5), dtype=np.float32)
-    xp, rm, n = pad_for_fused_gram(x)
-    # features pad to an EVEN number of _BLOCK_N tiles (folded-grid req)
-    assert xp.shape == (_BLOCK_R, 2 * _BLOCK_N) and n == 5
-    assert rm.sum() == 10
-    assert (xp.shape[1] // _BLOCK_N) % 2 == 0
 
 
 def test_symmetric_matches_full_grid(rng):
@@ -87,21 +63,6 @@ def test_symmetric_matches_full_grid(rng):
     )
     np.testing.assert_array_equal(sym, sym.T)
     np.testing.assert_allclose(sym, full, rtol=1e-6, atol=1e-5)
-
-
-def test_pallas_flag_harmless_on_cpu(rng, monkeypatch):
-    """TPUML_PALLAS_GRAM=1 must not change behavior off-TPU (Pallas only
-    lowers on the TPU family; CPU silently keeps the XLA path)."""
-    from spark_rapids_ml_tpu import PCA
-
-    monkeypatch.setenv("TPUML_PALLAS_GRAM", "1")
-    x = rng.normal(size=(300, 12))
-    m = PCA().setK(3).fit(x)
-    monkeypatch.delenv("TPUML_PALLAS_GRAM")
-    base = PCA().setK(3).fit(x)
-    import numpy as np
-
-    np.testing.assert_allclose(np.abs(m.pc), np.abs(base.pc), atol=1e-7)
 
 
 @pytest.mark.parametrize("bn,br", [(256, 512), (128, 256)])

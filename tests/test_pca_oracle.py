@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from spark_rapids_ml_tpu import PCA
-from spark_rapids_ml_tpu.ops.pca_kernel import pca_fit_kernel, pca_transform_kernel
+from spark_rapids_ml_tpu.ops.pca_kernel import pca_transform_kernel
 
 from conftest import numpy_pca_oracle
 
@@ -135,18 +135,25 @@ def test_transform_host_path_agrees(rng):
 
 
 def test_masked_fit_ignores_padding(rng):
-    # Static-shape padding: padded rows masked out must not change results.
-    import jax.numpy as jnp
+    # Static-shape padding: the stream's padded tail (27 masked rows of a
+    # 64-row batch, made garbage here) must not change results.
+    from spark_rapids_ml_tpu.data.batches import BatchSource
+    from spark_rapids_ml_tpu.ops.streaming import stream_covariance
 
-    x = rng.normal(size=(37, 5))
-    pad = np.zeros((27, 5))
-    x_padded = np.concatenate([x, pad])
-    mask = np.concatenate([np.ones(37), np.zeros(27)])
-    res = pca_fit_kernel(jnp.asarray(x_padded), 3, mask=jnp.asarray(mask))
-    pc, evr, mean = numpy_pca_oracle(x, 3)
-    np.testing.assert_allclose(np.asarray(res.components), pc, atol=ABS_TOL)
-    np.testing.assert_allclose(np.asarray(res.explained_variance), evr, atol=ABS_TOL)
-    np.testing.assert_allclose(np.asarray(res.mean), mean, atol=ABS_TOL)
+    x = rng.normal(loc=2.0, size=(37, 5))
+
+    class GarbageTail(BatchSource):
+        def batches(self):
+            for batch, mask in super().batches():
+                batch[~mask] = 1e6
+                yield batch, mask
+
+    # a list of chunks: a matrix source would clamp the batch to its 37 rows
+    cov, mean, count = stream_covariance(GarbageTail([x], batch_rows=64))
+    assert int(count) == 37
+    np.testing.assert_allclose(
+        np.asarray(cov), np.cov(x, rowvar=False), atol=ABS_TOL)
+    np.testing.assert_allclose(np.asarray(mean), x.mean(axis=0), atol=ABS_TOL)
 
 
 def test_transform_kernel_batched(rng):

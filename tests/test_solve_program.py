@@ -235,9 +235,9 @@ def test_rolled_loop_runs_the_iterations_it_is_told(monkeypatch, n_iter,
     """The convergence envelope of ``tests/test_chip_smoke.py``'s tail
     check, read through the gated program: four iterations meet it, three
     miss it (0.94 and 8.5 of the envelope there)."""
-    shape = chip_smoke.Shape(n_features=512, k=64, one_shot_rows=4096, top=8)
+    shape = chip_smoke.Shape(n_features=512, k=64, in_memory_rows=4096, top=8)
     oracle = chip_smoke.oracle_pca(
-        chip_smoke.make_rows(shape.one_shot_rows, shape.n_features), shape.k)
+        chip_smoke.make_rows(shape.in_memory_rows, shape.n_features), shape.k)
     bars = {"mean": 1e-6, "ortho": 1e-6, "pc_top": 1e-2, "evr_top": 1e-6,
             "evr_envelope": 3.0, "missed": 5e-2}
     real = randomized.randomized_pca_from_covariance

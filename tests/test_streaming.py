@@ -1,5 +1,6 @@
 """Streaming accumulation must equal one-shot fit (batch-size invariance)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -61,7 +62,7 @@ def test_donation_keeps_single_gram_buffer(rng):
     assert float(stats.count) == 80.0
 
 
-# -- production Gram dispatch (update_stats_auto / fused_update_applicable) --
+# -- production Gram dispatch (update_stats_auto / accumulate_path) ---------
 
 
 def _aligned_stats_and_batch(rng, rows=None, n=None, dtype=jnp.float32):
@@ -78,65 +79,74 @@ def test_fused_dispatch_rejects_cpu_and_auto_path_runs(rng):
     """On CPU the gate must pick the XLA path (Pallas doesn't lower) and
     update_stats_auto must still accumulate correctly through it."""
     from spark_rapids_ml_tpu.ops.streaming import (
-        fused_update_applicable,
+        accumulate_path,
         update_stats_auto,
     )
 
     stats, batch = _aligned_stats_and_batch(rng)
-    assert not fused_update_applicable(stats.gram, batch, None)
+    assert accumulate_path(stats.gram, batch, None) == "xla"
     out = update_stats_auto(stats, batch)
     assert int(out.count) == batch.shape[0]
 
 
-def test_fused_dispatch_shape_and_flag_branches(rng, monkeypatch):
+def test_fused_dispatch_shape_branches(rng, monkeypatch):
     """Every rejection branch of the gate, with the platform check stubbed
-    to 'tpu' so shape/flag logic is what's under test (CPU CI otherwise
+    to 'tpu' so shape logic is what's under test (CPU CI otherwise
     short-circuits before reaching it)."""
     import spark_rapids_ml_tpu.ops.streaming as streaming
     from spark_rapids_ml_tpu.ops.pallas_gram import _BLOCK_N, _BLOCK_R
-    from spark_rapids_ml_tpu.ops.streaming import fused_update_applicable
+    from spark_rapids_ml_tpu.ops.streaming import accumulate_path
 
     monkeypatch.setattr(streaming, "_gram_platform", lambda acc: "tpu")
 
     stats, batch = _aligned_stats_and_batch(rng)
-    ok = fused_update_applicable(stats.gram, batch, None)
-    assert ok  # aligned + f32 + tpu + no mask ⇒ fused
+    # aligned + f32 + tpu + no mask ⇒ fused
+    assert accumulate_path(stats.gram, batch, None) == "pallas"
 
     # mask present ⇒ XLA
     mask = jnp.ones((batch.shape[0],))
-    assert not fused_update_applicable(stats.gram, batch, mask)
+    assert accumulate_path(stats.gram, batch, mask) == "xla"
 
-    # kill switch wins over everything
-    monkeypatch.setenv("TPUML_PALLAS_GRAM", "0")
-    assert not fused_update_applicable(stats.gram, batch, None)
-    monkeypatch.delenv("TPUML_PALLAS_GRAM")
-
-    # misaligned rows ⇒ XLA (update_stats_fused does not pad)
-    assert not fused_update_applicable(stats.gram, batch[: _BLOCK_R - 8], None)
+    # misaligned rows ⇒ XLA (the fused step does not pad)
+    assert accumulate_path(stats.gram, batch[: _BLOCK_R - 8], None) == "xla"
 
     # odd feature-tile count can't fold ⇒ XLA
     stats3, batch3 = _aligned_stats_and_batch(rng, n=3 * _BLOCK_N)
-    assert not fused_update_applicable(stats3.gram, batch3, None)
+    assert accumulate_path(stats3.gram, batch3, None) == "xla"
 
     # non-f32 accumulator ⇒ XLA
     stats64, batch64 = _aligned_stats_and_batch(rng, dtype=jnp.float64)
-    assert not fused_update_applicable(stats64.gram, batch64, None)
+    assert accumulate_path(stats64.gram, batch64, None) == "xla"
+
+    # a traced accumulator has no device to ask ⇒ XLA
+    seen = []
+    jax.eval_shape(
+        lambda g: seen.append(accumulate_path(g, batch, None)) or g,
+        stats.gram)
+    assert seen == ["xla"]
 
 
-def test_symmetric_cost_heuristic_bands():
-    """The auto gate must not select Pallas in the width bands where
-    padding to an even tile count costs more than the XLA dot_general."""
-    from spark_rapids_ml_tpu.ops.pallas_gram import (
-        _BLOCK_N,
-        symmetric_cost_wins,
-    )
+def test_gate_never_pads_a_width(monkeypatch):
+    """The gate picks Pallas only at a width the folded grid takes as it
+    is: in the bands between, padding to an even tile count would cost
+    more than the XLA dot_general (the 784 cell's side of the choice)."""
+    import spark_rapids_ml_tpu.ops.streaming as streaming
+    from spark_rapids_ml_tpu.ops.pallas_gram import _BLOCK_N, _BLOCK_R
+    from spark_rapids_ml_tpu.ops.streaming import accumulate_path
 
+    monkeypatch.setattr(streaming, "_gram_platform", lambda acc: "tpu")
     block = 2 * _BLOCK_N
-    assert symmetric_cost_wins(4 * block)       # aligned: half the work
-    assert symmetric_cost_wins(block)           # aligned at one tile pair
-    assert not symmetric_cost_wins(block + 76)  # pads to 2·block: 2× XLA
-    # above √2·block (≈1449 for 1024-blocks): padding to 2·block wins again
-    assert symmetric_cost_wins(int(block * 1.45))
+
+    def path(n):
+        acc = jax.ShapeDtypeStruct((n, n), jnp.float32)
+        batch = jax.ShapeDtypeStruct((_BLOCK_R, n), jnp.float32)
+        return accumulate_path(acc, batch, None)
+
+    assert path(4 * block) == "pallas"      # aligned: half the work
+    assert path(block) == "pallas"          # aligned at one tile pair
+    assert path(block + 76) == "xla"        # would pad to 2·block: 2× XLA
+    assert path(784) == "xla"
+    assert path(int(block * 1.45)) == "xla"
 
 
 def test_centered_gram_auto_matches_plain(rng, monkeypatch):
@@ -154,34 +164,3 @@ def test_centered_gram_auto_matches_plain(rng, monkeypatch):
     a = update_centered_gram_auto(jnp.zeros((n, n), jnp.float32), batch, mean)
     b = update_centered_gram(jnp.zeros((n, n), jnp.float32), batch, mean)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
-
-
-def test_block_shape_reaches_fused_dispatch(monkeypatch):
-    """Block-shape overrides must reach the compiled kernel: the eager
-    wrapper reads gram_block_shape() per call and threads it as STATIC
-    jit args — a read inside the traced body would bake the first
-    compile's shape into the cache and silently ignore later overrides
-    (the bug the round-4 wave-2 A/B initially hit)."""
-    import spark_rapids_ml_tpu.ops.streaming as streaming
-    from spark_rapids_ml_tpu.ops import pallas_gram
-
-    seen = []
-
-    def fake_blocked(stats, batch, *, bn, br, precision=None):
-        seen.append((bn, br))
-        return stats
-
-    monkeypatch.setattr(streaming, "_update_stats_fused_blocked",
-                        fake_blocked)
-    stats = streaming.init_stats(8, dtype=jnp.float32)
-    batch = jnp.zeros((4, 8), dtype=jnp.float32)
-
-    monkeypatch.setattr(pallas_gram, "_BLOCK_N", 512)
-    monkeypatch.setattr(pallas_gram, "_BLOCK_R", 1024)
-    streaming.update_stats_fused(stats, batch)
-    monkeypatch.setattr(pallas_gram, "_BLOCK_N", 1024)
-    streaming.update_stats_fused(stats, batch)
-    assert seen == [(512, 1024), (1024, 1024)]
-
-    bn, br = pallas_gram.gram_block_shape()
-    assert (bn, br) == (1024, 1024)

@@ -415,13 +415,12 @@ def test_tile_aligned_batches_take_the_pallas_path_on_every_chip(monkeypatch):
     the XLA way. The CPU cannot run the kernel, so the platform seam says
     "tpu" and the fused step is stood in for by the XLA one: what is under
     test is the choice, per chip, and its name."""
-    from spark_rapids_ml_tpu.ops.pallas_gram import gram_block_shape
+    from spark_rapids_ml_tpu.ops.pallas_gram import _BLOCK_N, _BLOCK_R as br
 
-    bn, br = gram_block_shape()
-    n = 2 * bn
+    n = 2 * _BLOCK_N
     monkeypatch.setattr(streaming, "_gram_platform", lambda acc: "tpu")
     monkeypatch.setattr(
-        streaming, "_update_centered_gram_fused",
+        streaming, "_update_centered_gram_fused_blocked",
         lambda acc, batch, mean, precision=None: streaming.update_centered_gram(
             acc, batch, mean, None, precision=precision))
     rng = np.random.default_rng(5)

@@ -116,13 +116,17 @@ def test_pca_streamed_oneshot_iterator(data):
     )
 
 
-def test_pca_size_threshold_triggers_streaming(data, monkeypatch):
-    monkeypatch.setenv("TPUML_STREAM_THRESHOLD_BYTES", "1024")
-    streamed = PCA().setK(4).setBatchRows(256).fit(data)
-    monkeypatch.setenv("TPUML_STREAM_THRESHOLD_BYTES", str(1 << 40))
-    oneshot = PCA().setK(4).fit(data)
+def test_pca_matrix_in_several_batches_equals_one_batch(data):
+    """No size threshold: a matrix is a stream whatever its size, several
+    views and a masked tail under ``batchRows``, one batch of exactly its
+    rows otherwise."""
+    several = PCA().setK(4).setBatchRows(256).fit(data)
+    one = PCA().setK(4).fit(data)
+    assert several.fit_report_.extra["ingest"]["batches"] > 2
+    assert one.fit_report_.extra["ingest"]["batches"] == 2  # one a pass
+    assert one.fit_report_.extra["ingest"]["rows_put"] == 2 * len(data)
     np.testing.assert_allclose(
-        np.abs(streamed.pc), np.abs(oneshot.pc), atol=2e-4
+        np.abs(several.pc), np.abs(one.pc), atol=2e-4
     )
 
 
