@@ -183,11 +183,11 @@ class StreamingPCA:
 # The one-pass (Σxxᵀ, Σx, n) accumulator above loses accuracy to f32
 # cancellation in G − n·μμᵀ when |μ| ≫ σ. A re-iterable source affords the
 # reference's own schedule out-of-core: pass 1 streams (Σx, n) → μ, pass 2
-# streams the CENTERED Gram — numerically the two-pass fit kernel. HBM is
-# NOT bounded at one batch: nothing in the loop waits for the chip, so the
-# puts run a whole pass ahead and fill the device until ``device_put`` itself
-# waits for room. Pass 1 therefore keeps its device batches for pass 2 while
-# ``keep_budget_bytes`` has room: rows that fit on the chip cross once.
+# streams the CENTERED Gram — numerically the two-pass fit kernel. The loop
+# itself needs little HBM (``IngestTrace.put`` holds a chip to
+# ``PUTS_IN_FLIGHT`` batches in flight, whatever the stream's length), so
+# pass 1 keeps its device batches for pass 2 while ``keep_budget_bytes`` has
+# room: rows that fit on the chip cross once.
 
 class MeanStats(NamedTuple):
     col_sum: jnp.ndarray
@@ -297,17 +297,39 @@ def keep_budget_bytes(device, batch_nbytes: int, gram_nbytes: int) -> int:
     return max(0, free - 3 * batch_nbytes - 3 * gram_nbytes)
 
 
+# Puts a chip may have in flight: the batch crossing and the one being
+# re-tiled behind it. The link is FIFO, and a program whose batch has landed
+# still starts only after every transfer queued by then has landed too
+# (recorded v5e traces, ``PERF.md`` §5): with all of a fit's puts issued up
+# front every accumulate step ran behind the LAST landing; with two, step i
+# runs at landing i+1, under crossing i+2. One batch's re-tiling (0.06 s at
+# 4096) fits inside one crossing (0.15 s), so two keep the link full.
+PUTS_IN_FLIGHT = 2
+
+
+def wait_for_landing(x_dev) -> None:
+    """Block until the device batch ``device_put`` returned is on its chip
+    (``block_until_ready`` is a fence on the TPU runtime; it returns at once
+    on the CPU). Tests patch this function to stand in for the chip."""
+    jax.block_until_ready(x_dev)
+
+
 class _Chip:
     """One chip's share of a streamed fit: where its batches go, what of the
-    keep budget is left there, and its own counters."""
+    keep budget is left there, the puts it has not seen land, and its own
+    counters."""
 
     def __init__(self, device):
         self.device = device  # None = JAX's default device, uncommitted
         self.keep_room = 0  # bytes of the chip's budget not taken yet
+        # device batches put here and not yet waited for, oldest first: at
+        # most ``PUTS_IN_FLIGHT``
+        self.in_flight = collections.deque()
         self.counters = {
             "device": str(self.stats_device()), "rows": 0, "rows_put": 0,
             "bytes_put": 0, "batches_kept": 0, "bytes_kept": 0,
             "keep_budget_bytes": 0, "hbm_bytes_in_use": {},
+            "puts_in_flight_max": 0, "put_waits": 0, "put_wait_seconds": 0.0,
         }
 
     def stats_device(self):
@@ -320,7 +342,11 @@ class IngestTrace:
     reach ``fit_report_.extra["ingest"]``. It deals the host batches to the
     fit's chips (``device``: one, or a sequence of them) whole and in turn,
     and holds the device batches a two-pass fit keeps from pass 1 for
-    pass 2 (``keep`` / ``replay``), each chip under its own budget."""
+    pass 2 (``keep`` / ``replay``), each chip under its own budget. A chip
+    has at most ``PUTS_IN_FLIGHT`` (two) puts in flight: its third waits for
+    its first to land, because a landed batch's step starts only after the
+    transfers queued behind it (v5e traces: behind the fit's last landing
+    when all puts went out at once)."""
 
     def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
         self.timer = timer if timer is not None else PhaseTimer()
@@ -338,6 +364,7 @@ class IngestTrace:
             "batches_kept": 0, "bytes_kept": 0, "keep_budget_bytes": 0,
             "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
+            "puts_in_flight_max": 0, "put_waits": 0, "put_wait_seconds": 0.0,
             "hbm_bytes_in_use": {},
             "chips": len(self.chips), "collective_bytes": {},
             "per_chip": [chip.counters for chip in self.chips],
@@ -396,17 +423,35 @@ class IngestTrace:
         self.pass_rows += valid
         self.chips[c].counters["rows"] += valid
 
+    def _await_window(self, chip: _Chip) -> None:
+        """Before a put that would be ``chip``'s third in flight: wait for
+        its oldest to land, and let go of it."""
+        if len(chip.in_flight) < PUTS_IN_FLIGHT:
+            return
+        t0 = time.perf_counter()
+        wait_for_landing(chip.in_flight.popleft())
+        seconds = time.perf_counter() - t0
+        for counters in (self.counters, chip.counters):
+            counters["put_waits"] += 1
+            counters["put_wait_seconds"] += seconds
+
     def put(self, batch, mask, dtype):
         """The next host batch → the chip whose turn it is, whole and in
         one hop (``jnp.asarray`` would land it on device 0 whatever the
-        chip is). (chip index, device batch, device mask)."""
+        chip is), once the chip's window has room (the wait is part of the
+        put stage). (chip index, device batch, device mask)."""
         c = self.turn % len(self.chips)
         chip = self.chips[c]
         self.turn += 1
         with self.stage(SPAN_PUT, PHASE_PUT, "put_seconds_max"):
+            self._await_window(chip)
             x = np.asarray(batch, dtype=dtype)
             x_dev = jax.device_put(x, chip.device)
             m_dev = None if mask is None else jax.device_put(mask, chip.device)
+        chip.in_flight.append(x_dev)
+        for counters in (self.counters, chip.counters):
+            counters["puts_in_flight_max"] = max(
+                counters["puts_in_flight_max"], len(chip.in_flight))
         self.put_rows = x.shape[0] if mask is None else int(mask.sum())
         self._count_rows(c, self.put_rows)
         self.itemsize = x.itemsize
@@ -459,6 +504,13 @@ class IngestTrace:
                 self.turn += 1
             else:
                 yield self.put(batch, mask, dtype)
+
+    def release(self) -> None:
+        """Let go of every device batch held here (kept for pass 2, or in a
+        chip's window): none outlives the walks over it."""
+        self.kept.clear()
+        for chip in self.chips:
+            chip.in_flight.clear()
 
     def accumulate(self, path: str):
         self.counters["accumulate_calls"][path] += 1
@@ -549,6 +601,12 @@ def stream_covariance(
     backend reports no memory, as on the CPU). The arithmetic is the same
     either way. Returns device arrays; covariance is normalized by n−1 as
     everywhere in this package.
+    A chip has at most two puts in flight (``PUTS_IN_FLIGHT``; the third
+    waits inside ``IngestTrace.put`` for the first to land): the v5e traces
+    show a landed batch's step starting only after every transfer queued
+    behind it, so a loop that issues all its puts at once runs every step
+    after the last landing, and one that issues two runs step i under
+    crossing i+2.
     ``device`` is one chip or a sequence of them. Over several, the host
     batches are dealt to the chips whole and in turn; each chip sums its own
     with the programs the one-chip fit runs, keeps its own batches under its
@@ -596,7 +654,7 @@ def stream_covariance(
                             grams[c], x_dev, means[c], m_dev,
                             precision=precision)
         finally:
-            ingest.kept.clear()  # no kept batch outlives the walk over it
+            ingest.release()  # no device batch outlives the walks over it
         if several:
             (gram_acc,) = collective_sum(ingest, [(g,) for g in grams])
         else:
@@ -616,13 +674,16 @@ def stream_covariance(
         return gram_acc / denom, means[0], count
 
     stats = [init_stats(n, dtype=dtype, device=d) for d in devices]
-    with ingest.walk(SPAN_PASS_STATS):
-        for batch, mask in ingest.batches(source):
-            c, x_dev, m_dev = ingest.put(batch, mask, dtype)
-            with ingest.accumulate(
-                    accumulate_path(stats[c].gram, x_dev, m_dev)):
-                stats[c] = update_stats_auto(stats[c], x_dev, m_dev,
-                                             precision=precision)
+    try:
+        with ingest.walk(SPAN_PASS_STATS):
+            for batch, mask in ingest.batches(source):
+                c, x_dev, m_dev = ingest.put(batch, mask, dtype)
+                with ingest.accumulate(
+                        accumulate_path(stats[c].gram, x_dev, m_dev)):
+                    stats[c] = update_stats_auto(stats[c], x_dev, m_dev,
+                                                 precision=precision)
+    finally:
+        ingest.release()
     ingest.set_data(n)
     if several:
         total = collective_stats(ingest, stats)

@@ -92,12 +92,16 @@ class DistributedStreamingPCA:
         # the ONE collective of the hand-fed fit, then the one-chip solve;
         # the per-chip accumulators stay as they are (finalize does not end
         # the stream)
-        return jax.block_until_ready(
+        result = jax.block_until_ready(
             finalize_stats(
                 self._total(), k, mean_centering=mean_centering,
                 solver=solver
             )
         )
+        # every batch fed so far has been summed: the put window's last two
+        # a chip need not stay on it until the next ``partial_fit``
+        self._ingest.release()
+        return result
 
 
 @fit_instrumentation("distributed_streaming_pca")

@@ -167,14 +167,19 @@ def test_sub_phases_are_in_fit_timings(fitted):
     assert t["covariance/put"] > 0 and t["covariance/dispatch"] > 0
     # the report's phases carry them too
     assert set(t) <= set(fitted["report"].phases)
-    # the spans' own seconds are the phases' seconds, to the clock's grain
-    by_name = {}
+    # a stage's timer starts before its span and stops after it, so a
+    # phase's seconds are its spans' seconds and a little more per stage:
+    # opening and filing the span, and whatever a loaded host (six test
+    # workers) takes the thread off the core for in between
+    seconds, stages = {}, {}
     for e in fitted["events"]:
-        by_name[e.name] = by_name.get(e.name, 0.0) + e.dur_us / 1e6
-    assert by_name[streaming.SPAN_PUT] == pytest.approx(
-        t["covariance/put"], abs=2e-3)
-    assert by_name[pca_module.SPAN_FETCH] == pytest.approx(
-        t["fetch"], abs=2e-3)
+        seconds[e.name] = seconds.get(e.name, 0.0) + e.dur_us / 1e6
+        stages[e.name] = stages.get(e.name, 0) + 1
+    grain, slack = 1e-5, 0.05  # a span's µs rounding; seconds a stage
+    for span, phase in ((streaming.SPAN_PUT, "covariance/put"),
+                        (pca_module.SPAN_FETCH, "fetch")):
+        assert seconds[span] - grain * stages[span] <= t[phase] \
+            <= seconds[span] + slack * stages[span], (span, phase)
 
 
 def test_ingest_counters(fitted):
