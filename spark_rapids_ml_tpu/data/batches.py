@@ -20,6 +20,8 @@ see ``ops/covariance.covariance_from_stats``).
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 from typing import Iterator, Optional, Tuple
 
@@ -53,6 +55,48 @@ def auto_batch_rows(n_features: int, target_bytes: int = 128 << 20,
     return max(1024, (rows // 256) * 256)
 
 
+# What a walk over a source counts (``fit_report_.extra["ingest"]`` of a
+# streamed fit): the chunks as they arrive and whether reading one left its
+# rows where they were (``chunks_viewed``) or wrote them into a new array
+# (``chunks_copied``: a list densified, sparse rows filled in), and the
+# fixed-shape batches as they leave — slices of one chunk
+# (``batches_viewed``) or assembled from several / padded, by a host copy of
+# ``bytes_reblocked`` bytes (``batches_copied``).
+SOURCE_COUNTERS = {
+    "chunks": 0, "chunk_rows_min": None, "chunk_rows_max": 0,
+    "chunks_viewed": 0, "chunks_copied": 0,
+    "batches_viewed": 0, "batches_copied": 0, "bytes_reblocked": 0,
+}
+
+
+class _Untraced:
+    """What ``BatchSource.batches`` reports to when nobody listens: the
+    interface ``ops.streaming.IngestTrace`` gives it."""
+
+    def __init__(self):
+        self.counters = dict(SOURCE_COUNTERS)
+
+    def next_stage(self, part: str):
+        return contextlib.nullcontext()
+
+
+def columnar_chunks(input_col: Optional[str] = None):
+    """``chunk_transform`` that reads a columnar chunk (a
+    ``pyarrow.RecordBatch`` or ``Table``) through ``data.arrow
+    .column_to_matrix`` and leaves every other chunk alone."""
+
+    def read(chunk):
+        from spark_rapids_ml_tpu.data.arrow import column_to_matrix, is_columnar
+
+        return column_to_matrix(chunk, input_col) if is_columnar(chunk) \
+            else chunk
+
+    return read
+
+
+_EXHAUSTED = object()  # what a source's iterator gives when it has no more
+
+
 def _as_chunk(chunk) -> np.ndarray:
     arr = np.asarray(chunk)
     if arr.ndim == 1:
@@ -64,27 +108,37 @@ def _as_chunk(chunk) -> np.ndarray:
     return arr
 
 
-def streaming_source(dataset, batch_rows: int = 0) -> Optional["BatchSource"]:
+def streaming_source(dataset, batch_rows: int = 0,
+                     input_col: Optional[str] = None
+                     ) -> Optional["BatchSource"]:
     """Return a BatchSource for inherently-streaming fit() inputs (a
     generator / iterator of chunks, or a zero-arg callable producing one),
     else None.
+
+    A chunk may be columnar (a ``pyarrow.RecordBatch``: what Spark's
+    ``mapInArrow`` hands a worker): its vector column ``input_col`` (None =
+    its only column) is then read by ``data.arrow``. A ``RecordBatchReader``
+    is an iterator of such chunks; a ``pyarrow.Table`` (or one
+    ``RecordBatch``) streams as its record batches, re-iterable.
 
     Materializable inputs (arrays, frames, pandas, lists of vectors) return
     None — estimators decide separately whether to stream those by size.
     """
     import pandas as pd
 
+    from spark_rapids_ml_tpu.data.arrow import is_columnar
     from spark_rapids_ml_tpu.data.frame import VectorFrame
 
     if isinstance(dataset, (VectorFrame, pd.DataFrame, np.ndarray, list, tuple)):
         return None
-    if callable(dataset):
-        return BatchSource(dataset, batch_rows=batch_rows)
-    if hasattr(dataset, "__array__"):
+    if is_columnar(dataset):
+        table = dataset
+        dataset = getattr(table, "to_batches", lambda: [table])
+    if not callable(dataset) and (hasattr(dataset, "__array__")
+                                  or not hasattr(dataset, "__next__")):
         return None
-    if hasattr(dataset, "__next__"):
-        return BatchSource(dataset, batch_rows=batch_rows)
-    return None
+    return BatchSource(dataset, batch_rows=batch_rows,
+                       chunk_transform=columnar_chunks(input_col))
 
 
 class BatchSource:
@@ -115,6 +169,7 @@ class BatchSource:
         self._factory = None
         self._oneshot: Optional[Iterator] = None
         self._transform = chunk_transform
+        self.trace = None  # who ``batches()`` reports to: see there
 
         if callable(source):
             # A factory must produce a FRESH iterator per call. `lambda: gen`
@@ -129,7 +184,7 @@ class BatchSource:
             else:
                 self._factory = source
         elif isinstance(source, (list, tuple)):
-            chunks = [self._prep(c) for c in source]
+            chunks = [self._prep(c)[0] for c in source]
             self._factory = lambda: iter(chunks)
         elif hasattr(source, "__array__") or isinstance(source, np.ndarray):
             self._matrix = np.asarray(source)
@@ -145,19 +200,25 @@ class BatchSource:
         self._consumed = False
         self._first_pass_rows: Optional[int] = None
         self.n_features = n_features
-        self._peeked: Optional[np.ndarray] = None
+        # (chunk, viewed) pairs read ahead of the first pass
+        self._peeked: list = []
         if self._matrix is not None:
             self.n_features = self._matrix.shape[1]
         elif self.n_features is None:
-            # Peek one chunk to learn the width (stashed and re-yielded).
-            it = self._factory() if self._factory else self._oneshot
-            try:
-                first = self._prep(next(iter(it)))
-            except StopIteration:
-                raise ValueError("batch source is empty") from None
-            self.n_features = first.shape[1]
+            # Peek chunks up to the first that has rows to learn the width
+            # (stashed and re-yielded): a columnar chunk of no rows has no
+            # width to give.
+            it = iter(self._factory() if self._factory else self._oneshot)
+            peeked = []
+            for chunk in it:
+                peeked.append(self._prep(chunk))
+                if peeked[-1][0].shape[0]:
+                    break
+            if not peeked:
+                raise ValueError("batch source is empty")
+            self.n_features = peeked[-1][0].shape[1]
             if self._factory is None:
-                self._peeked = first
+                self._peeked = peeked
                 self._oneshot = it
             # factory sources: the peek iterator is simply dropped; a fresh
             # pass re-produces every chunk.
@@ -172,47 +233,74 @@ class BatchSource:
     def reiterable(self) -> bool:
         return self._matrix is not None or self._factory is not None
 
-    def _prep(self, chunk) -> np.ndarray:
+    def _prep(self, chunk) -> Tuple[np.ndarray, bool]:
+        """(the chunk as a 2-D array, whether its rows stayed where they
+        were): the array is the chunk itself or a view of another buffer
+        (Arrow's, a reshape), not one made to hold its rows."""
+        raw = chunk
         if self._transform is not None:
             chunk = self._transform(chunk)
-        return _as_chunk(chunk)
+        arr = _as_chunk(chunk)
+        return arr, arr is raw or not arr.flags.owndata or arr.size == 0
 
-    def _chunks(self) -> Iterator[np.ndarray]:
+    def _read(self, chunks, trace) -> Iterator[Tuple[np.ndarray, bool]]:
+        while True:
+            with trace.next_stage("read"):
+                chunk = next(chunks, _EXHAUSTED)
+                if chunk is _EXHAUSTED:
+                    return
+                pair = self._prep(chunk)
+            yield pair
+
+    def _chunks(self, trace) -> Iterator[np.ndarray]:
         if self._matrix is not None:
-            b = self.batch_rows
-            for i in range(0, self._matrix.shape[0], b):
-                yield self._matrix[i:i + b]
-            return
-        if self._factory is not None:
-            for c in self._factory():
-                yield self._prep(c)
-            return
-        if self._consumed:
-            raise RuntimeError(
-                "one-shot batch source already consumed; pass a callable "
-                "returning a fresh iterator (or a matrix/list) to allow "
-                "multiple passes"
-            )
-        self._consumed = True
-        if self._peeked is not None:
-            yield self._peeked
-            self._peeked = None
-        for c in self._oneshot:
-            yield self._prep(c)
+            pairs = [(self._matrix, True)]
+        elif self._factory is not None:
+            pairs = self._read(iter(self._factory()), trace)
+        else:
+            if self._consumed:
+                raise RuntimeError(
+                    "one-shot batch source already consumed; pass a "
+                    "callable returning a fresh iterator (or a matrix/list) "
+                    "to allow multiple passes"
+                )
+            self._consumed = True
+            peeked, self._peeked = self._peeked, []
+            pairs = itertools.chain(peeked, self._read(self._oneshot, trace))
+        counters = trace.counters
+        for chunk, viewed in pairs:
+            rows = chunk.shape[0]
+            counters["chunks"] += 1
+            counters["chunks_viewed" if viewed else "chunks_copied"] += 1
+            counters["chunk_rows_max"] = max(counters["chunk_rows_max"], rows)
+            least = counters["chunk_rows_min"]
+            counters["chunk_rows_min"] = rows if least is None \
+                else min(least, rows)
+            yield chunk
 
     def batches(self) -> Iterator[Tuple[np.ndarray, Optional[np.ndarray]]]:
         """Yield fixed-shape ``(batch, mask)`` pairs; mask None = all valid.
+
+        ``self.trace`` (an ``ops.streaming.IngestTrace``, where a streamed
+        fit has set one) is told what the walk does: ``next_stage("read")``
+        wraps the reading of each chunk, ``next_stage("copy")`` each host
+        copy of re-blocking, and its ``counters`` get ``SOURCE_COUNTERS``'
+        keys counted.
 
         Every FULLY-consumed pass must see the same number of rows as the
         first one — a "re-iterable" factory that actually hands back a
         shared, partially-exhausted underlying iterator (one the identity
         check in ``__init__`` cannot see, e.g. ``lambda: map(f, shared_gen)``)
         would otherwise silently zero out second-pass accumulations."""
+        trace = self.trace or _Untraced()
+        counters = trace.counters
         b, n = self.batch_rows, self.n_features
         carry: list = []
         carry_rows = 0
         pass_rows = 0
-        for chunk in self._chunks():
+        for chunk in self._chunks(trace):
+            if not chunk.shape[0]:
+                continue  # nothing to add, and maybe no width to check
             pass_rows += chunk.shape[0]
             if chunk.shape[1] != n:
                 raise ValueError(
@@ -228,9 +316,14 @@ class BatchSource:
                 carry_rows += take
                 start = take
                 if carry_rows == b:
-                    yield np.concatenate(carry, axis=0), None
+                    with trace.next_stage("copy"):
+                        batch = np.concatenate(carry, axis=0)
+                    counters["batches_copied"] += 1
+                    counters["bytes_reblocked"] += batch.nbytes
+                    yield batch, None
                     carry, carry_rows = [], 0
             while chunk.shape[0] - start >= b:
+                counters["batches_viewed"] += 1
                 yield chunk[start:start + b], None
                 start += b
             if start < chunk.shape[0]:
@@ -239,11 +332,17 @@ class BatchSource:
         if carry_rows:
             # the fill stage flushes exactly at b, so any remainder here is
             # strictly short: pad + mask
-            tail = np.concatenate(carry, axis=0) if len(carry) > 1 else carry[0]
-            padded = np.zeros((b, n), dtype=tail.dtype)
-            padded[:carry_rows] = tail
-            mask = np.zeros((b,), dtype=bool)
-            mask[:carry_rows] = True
+            with trace.next_stage("copy"):
+                tail = np.concatenate(carry, axis=0) if len(carry) > 1 \
+                    else carry[0]
+                padded = np.zeros((b, n), dtype=tail.dtype)
+                padded[:carry_rows] = tail
+                mask = np.zeros((b,), dtype=bool)
+                mask[:carry_rows] = True
+            counters["batches_copied"] += 1
+            # the tail's rows written once into the padded batch, and once
+            # before that where several pieces were joined
+            counters["bytes_reblocked"] += tail.nbytes * min(len(carry), 2)
             yield padded, mask
         if self._first_pass_rows is None:
             self._first_pass_rows = pass_rows

@@ -288,8 +288,12 @@ class PCA(PCAParams):
         # iterator of chunks as it comes, anything else densified and walked
         # as views of the matrix (one batch of exactly its rows when it is
         # under batchRows) — the analogue of the reference's per-partition
-        # chunking (RapidsRowMatrix.scala:168-202).
-        source = streaming_source(dataset, self.getBatchRows())
+        # chunking (RapidsRowMatrix.scala:168-202). A columnar chunk (an
+        # Arrow record batch) gives its vector column: inputCol where that
+        # is set, else its only one.
+        source = streaming_source(
+            dataset, self.getBatchRows(),
+            self.getInputCol() if self.isSet("inputCol") else None)
         if source is None:
             frame = as_vector_frame(dataset, self.getInputCol())
             with timer.phase("densify"):

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_ml_tpu.data.batches import SOURCE_COUNTERS
 from spark_rapids_ml_tpu.obs.memory import device_memory_stats
 from spark_rapids_ml_tpu.obs.report import current_fit
 from spark_rapids_ml_tpu.obs.xprof import tracked_jit
@@ -264,12 +265,22 @@ SPAN_SYNC_COV = "stream:sync/cov"
 STREAM_SPANS = (SPAN_PASS_MEAN, SPAN_PASS_GRAM, SPAN_PASS_STATS, SPAN_NEXT,
                 SPAN_PUT, *SPAN_ACCUMULATE.values(), SPAN_SYNC_COUNT,
                 SPAN_SYNC_COV)
+# inside ``stream:next``, emitted by the source as it is walked
+# (``data.batches.BatchSource.batches``): the pull of the next chunk from
+# the dataset and its reading into a 2-D array (``data.arrow`` for a
+# columnar chunk), and each host copy of re-blocking (a batch assembled from
+# several chunks, a padded tail). Not in ``STREAM_SPANS``: the benchmark
+# lists them apart (``benchmarks/work/reblock.py``), so idle seconds under
+# them count as ``stream:next``'s.
+SPAN_NEXT_PART = {"read": "stream:next/read", "copy": "stream:next/copy"}
 # only a fit over several chips emits these: each wraps the dispatch of one
 # all-reduce (the chips' parts handed to the mesh program) and nothing else
 SPAN_COLLECTIVE = {"mean": "stream:collective/mean",
                    "gram": "stream:collective/gram"}
 
 PHASE_NEXT = "covariance/next"
+PHASE_NEXT_PART = {"read": "covariance/next/read",
+                   "copy": "covariance/next/copy"}
 PHASE_PUT = "covariance/put"
 PHASE_DISPATCH = "covariance/dispatch"
 PHASE_SYNC = "covariance/sync"
@@ -350,6 +361,8 @@ class IngestTrace:
 
     def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
         self.timer = timer if timer is not None else PhaseTimer()
+        for phase in PHASE_NEXT_PART.values():
+            self.timer.add(phase, 0.0)  # there, at 0, in a fit with neither
         devices = device if isinstance(device, (list, tuple)) else (device,)
         self.chips = [_Chip(d) for d in devices]
         self.turn = 0  # batches dealt in this pass: the next goes to
@@ -361,6 +374,7 @@ class IngestTrace:
         self.kept = collections.deque()
         self.counters = {
             "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
+            **SOURCE_COUNTERS,  # counted by the source as it is walked
             "batches_kept": 0, "bytes_kept": 0, "keep_budget_bytes": 0,
             "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
@@ -410,7 +424,15 @@ class IngestTrace:
         # the pass's last put has returned and its last program is queued
         self.hbm(_boundary(span) + ":end")
 
+    def next_stage(self, part: str):
+        """A stage of the source's own inside ``stream:next``: ``"read"``
+        or ``"copy"``."""
+        return self.stage(SPAN_NEXT_PART[part], PHASE_NEXT_PART[part])
+
     def batches(self, source):
+        # the source reports its reads, copies and counts here
+        # (``BatchSource.batches``; a stand-in that does not is left alone)
+        source.trace = self
         batches = source.batches()
         while True:
             with self.stage(SPAN_NEXT, PHASE_NEXT):

@@ -29,43 +29,28 @@ import numpy as np
 
 from spark_rapids_ml_tpu.utils.numeric import sigmoid as _sigmoid
 
-from spark_rapids_ml_tpu.data.vector import rows_to_matrix
-
-# Spark VectorUDT struct tags (pyspark.ml.linalg.VectorUDT.serialize)
-_SPARSE, _DENSE = 0, 1
+from spark_rapids_ml_tpu.data.arrow import column_view, densify_vector_rows
 
 
 def vector_column_to_matrix(column, n_features: Optional[int] = None) -> np.ndarray:
-    """Densify one Arrow (or pylist) VectorUDT column to an (m, n) matrix.
+    """Densify one Arrow (or pylist) VectorUDT column to an (m, n) float64
+    matrix.
 
     Handles dense rows (type=1: values), sparse rows (type=0: size, indices,
     values), plain list rows, and mixed encodings — the dense/sparse
-    equivalence contract of ``PCASuite.scala:155-190``.
+    equivalence contract of ``PCASuite.scala:155-190``. An Arrow column
+    whose rows lie side by side in one values buffer (a dense list column,
+    an all-dense ``VectorUDT`` one) is read from there in one vectorised
+    copy (``data.arrow``); only sparse, mixed and pylist input walk the
+    rows in Python.
     """
     if hasattr(column, "to_pylist"):
+        view = column_view(column)
+        if view is not None:
+            # a copy: callers own their matrix, an Arrow buffer is read-only
+            return np.array(view, dtype=np.float64)
         column = column.to_pylist()
-    rows = []
-    for entry in column:
-        if entry is None:
-            raise ValueError("null vector row in input column")
-        if isinstance(entry, dict):
-            if entry.get("type") == _DENSE or (
-                entry.get("type") is None and entry.get("indices") is None
-            ):
-                rows.append(np.asarray(entry["values"], dtype=np.float64))
-            elif entry.get("type") == _SPARSE:
-                size = int(entry["size"])
-                dense = np.zeros(size)
-                idx = np.asarray(entry["indices"], dtype=np.int64)
-                dense[idx] = np.asarray(entry["values"], dtype=np.float64)
-                rows.append(dense)
-            else:
-                raise ValueError(f"unrecognized vector struct: {entry!r}")
-        else:
-            rows.append(np.asarray(entry, dtype=np.float64).reshape(-1))
-    if not rows:
-        return np.zeros((0, n_features or 0))
-    return rows_to_matrix(rows)
+    return densify_vector_rows(column, n_features)
 
 
 def _batch_weights_agg(batch, weight_col: Optional[str]):

@@ -106,14 +106,26 @@ def fitted(request):
 
 
 def _expected_names(f) -> list:
+    """The fit's spans in order. Inside each ``stream:next`` the source's
+    own: one ``stream:next/read`` a chunk pulled and read (both chunks of
+    128 rows arrive in the ``next`` for batches 1 and 3, the exhausted pull
+    in the last; a one-shot source has read its first chunk before the
+    fit's stream starts, to learn the width) and one ``stream:next/copy``
+    for the padded tail (in that last ``next`` too)."""
     per_pass = f["batches_per_pass"]
+    read, copy = (streaming.SPAN_NEXT_PART[p] for p in ("read", "copy"))
 
     def walk(pass_span, paths):
         names = [pass_span]
-        for path in paths:
-            names += [streaming.SPAN_NEXT, streaming.SPAN_PUT,
-                      streaming.SPAN_ACCUMULATE[path]]
-        return names + [streaming.SPAN_NEXT]  # the exhausted next()
+        for i, path in enumerate(paths):
+            names += [streaming.SPAN_NEXT]
+            if i == 2 or (i == 0 and f["two_pass"]):
+                names += [read]
+            if i == 3 and f["ragged"]:  # the exhausted pull, then the pad
+                names += [read, copy]
+            names += [streaming.SPAN_PUT, streaming.SPAN_ACCUMULATE[path]]
+        names += [streaming.SPAN_NEXT]  # the exhausted next()
+        return names if f["ragged"] else names + [read]
 
     # on the CPU every Gram goes the XLA way, the masked tail included
     names = [pca_module.SPAN_FIT, pca_module.SPAN_STREAMED_COV]
@@ -148,7 +160,9 @@ def test_span_nesting(fitted):
     in_cov = passes + (streaming.SPAN_SYNC_COUNT, streaming.SPAN_SYNC_COV)
     for e in events:
         parent = _parent_of(e, events)
-        if e.name in in_pass:
+        if e.name in streaming.SPAN_NEXT_PART.values():
+            assert parent == streaming.SPAN_NEXT, (e.name, parent)
+        elif e.name in in_pass:
             assert parent in passes, (e.name, parent)
         elif e.name in in_cov:
             assert parent == pca_module.SPAN_STREAMED_COV, (e.name, parent)
