@@ -61,11 +61,15 @@ def auto_batch_rows(n_features: int, target_bytes: int = 128 << 20,
 # (``chunks_copied``: a list densified, sparse rows filled in), and the
 # fixed-shape batches as they leave — slices of one chunk
 # (``batches_viewed``) or assembled from several / padded, by a host copy of
-# ``bytes_reblocked`` bytes (``batches_copied``).
+# ``bytes_reblocked`` bytes (``batches_copied``). The array a copied batch is
+# written into is the trace's to give (``staging``), and the trace counts
+# whether it had been written before (``staging_reused``) or was made for
+# this batch (``staging_fresh``).
 SOURCE_COUNTERS = {
     "chunks": 0, "chunk_rows_min": None, "chunk_rows_max": 0,
     "chunks_viewed": 0, "chunks_copied": 0,
     "batches_viewed": 0, "batches_copied": 0, "bytes_reblocked": 0,
+    "staging_reused": 0, "staging_fresh": 0,
 }
 
 
@@ -78,6 +82,12 @@ class _Untraced:
 
     def next_stage(self, part: str):
         return contextlib.nullcontext()
+
+    def staging(self, shape, dtype) -> np.ndarray:
+        """The array to assemble a copied batch into: a new one every time.
+        Nobody says when such a walk's caller is done with a batch, so no
+        caller ever sees a buffer twice."""
+        return np.empty(shape, dtype)
 
 
 def columnar_chunks(input_col: Optional[str] = None):
@@ -278,6 +288,16 @@ class BatchSource:
                 else min(least, rows)
             yield chunk
 
+    def _join(self, pieces: list, rows: int, trace) -> np.ndarray:
+        """A ``(batch_rows, n_features)`` array of the trace's giving with
+        ``pieces`` (``rows`` rows in all) written into its head, in the
+        dtype ``np.concatenate`` would give them; rows past ``rows`` are
+        the caller's to fill."""
+        dtype = np.result_type(*(piece.dtype for piece in pieces))
+        batch = trace.staging((self.batch_rows, self.n_features), dtype)
+        np.concatenate(pieces, axis=0, out=batch[:rows])
+        return batch
+
     def batches(self) -> Iterator[Tuple[np.ndarray, Optional[np.ndarray]]]:
         """Yield fixed-shape ``(batch, mask)`` pairs; mask None = all valid.
 
@@ -285,7 +305,11 @@ class BatchSource:
         fit has set one) is told what the walk does: ``next_stage("read")``
         wraps the reading of each chunk, ``next_stage("copy")`` each host
         copy of re-blocking, and its ``counters`` get ``SOURCE_COUNTERS``'
-        keys counted.
+        keys counted. A copied batch is written into the array
+        ``trace.staging(shape, dtype)`` gives: a new one in a walk nobody
+        traces; a streamed fit lends one whose pages are already there, and
+        lends it again only when the put that read it has landed — so a
+        batch of such a walk is the consumer's until it asks for the next.
 
         Every FULLY-consumed pass must see the same number of rows as the
         first one — a "re-iterable" factory that actually hands back a
@@ -317,7 +341,7 @@ class BatchSource:
                 start = take
                 if carry_rows == b:
                     with trace.next_stage("copy"):
-                        batch = np.concatenate(carry, axis=0)
+                        batch = self._join(carry, b, trace)
                     counters["batches_copied"] += 1
                     counters["bytes_reblocked"] += batch.nbytes
                     yield batch, None
@@ -333,16 +357,14 @@ class BatchSource:
             # the fill stage flushes exactly at b, so any remainder here is
             # strictly short: pad + mask
             with trace.next_stage("copy"):
-                tail = np.concatenate(carry, axis=0) if len(carry) > 1 \
-                    else carry[0]
-                padded = np.zeros((b, n), dtype=tail.dtype)
-                padded[:carry_rows] = tail
+                padded = self._join(carry, carry_rows, trace)
+                padded[carry_rows:] = 0
                 mask = np.zeros((b,), dtype=bool)
                 mask[:carry_rows] = True
             counters["batches_copied"] += 1
-            # the tail's rows written once into the padded batch, and once
-            # before that where several pieces were joined
-            counters["bytes_reblocked"] += tail.nbytes * min(len(carry), 2)
+            # the tail's rows, written once: its pieces go straight into the
+            # padded batch
+            counters["bytes_reblocked"] += carry_rows * n * padded.itemsize
             yield padded, mask
         if self._first_pass_rows is None:
             self._first_pass_rows = pass_rows

@@ -369,6 +369,7 @@ class PCA(PCAParams):
             )
             with ingest.sync(SPAN_SYNC_COV):
                 cov = jax.block_until_ready(cov)
+            ingest.all_landed()  # every batch put is in ``cov``
         return cov, mean, count, ingest
 
     def _solve(self, cov, k, timer, ingest):

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import threading
 import time
 from functools import partial
 from typing import NamedTuple, Optional
@@ -325,6 +326,77 @@ def wait_for_landing(x_dev) -> None:
     jax.block_until_ready(x_dev)
 
 
+def put_copies(device) -> bool:
+    """Whether ``jax.device_put`` to ``device`` copies the host array into
+    memory of the device's own, so that nothing reads the host array once
+    the put has landed. A chip does. The CPU backend does not: a host array
+    that is 64-byte aligned *becomes* the device array (jax 0.9.0:
+    zero-copy, whatever ``may_alias`` says), and a later write to it changes
+    the batch a queued step has yet to read — a staging buffer put there is
+    the device array's from then on, and never lent again. Tests patch this
+    function (and make ``device_put`` copy) to stand in for the chip."""
+    return device.platform != "cpu"
+
+
+class StagingPool:
+    """Host arrays for re-blocking whose pages are already there.
+
+    A device batch assembled from several chunks is a host copy into an
+    array of ``batchRows`` × n (2 GiB in the 4096-wide cells). glibc hands
+    a freed block that large back to the kernel, so an array made for every
+    batch pays its first touches every time: 2.2–2.3 s of a 2 GiB copy that
+    takes 0.11 s into touched pages (v5e host, no transparent huge pages;
+    ``PERF.md`` §6 PR 34), and the runtime's ``device_put`` call takes
+    0.17 s for a host array it has not been handed before against 0.5 ms
+    for one it has (§6 PR 35). The pool keeps the arrays between batches *and
+    between fits*: a fit of four batches that made its own would still
+    touch three of four.
+
+    It holds free arrays of ONE (shape, dtype) — the last asked for; asking
+    for another lets the old ones go — and only what ``IngestTrace`` hands
+    back, which it does when the put that read an array has landed. Made
+    empty: nothing is allocated until a streamed fit copies a batch. What a
+    process then keeps between fits is up to (``PUTS_IN_FLIGHT`` + 1) × the
+    fit's chips batches of host memory (6 GiB on one chip at
+    131,072 × 4096 float32)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()  # fits may run on several threads
+        self._key = None  # (shape, dtype) of the arrays in ``_free``
+        self._free: list = []
+
+    def lend(self, shape, dtype, most: int):
+        """(an array of ``shape`` and ``dtype``, whether the pool had it):
+        the borrower's until it hands it to ``take_back`` or drops it. The
+        pool keeps at most ``most`` others."""
+        key = (tuple(shape), np.dtype(dtype))
+        with self._lock:
+            if key != self._key:
+                self._key, self._free = key, []
+            del self._free[most:]
+            if self._free:
+                return self._free.pop(), True
+        return np.empty(*key), False
+
+    def take_back(self, buffer: np.ndarray, most: int) -> None:
+        """``buffer`` is read by nobody any more. Kept if it is of the shape
+        last asked for and fewer than ``most`` are free."""
+        with self._lock:
+            if (buffer.shape, buffer.dtype) == self._key \
+                    and len(self._free) < most:
+                self._free.append(buffer)
+
+    def free(self) -> list:
+        """The arrays nobody has borrowed (a copy of the list)."""
+        with self._lock:
+            return list(self._free)
+
+
+# the process's one pool: staging buffers have to outlive a fit to be worth
+# anything (see ``StagingPool``)
+STAGING = StagingPool()
+
+
 class _Chip:
     """One chip's share of a streamed fit: where its batches go, what of the
     keep budget is left there, the puts it has not seen land, and its own
@@ -333,8 +405,9 @@ class _Chip:
     def __init__(self, device):
         self.device = device  # None = JAX's default device, uncommitted
         self.keep_room = 0  # bytes of the chip's budget not taken yet
-        # device batches put here and not yet waited for, oldest first: at
-        # most ``PUTS_IN_FLIGHT``
+        # (device batch, the staging buffer it was put from or None) of the
+        # puts here not yet waited for, oldest first: at most
+        # ``PUTS_IN_FLIGHT``
         self.in_flight = collections.deque()
         self.counters = {
             "device": str(self.stats_device()), "rows": 0, "rows_put": 0,
@@ -357,7 +430,14 @@ class IngestTrace:
     has at most ``PUTS_IN_FLIGHT`` (two) puts in flight: its third waits for
     its first to land, because a landed batch's step starts only after the
     transfers queued behind it (v5e traces: behind the fit's last landing
-    when all puts went out at once)."""
+    when all puts went out at once).
+    It also lends the source the arrays it assembles copied batches into
+    (``staging``, out of the process's ``STAGING`` pool) and alone says when
+    one is free again: when the put that read it is seen to land —
+    ``_await_window``'s wait, or ``all_landed`` for the window's last —
+    never sooner and never on a guess; a fit that dies drops what it has
+    not seen land. With a copy of batch *i* + 2 assembled while puts *i*
+    and *i* + 1 are in flight, a chip cycles ``PUTS_IN_FLIGHT`` + 1."""
 
     def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
         self.timer = timer if timer is not None else PhaseTimer()
@@ -372,9 +452,16 @@ class IngestTrace:
         self.itemsize = 0
         # (turn, chip index, valid rows, x_dev, m_dev) of pass 1, in order
         self.kept = collections.deque()
+        # staging buffers: the one lent to the source and not put yet, and
+        # those whose puts were still in flight at ``release``
+        self._assembling: Optional[np.ndarray] = None
+        self._unlanded: list = []
+        self._staging_most = (PUTS_IN_FLIGHT + 1) * len(self.chips)
         self.counters = {
             "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
-            **SOURCE_COUNTERS,  # counted by the source as it is walked
+            # counted by the source as it is walked (``staging_*``: by
+            # ``staging``, which the source asks)
+            **SOURCE_COUNTERS,
             "batches_kept": 0, "bytes_kept": 0, "keep_budget_bytes": 0,
             "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
@@ -429,6 +516,25 @@ class IngestTrace:
         or ``"copy"``."""
         return self.stage(SPAN_NEXT_PART[part], PHASE_NEXT_PART[part])
 
+    def staging(self, shape, dtype) -> np.ndarray:
+        """The array the source assembles its next copied batch into: one
+        the pool had (its pages touched) or, failing that, a new one."""
+        self._free_unput()
+        buffer, reused = STAGING.lend(shape, dtype, self._staging_most)
+        self.counters["staging_reused" if reused else "staging_fresh"] += 1
+        self._assembling = buffer
+        return buffer
+
+    def _take_back(self, buffer: Optional[np.ndarray]) -> None:
+        if buffer is not None:
+            STAGING.take_back(buffer, self._staging_most)
+
+    def _free_unput(self) -> None:
+        """A buffer lent and never put (a kept turn passed over in
+        ``replay``) was read by nobody: free when the source moves on."""
+        self._take_back(self._assembling)
+        self._assembling = None
+
     def batches(self, source):
         # the source reports its reads, copies and counts here
         # (``BatchSource.batches``; a stand-in that does not is left alone)
@@ -447,11 +553,14 @@ class IngestTrace:
 
     def _await_window(self, chip: _Chip) -> None:
         """Before a put that would be ``chip``'s third in flight: wait for
-        its oldest to land, and let go of it."""
+        its oldest to land, and let go of it — its staging buffer, if it
+        had one, back to the pool."""
         if len(chip.in_flight) < PUTS_IN_FLIGHT:
             return
         t0 = time.perf_counter()
-        wait_for_landing(chip.in_flight.popleft())
+        x_dev, staged = chip.in_flight.popleft()
+        wait_for_landing(x_dev)
+        self._take_back(staged)
         seconds = time.perf_counter() - t0
         for counters in (self.counters, chip.counters):
             counters["put_waits"] += 1
@@ -470,7 +579,15 @@ class IngestTrace:
             x = np.asarray(batch, dtype=dtype)
             x_dev = jax.device_put(x, chip.device)
             m_dev = None if mask is None else jax.device_put(mask, chip.device)
-        chip.in_flight.append(x_dev)
+        staged, self._assembling = self._assembling, None
+        if staged is not x:
+            # not the batch lent for (passed over), or a cast made ``x``
+            # another array: nobody reads the lent one
+            self._take_back(staged)
+            staged = None
+        elif not put_copies(chip.stats_device()):
+            staged = None  # the device array's from now on
+        chip.in_flight.append((x_dev, staged))
         for counters in (self.counters, chip.counters):
             counters["puts_in_flight_max"] = max(
                 counters["puts_in_flight_max"], len(chip.in_flight))
@@ -529,10 +646,24 @@ class IngestTrace:
 
     def release(self) -> None:
         """Let go of every device batch held here (kept for pass 2, or in a
-        chip's window): none outlives the walks over it."""
+        chip's window): none outlives the walks over it. The staging
+        buffers of the window's puts stay out of the pool until
+        ``all_landed``; a fit that dies never says so, and they go with
+        it."""
         self.kept.clear()
+        self._free_unput()
         for chip in self.chips:
+            self._unlanded += [staged for _, staged in chip.in_flight
+                               if staged is not None]
             chip.in_flight.clear()
+
+    def all_landed(self) -> None:
+        """The host has read a value that every put of the fit fed (the
+        covariance, the solve's result): the buffers ``release`` held back
+        are free."""
+        for staged in self._unlanded:
+            self._take_back(staged)
+        self._unlanded = []
 
     def accumulate(self, path: str):
         self.counters["accumulate_calls"][path] += 1
@@ -629,6 +760,10 @@ def stream_covariance(
     behind it, so a loop that issues all its puts at once runs every step
     after the last landing, and one that issues two runs step i under
     crossing i+2.
+    A batch the source assembles by a host copy is written into a staging
+    buffer lent by the ``IngestTrace`` out of the process's ``STAGING`` pool
+    and taken back when its put has landed (``StagingPool``): the process
+    keeps up to (``PUTS_IN_FLIGHT`` + 1) × chips such buffers between fits.
     ``device`` is one chip or a sequence of them. Over several, the host
     batches are dealt to the chips whole and in turn; each chip sums its own
     with the programs the one-chip fit runs, keeps its own batches under its

@@ -138,4 +138,5 @@ def distributed_streaming_pca_fit(
     with ctx.phase("finalize"), current_run().step("finalize", rows=rows):
         components, evr, _ = pca_from_covariance_gated(cov, k, solver=solver)
         jax.block_until_ready((components, evr))
+    ingest.all_landed()  # every batch put is in the covariance solved
     return PCAFitResult(components, evr, mean)

@@ -319,7 +319,9 @@ def _span_counts(model) -> dict:
     return counts
 
 
-def test_the_source_reports_its_reads_and_copies():
+def test_the_source_reports_its_reads_and_copies(monkeypatch):
+    # a pool no earlier fit of this process has left a buffer in
+    monkeypatch.setattr(streaming, "STAGING", streaming.StagingPool())
     chunks = _chunks()
     model = _estimator().fit(_record_batches(chunks))
     ingest = model.fit_report_.extra["ingest"]
@@ -329,7 +331,12 @@ def test_the_source_reports_its_reads_and_copies():
         # 512 rows are four device batches, every one of them assembled
         # across record-batch boundaries, none padded
         "batches_viewed": 0, "batches_copied": 4,
-        "bytes_reblocked": 4 * BATCH * N * 4}
+        "bytes_reblocked": 4 * BATCH * N * 4,
+        # each written into an array made for it: the CPU keeps the host
+        # array it is handed (``streaming.put_copies``), so none comes back
+        # to be lent again (``test_reblock_staging.py`` stands in for the
+        # chip, where they do)
+        "staging_reused": 0, "staging_fresh": 4}
     assert ingest["batches"] == 4 and ingest["bytes_put"] == 4 * BATCH * N * 4
     assert ingest["accumulate_calls"]["xla"] == 4
     spans = _span_counts(model)
@@ -377,7 +384,14 @@ def test_a_padded_tail_is_a_copy_and_a_list_chunk_is_a_copied_chunk():
 def test_untraced_walks_count_nowhere():
     source = BatchSource(lambda: iter(_chunks()), batch_rows=BATCH)
     assert source.trace is None
-    assert sum(b.shape[0] for b, _ in source.batches()) == 4 * BATCH
+    pool = [id(b) for b in streaming.STAGING.free()]
+    batches = [b for b, _ in source.batches()]
+    assert sum(b.shape[0] for b in batches) == 4 * BATCH
+    assert source.trace is None
+    # every copied batch in an array of its own, the fits' pool not asked
+    assert not any(np.shares_memory(a, b) for i, a in enumerate(batches)
+                   for b in batches[:i])
+    assert [id(b) for b in streaming.STAGING.free()] == pool
 
 
 def test_the_new_names_are_the_benchmarks_and_not_in_the_stream_list():
