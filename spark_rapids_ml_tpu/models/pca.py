@@ -260,20 +260,6 @@ class PCA(PCAParams):
 
         return load_params(PCA, path)
 
-    def _solve_cov_gated(self, cov, k):
-        """Device eigensolve honoring svdSolver, through the residual gate
-        ('auto' → randomized when k ≪ n, verified, dense-eigh fallback);
-        records the choice for ``model.svd_solver_used_``."""
-        import jax
-
-        from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance_gated
-
-        pc, evr, used = pca_from_covariance_gated(
-            cov, k, solver=self.getSvdSolver()
-        )
-        self._svd_solver_used = used
-        return jax.block_until_ready((pc, evr))
-
     @observed_fit("pca")
     def fit(self, dataset) -> "PCAModel":
         timer = PhaseTimer()
@@ -381,17 +367,35 @@ class PCA(PCAParams):
                 return _host_eig_topk(np.asarray(cov, dtype=np.float64), k)
         if ingest is not None:
             ingest.hbm("solve:start")
-        with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
-            if ingest is None:
-                import jax
-
-                cov = jax.device_put(
-                    np.asarray(cov, dtype=_resolve_dtype(self.getDtype())),
-                    _resolve_device(self.getDeviceId()))
-            pc, evr = self._solve_cov_gated(cov, k)
+        pc, evr, self._svd_solver_used = solve_on_chip(
+            cov, k, self.getSvdSolver(), timer,
+            self.getDeviceId(), self.getDtype())
         if ingest is not None:
             ingest.hbm("solve:end")
         return pc, evr
+
+
+def solve_on_chip(cov, k: int, solver: str, timer: PhaseTimer,
+                  device_id: int = -1, dtype: str = "auto"):
+    """(components, explained variance, the solver that answered): the top
+    k of ``cov`` through the residual gate (``ops.eigh
+    .pca_from_covariance_gated``: 'auto' → randomized when k ≪ n, verified,
+    dense-eigh fallback; it notes ``solve`` on the fit's report), as the
+    phase ``solve`` under the span ``xla eigh``. A covariance that is not on
+    a chip yet — a host stage's, or the Spark front's merged one — is put
+    on the chip ``device_id`` names as ``dtype`` first, inside the phase.
+    The one device solve of a PCA fit: ``PCA.fit`` and the Spark front's
+    driver both end here."""
+    import jax
+
+    from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance_gated
+
+    with timer.phase("solve"), TraceRange(SPAN_XLA_EIGH, TraceColor.BLUE):
+        if not isinstance(cov, jax.Array):
+            cov = jax.device_put(np.asarray(cov, dtype=_resolve_dtype(dtype)),
+                                 _resolve_device(device_id))
+        pc, evr, used = pca_from_covariance_gated(cov, k, solver=solver)
+        return (*jax.block_until_ready((pc, evr)), used)
 
 
 def _host_covariance_streamed(source, mean_centering: bool):

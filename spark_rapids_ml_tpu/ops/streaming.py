@@ -90,7 +90,7 @@ def finalize_stats(
     # 'auto' resolves statically (this function is jitted, so the residual
     # gate cannot run here — eager callers wanting the gate use
     # ops.eigh.pca_from_covariance_gated directly, as bench.py and the
-    # PCA model's _solve_cov_gated do)
+    # PCA fit's models.pca.solve_on_chip do)
     components, evr = pca_from_covariance(
         cov, k, flip_signs=flip_signs, solver=solver
     )
@@ -736,6 +736,42 @@ def collective_stats(ingest: IngestTrace, stats: list) -> GramStats:
     return GramStats(gram, col_sum, count[0])
 
 
+def stream_gram_stats(
+    source,
+    dtype=jnp.float32,
+    device=None,
+    precision: Optional[str] = None,
+    ingest: Optional[IngestTrace] = None,
+) -> GramStats:
+    """Stream a ``data.batches.BatchSource`` once into its raw moments
+    (Σxxᵀ, Σx, n): the one-pass walk of ``stream_covariance``, which calls
+    this, handed back before any centring. For a caller that is one part of
+    a larger sum — an executor task of the Spark front hands its partition's
+    moments to a driver that centres once over all partitions. Device
+    arrays, on the first of the fit's chips; ``device``, ``ingest`` and
+    everything about puts, staging buffers and chips as in
+    ``stream_covariance``."""
+    if ingest is None:
+        ingest = IngestTrace(device=device)
+    devices = ingest.devices
+    n = source.n_features
+    stats = [init_stats(n, dtype=dtype, device=d) for d in devices]
+    try:
+        with ingest.walk(SPAN_PASS_STATS):
+            for batch, mask in ingest.batches(source):
+                c, x_dev, m_dev = ingest.put(batch, mask, dtype)
+                with ingest.accumulate(
+                        accumulate_path(stats[c].gram, x_dev, m_dev)):
+                    stats[c] = update_stats_auto(stats[c], x_dev, m_dev,
+                                                 precision=precision)
+    finally:
+        ingest.release()
+    ingest.set_data(n)
+    if len(devices) > 1:
+        return collective_stats(ingest, stats)
+    return stats[0]
+
+
 def stream_covariance(
     source,
     mean_centering: bool = True,
@@ -830,22 +866,8 @@ def stream_covariance(
         denom = jnp.maximum(count - 1, 1)
         return gram_acc / denom, means[0], count
 
-    stats = [init_stats(n, dtype=dtype, device=d) for d in devices]
-    try:
-        with ingest.walk(SPAN_PASS_STATS):
-            for batch, mask in ingest.batches(source):
-                c, x_dev, m_dev = ingest.put(batch, mask, dtype)
-                with ingest.accumulate(
-                        accumulate_path(stats[c].gram, x_dev, m_dev)):
-                    stats[c] = update_stats_auto(stats[c], x_dev, m_dev,
-                                                 precision=precision)
-    finally:
-        ingest.release()
-    ingest.set_data(n)
-    if several:
-        total = collective_stats(ingest, stats)
-    else:
-        (total,) = stats
+    total = stream_gram_stats(source, dtype=dtype, precision=precision,
+                              ingest=ingest)
     cov = covariance_from_stats(
         total.gram, total.col_sum, total.count, mean_centering=mean_centering
     )

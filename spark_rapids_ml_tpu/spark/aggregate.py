@@ -133,6 +133,48 @@ def stats_spark_ddl() -> str:
     return "gram array<double>, col_sum array<double>, count double"
 
 
+def stats_record_batch(gram, col_sum, count):
+    """One stats row (schema ``stats_arrow_schema()``) as Arrow arrays over
+    the NumPy buffers of ``gram`` (n×n or flat) and ``col_sum``: float64
+    C-contiguous arrays are wrapped where they lie, and no Python object is
+    made per element — an n×n Gram as a Python list is n² floats (16.8 M at
+    n = 4096, seconds each way)."""
+    import pyarrow as pa
+
+    def one_list(values):
+        flat = np.ascontiguousarray(values, dtype=np.float64).reshape(-1)
+        return pa.ListArray.from_arrays(
+            pa.array([0, flat.size], type=pa.int32()), pa.array(flat))
+
+    return pa.RecordBatch.from_arrays(
+        [one_list(gram), one_list(col_sum),
+         pa.array([float(count)], type=pa.float64())],
+        schema=stats_arrow_schema())
+
+
+def arrow_stats_rows(table) -> Iterator[Dict[str, object]]:
+    """The rows of collected statistics (a ``pyarrow.Table`` or
+    ``RecordBatch``: what ``DataFrame.toArrow()`` hands the driver) as dicts
+    of Arrow scalars, for ``combine_stats``: nothing is copied and no list
+    is made."""
+    batches = table.to_batches() if hasattr(table, "to_batches") else [table]
+    for batch in batches:
+        columns = {name: batch.column(name) for name in batch.schema.names}
+        for i in range(batch.num_rows):
+            yield {name: column[i] for name, column in columns.items()}
+
+
+def _float64_values(value) -> np.ndarray:
+    """A stats row's array value as a flat float64 array: a view of Arrow's
+    buffer for an Arrow list scalar or array, ``np.asarray`` of anything
+    else (a NumPy array, a Python list)."""
+    values = getattr(value, "values", value)  # a ListScalar's items
+    if hasattr(values, "to_numpy"):
+        return np.asarray(values.to_numpy(zero_copy_only=False),
+                          dtype=np.float64)
+    return np.asarray(values, dtype=np.float64).reshape(-1)
+
+
 def partition_xy_stats(
     batches: Iterable, features_col: str, label_col: str,
     weight_col: Optional[str] = None,
@@ -752,25 +794,84 @@ def combine_stats(
     """Driver-side reduce of per-partition stats rows → (G, Σx, n).
 
     The analogue of the reference's ``cov.reduce(_ + _)``
-    (``RapidsRowMatrix.scala:202``), summing n×n partials on the driver —
-    but over ~P small rows collected once, not a shuffle."""
+    (``RapidsRowMatrix.scala:202``), summing n×n partials on the driver in
+    float64 — but over ~P rows collected once, not a shuffle. A row is a
+    dict or a ``Row``; its ``gram`` and ``col_sum`` may be Arrow-backed
+    (``arrow_stats_rows``: read as views), NumPy arrays or Python lists."""
     gram = None
     col_sum = None
     count = 0
     for row in rows:
         get = row.get if isinstance(row, dict) else row.__getitem__
-        g = np.asarray(get("gram"), dtype=np.float64)
-        s = np.asarray(get("col_sum"), dtype=np.float64)
+        g = _float64_values(get("gram"))
+        s = _float64_values(get("col_sum"))
+        n = s.shape[0]
         if gram is None:
-            n = s.shape[0]
-            gram = np.zeros((n, n))
-            col_sum = np.zeros(n)
-        gram += g.reshape(col_sum.shape[0], col_sum.shape[0])
-        col_sum += s
-        count += float(get("count"))  # Σw: fractional under weightCol
+            # the sums' own arrays: a row's may be Arrow's, read-only
+            gram = np.array(g.reshape(n, n))
+            col_sum = np.array(s)
+        else:
+            gram += g.reshape(n, n)
+            col_sum += s
+        c = get("count")  # Σw: fractional under weightCol
+        count += float(c.as_py() if hasattr(c, "as_py") else c)
     if gram is None:
         raise ValueError("no partition statistics to combine (empty dataset)")
     return gram, col_sum, count
+
+
+def covariance_from_moments(
+    gram: np.ndarray,
+    col_sum: np.ndarray,
+    count: float,
+    mean_centering: bool = True,
+    out: Optional[np.ndarray] = None,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """(covariance, mean) from the global moments, in float64 on the host,
+    centred once over all partitions: ``(G − N·μμᵀ)/(N − 1)``. Written a
+    block of rows at a time, so that the temporaries stay a few MiB beside
+    an n×n result (134 MB at n = 4096); the arithmetic of an element is the
+    same whatever the blocks. ``out`` (n×n float64; ``gram`` itself where
+    the caller owns it, as the sum ``combine_stats`` returns) takes the
+    covariance instead of a new array: on a host where every new array of
+    that size pays its first touches, one 134 MB array less is 0.1 s."""
+    if count < 2 and mean_centering:
+        raise ValueError("mean centering requires more than one row")
+    denom = max(count - 1, 1)
+    if not mean_centering:
+        return np.divide(gram, denom, out=out), np.zeros_like(col_sum)
+    mean = col_sum / max(count, 1)
+    n = mean.shape[0]
+    cov = np.empty((n, n)) if out is None else out
+    step = max(1, (1 << 20) // max(n, 1))  # ≈8 MiB of float64 a block
+    for i in range(0, n, step):
+        block = np.outer(mean[i:i + step], mean)
+        block *= count
+        np.subtract(gram[i:i + step], block, out=cov[i:i + step])
+        cov[i:i + step] /= denom
+    return cov, mean
+
+
+def solve_covariance(cov, k: int, use_xla_svd: bool = True,
+                     device_id: int = -1, timer=None):
+    """(pc, explained variance, the device solver that answered or None):
+    the top k of a host covariance as the phase ``solve`` of ``timer`` — on
+    the driver's accelerator through the gated solve every PCA fit uses
+    (``models.pca.solve_on_chip``: solver 'auto', so a small n resolves to
+    the dense ``eigh`` and n = 4096, k = 256 to the gated randomized
+    program; like the reference's driver-GPU ``calSVD``,
+    ``RapidsRowMatrix.scala:94-95``), or NumPy/LAPACK on the host."""
+    from spark_rapids_ml_tpu.utils.timing import PhaseTimer
+
+    timer = timer if timer is not None else PhaseTimer()
+    if use_xla_svd:
+        from spark_rapids_ml_tpu.models.pca import solve_on_chip
+
+        return solve_on_chip(cov, k, "auto", timer, device_id)
+    from spark_rapids_ml_tpu.models.pca import _host_eig_topk
+
+    with timer.phase("solve"):
+        return (*_host_eig_topk(cov, k), None)
 
 
 def finalize_pca_from_stats(
@@ -782,42 +883,15 @@ def finalize_pca_from_stats(
     use_xla_svd: bool = True,
     device_id: int = -1,
 ):
-    """Driver-side finalization: covariance from global stats → top-k eigh.
-
-    The covariance assembly from already-reduced statistics is a cheap host
-    NumPy step either way; ``use_xla_svd`` selects where the EIGENSOLVE runs
-    — the driver's accelerator (one compiled program, like the reference's
-    driver-GPU ``calSVD``, ``RapidsRowMatrix.scala:94-95``) or NumPy/LAPACK.
+    """Driver-side finalization: covariance from global stats → top-k solve
+    (``covariance_from_moments`` then ``solve_covariance``; the front's
+    ``PCA._fit`` calls the two itself, each under its own span).
     Returns (pc, explained_variance, mean) float64.
     """
-    if count < 2 and mean_centering:
-        raise ValueError("mean centering requires more than one row")
-    denom = max(count - 1, 1)
-    mean = col_sum / max(count, 1) if mean_centering else np.zeros_like(col_sum)
-    if mean_centering:
-        cov = (gram - count * np.outer(mean, mean)) / denom
-    else:
-        cov = gram / denom
-    if use_xla_svd:
-        import jax
-        import jax.numpy as jnp
-
-        from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
-        from spark_rapids_ml_tpu.ops.eigh import pca_from_covariance
-
-        device = _resolve_device(device_id)
-        dtype = _resolve_dtype("auto")
-        cov_dev = jax.device_put(jnp.asarray(cov, dtype=dtype), device)
-        pc, evr = jax.block_until_ready(pca_from_covariance(cov_dev, k))
-        return (
-            np.asarray(pc, dtype=np.float64),
-            np.asarray(evr, dtype=np.float64),
-            mean,
-        )
-    from spark_rapids_ml_tpu.models.pca import _host_eig_topk
-
-    pc, evr = _host_eig_topk(cov, k)
-    return np.asarray(pc), np.asarray(evr), mean
+    cov, mean = covariance_from_moments(gram, col_sum, count, mean_centering)
+    pc, evr, _ = solve_covariance(cov, k, use_xla_svd, device_id)
+    return (np.asarray(pc, dtype=np.float64),
+            np.asarray(evr, dtype=np.float64), mean)
 
 
 # --------------------------------------------------------------------------

@@ -18,23 +18,47 @@ through ``utils.resources.resolve_device_ordinal`` (task env /
 ``TPU_VISIBLE_CHIPS`` pinning from ``scripts/get_tpus_resources.sh``
 discovery), so one chip-pinned executor process sees one chip.
 
-Batches are padded to power-of-two row buckets with a validity mask, so
-an arbitrary partition produces a handful of compiled shapes, not one
-compilation per batch size.
+The covariance statistics — a partition's (Σxxᵀ, Σx, n), of the rows or of
+Z = [X | y] — ride the ONE streamed loop every ``PCA.fit`` runs
+(``ops.streaming.stream_gram_stats``): the task's batch iterator becomes a
+``BatchSource`` that reads record batches as views and re-blocks them into
+``batchRows`` device batches in lent staging buffers, at most two puts are
+in flight, the Gram kernel is ``accumulate_path``'s choice, and the spans
+and counters are the loop's own. The row a task hands back is Arrow over
+the NumPy buffers (``aggregate.stats_record_batch``), not n² Python floats.
+The other statistics families below (Newton partials, Lloyd half-steps,
+histograms) still pad their batches to power-of-two row buckets with a
+validity mask, so an arbitrary partition produces a handful of compiled
+shapes.
 """
 
 from __future__ import annotations
 
+import functools
+import itertools
 from typing import Dict, Iterable, Iterator, Optional
 
 import numpy as np
 
 from spark_rapids_ml_tpu.spark.aggregate import (
-    stats_arrow_schema,
+    stats_record_batch,
     vector_column_to_matrix,
 )
 
 _MIN_BUCKET = 256
+
+# One executor task of the covariance plane, and inside it the hand-back of
+# its statistics: spans in the profiler's trace and the ``obs.spans`` ring,
+# seconds under the ``fit_timings_`` keys (``benchmarks/work/stage.py``
+# mirrors the names).
+SPAN_TASK = "stage:task"
+SPAN_HANDBACK = "stage:handback"
+PHASE_TASK = "stage/task"
+PHASE_HANDBACK = "stage/handback"
+# where a task leaves its timings and counters for a driver in the same
+# process (``fit_report_.extra`` of the fit in flight, until the driver
+# takes them): ``take_task_reports``
+_TASK_REPORTS = "stage_task_reports"
 
 
 def executor_device_available() -> bool:
@@ -59,43 +83,110 @@ def _bucket_rows(m: int) -> int:
     return b
 
 
-def _device_gram_stats(matrices: Iterable[np.ndarray], device, dt):
-    """Core loop shared by the gram and the Z=[X|y] device paths: stream
-    (m, n) host matrices through the donated device accumulator, padded
-    to power-of-two row buckets with a validity mask."""
+def _from_first_row(chunks: Iterable):
+    """``chunks`` from the first that has a row on, or None for a partition
+    without one (an empty partition adds nothing, and has no width to
+    give)."""
+    chunks = iter(chunks)
+    for chunk in chunks:
+        rows = chunk.num_rows if hasattr(chunk, "num_rows") else len(chunk)
+        if rows:
+            return itertools.chain([chunk], chunks)
+    return None
+
+
+def _device_gram_stats(chunks: Iterable, input_col: Optional[str], device,
+                       dt, batch_rows: int = 0, precision=None, row=dict):
+    """One executor task of the covariance plane: the partition's chunks
+    (record batches, whose column ``input_col`` is read by ``data.arrow`` —
+    a null or a ragged row raises, nothing is padded or dropped — or plain
+    (m, n) arrays) through the one streamed loop in its one-pass form, then
+    the hand-back: the Gram fetched, its float64 form, and the stats row
+    ``row(gram=, col_sum=, count=)`` makes of them. None for a partition
+    without a row."""
     import jax
-    import jax.numpy as jnp
 
-    from spark_rapids_ml_tpu.ops.streaming import init_stats, update_stats_auto
+    from spark_rapids_ml_tpu.data.batches import streaming_source
+    from spark_rapids_ml_tpu.models.pca import SPAN_STREAMED_COV
+    from spark_rapids_ml_tpu.ops.streaming import (
+        SPAN_SYNC_COV,
+        IngestTrace,
+        stream_gram_stats,
+    )
+    from spark_rapids_ml_tpu.utils.timing import PhaseTimer
+    from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
 
-    stats = None
-    n_cols: Optional[int] = None
-    for x in matrices:
-        m = x.shape[0]
-        if m == 0:
-            continue
-        if stats is None:
-            n_cols = x.shape[1]
-            stats = init_stats(n_cols, dtype=dt, device=device)
-        bucket = _bucket_rows(m)
-        if bucket != m:
-            padded = np.zeros((bucket, n_cols), dtype=x.dtype)
-            padded[:m] = x
-            mask = np.zeros(bucket, dtype=bool)
-            mask[:m] = True
-            stats = update_stats_auto(
-                stats, jnp.asarray(padded, dtype=dt), jnp.asarray(mask)
-            )
-        else:
-            stats = update_stats_auto(stats, jnp.asarray(x, dtype=dt))
-    if stats is None:
-        return None
-    stats = jax.block_until_ready(stats)
-    return {
-        "gram": np.asarray(stats.gram, dtype=np.float64).ravel().tolist(),
-        "col_sum": np.asarray(stats.col_sum, dtype=np.float64).tolist(),
-        "count": int(stats.count),
-    }
+    timer = PhaseTimer()
+    out, counters = None, None
+    with timer.phase(PHASE_TASK), TraceRange(SPAN_TASK, TraceColor.PURPLE):
+        chunks = _from_first_row(chunks)
+        if chunks is not None:
+            source = streaming_source(chunks, batch_rows, input_col)
+            ingest = IngestTrace(timer, device)
+            counters = ingest.counters
+            with timer.phase("covariance"), TraceRange(
+                    SPAN_STREAMED_COV, TraceColor.RED):
+                stats = stream_gram_stats(source, dtype=dt,
+                                          precision=precision, ingest=ingest)
+                with ingest.sync(SPAN_SYNC_COV):
+                    stats = jax.block_until_ready(stats)
+                ingest.all_landed()  # every batch put is in ``stats``
+            with timer.phase(PHASE_HANDBACK), TraceRange(
+                    SPAN_HANDBACK, TraceColor.CYAN):
+                out = row(
+                    gram=np.asarray(stats.gram, dtype=np.float64),
+                    col_sum=np.asarray(stats.col_sum, dtype=np.float64),
+                    count=int(stats.count))
+    _report_task(timer, counters)
+    return out
+
+
+def _report_task(timer, counters: Optional[dict]) -> None:
+    """Leave the task's seconds and the loop's counters (None: a partition
+    without a row) where a driver in this process finds them."""
+    from spark_rapids_ml_tpu.obs.report import current_fit
+
+    fit = current_fit()  # the null context outside a fit: it keeps nothing
+    fit.note(**{_TASK_REPORTS: [
+        *fit.extra.get(_TASK_REPORTS, ()),
+        {"timings": timer.as_dict(), "ingest": counters}]})
+
+
+def take_task_reports() -> list:
+    """The reports of the tasks that ran in this process inside the fit in
+    flight (``[{"timings": …, "ingest": …}]``, in task order), taken off
+    the fit's report; [] where the tasks ran elsewhere."""
+    from spark_rapids_ml_tpu.obs.report import current_fit
+
+    return current_fit().extra.pop(_TASK_REPORTS, [])
+
+
+def _one_counter(a, b, key: str):
+    """A counter of ``extra["ingest"]`` over two tasks: counts and seconds
+    added; ``*_max`` / ``*_min``, ``chips``, the (unused) keep budget and
+    the boundaries' ``hbm_bytes_in_use`` by their extreme; dicts key by key
+    and ``per_chip`` chip by chip."""
+    if a is None or b is None:
+        return b if a is None else a
+    if isinstance(b, dict):
+        return {k: _one_counter(a.get(k), b.get(k),
+                                key if key == "hbm_bytes_in_use" else k)
+                for k in {**a, **b}}
+    if isinstance(b, list):
+        return [_one_counter(x, y, key) for x, y in zip(a, b)]
+    if isinstance(b, str):  # a chip's name
+        return a
+    if key.endswith("_min"):
+        return min(a, b)
+    if key.endswith("_max") or key in ("chips", "keep_budget_bytes",
+                                       "hbm_bytes_in_use"):
+        return max(a, b)
+    return a + b
+
+
+def sum_ingest_counters(parts: Iterable[dict]) -> dict:
+    """The tasks' ``extra["ingest"]`` as one fit's (``_one_counter``)."""
+    return functools.reduce(lambda a, b: _one_counter(a, b, ""), parts)
 
 
 def partition_gram_stats_device(
@@ -103,32 +194,29 @@ def partition_gram_stats_device(
     input_col: str,
     device_id: int = -1,
     dtype: str = "auto",
+    batch_rows: int = 0,
+    precision=None,
+    row=dict,
 ) -> Iterator[Dict[str, object]]:
     """One partition's (Σxxᵀ, Σx, n), accumulated ON this executor's
-    accelerator.
+    accelerator by the one streamed loop (``_device_gram_stats``).
 
-    Same contract and output row as ``aggregate.partition_gram_stats``
-    (so the driver-side ``combine_stats`` is shared), but the Gram runs as
-    jitted MXU matmuls into a donated device accumulator instead of NumPy
-    on the executor CPU. The f64→f32 note: on accelerators the compute
-    dtype follows the platform default (f32 on TPU) — the same documented
-    precision envelope as every other streamed device fit in this repo.
+    Same contract as ``aggregate.partition_gram_stats`` (the driver-side
+    ``combine_stats`` is shared), with the Gram (n, n) and the column sums
+    as float64 NumPy arrays, not lists. ``batch_rows`` is the estimator's
+    ``batchRows`` (0 = auto-sized ≈128 MiB device batches), ``precision``
+    its resolved ``gramPrecision``. The f64→f32 note: on accelerators the
+    compute dtype follows the platform default (f32 on TPU) — the same
+    documented precision envelope as every other streamed device fit in
+    this repo.
     """
     from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
 
-    device = _resolve_device(device_id)
-    dt = _resolve_dtype(dtype)
-
-    def matrices():
-        for batch in batches:
-            if hasattr(batch, "column"):
-                yield vector_column_to_matrix(batch.column(input_col))
-            else:
-                yield np.asarray(batch, dtype=np.float64)
-
-    row = _device_gram_stats(matrices(), device, dt)
-    if row is not None:
-        yield row
+    out = _device_gram_stats(
+        batches, input_col, _resolve_device(device_id), _resolve_dtype(dtype),
+        batch_rows, precision, row)
+    if out is not None:
+        yield out
 
 
 def _xy_matrices(batches, features_col: str, label_col: str):
@@ -150,31 +238,27 @@ def partition_xy_stats_device(
     label_col: str,
     device_id: int = -1,
     dtype: str = "auto",
+    row=dict,
 ) -> Iterator[Dict[str, object]]:
     """Device counterpart of ``aggregate.partition_xy_stats``: the (n+1)²
     Gram of Z = [X | y] accumulated on this executor's accelerator (the
-    augmented-column trick shared with the streamed LinearRegression)."""
+    augmented-column trick shared with the streamed LinearRegression), by
+    the same loop as the PCA plane: each batch's Z is one chunk of it."""
     from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
 
-    device = _resolve_device(device_id)
-    dt = _resolve_dtype(dtype)
-
-    def matrices():
-        for x, y in _xy_matrices(batches, features_col, label_col):
-            yield np.concatenate([x, y.reshape(-1, 1)], axis=1)
-
-    row = _device_gram_stats(matrices(), device, dt)
-    if row is not None:
-        yield row
+    chunks = (np.concatenate([x, y.reshape(-1, 1)], axis=1)
+              for x, y in _xy_matrices(batches, features_col, label_col))
+    out = _device_gram_stats(
+        chunks, None, _resolve_device(device_id), _resolve_dtype(dtype),
+        row=row)
+    if out is not None:
+        yield out
 
 
 def partition_xy_stats_device_arrow(batches, features_col: str,
                                     label_col: str, device_id: int = -1):
-    import pyarrow as pa
-
-    for row in partition_xy_stats_device(batches, features_col, label_col,
-                                         device_id):
-        yield pa.RecordBatch.from_pylist([row], schema=stats_arrow_schema())
+    yield from partition_xy_stats_device(
+        batches, features_col, label_col, device_id, row=stats_record_batch)
 
 
 def partition_logreg_stats_device(
@@ -415,14 +499,15 @@ def partition_kmeans_stats_device_arrow(batches, input_col: str,
 
 
 def partition_gram_stats_device_arrow(
-    batches, input_col: str, device_id: int = -1
+    batches, input_col: str, device_id: int = -1, batch_rows: int = 0,
+    precision=None,
 ):
     """``mapInArrow`` adapter for the device path — same output schema as
-    the host adapter, so driver combine/finalize code is shared."""
-    import pyarrow as pa
-
-    for row in partition_gram_stats_device(batches, input_col, device_id):
-        yield pa.RecordBatch.from_pylist([row], schema=stats_arrow_schema())
+    the host adapter, so driver combine/finalize code is shared; the row is
+    Arrow over the NumPy buffers, made inside the task's hand-back."""
+    yield from partition_gram_stats_device(
+        batches, input_col, device_id, batch_rows=batch_rows,
+        precision=precision, row=stats_record_batch)
 
 
 def _task_identity():
@@ -479,8 +564,6 @@ def partition_gram_stats_device_collective(
     """
     import os
 
-    import pyarrow as pa
-
     part_id, n_parts = _task_identity()
     os.environ["SPARK_RAPIDS_ML_TPU_COORDINATOR"] = coordinator
     os.environ["SPARK_RAPIDS_ML_TPU_NUM_PROCESSES"] = str(n_parts)
@@ -523,8 +606,7 @@ def partition_gram_stats_device_collective(
 
     if n_parts == 1:
         if local:
-            yield pa.RecordBatch.from_pylist([local[0]],
-                                             schema=stats_arrow_schema())
+            yield stats_record_batch(**local[0])
         return
 
     import jax
@@ -558,14 +640,7 @@ def partition_gram_stats_device_collective(
     count_total = (hi << 20) + lo
     if part_id != 0:
         return
-    yield pa.RecordBatch.from_pylist(
-        [{
-            "gram": total[: n * n].tolist(),
-            "col_sum": total[n * n :].tolist(),
-            "count": count_total,
-        }],
-        schema=stats_arrow_schema(),
-    )
+    yield stats_record_batch(total[: n * n], total[n * n :], count_total)
 
 
 def partition_multinomial_stats_device(

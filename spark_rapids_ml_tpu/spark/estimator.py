@@ -41,12 +41,32 @@ from spark_rapids_ml_tpu.spark._compat import (
 )
 
 from spark_rapids_ml_tpu.spark.aggregate import (
+    arrow_stats_rows,
     combine_stats,
-    finalize_pca_from_stats,
+    covariance_from_moments,
     partition_gram_stats_arrow,
+    solve_covariance,
     stats_spark_ddl,
 )
-from spark_rapids_ml_tpu.obs import observed_transform
+from spark_rapids_ml_tpu.obs import observed_fit, observed_transform
+
+# the driver's half of the stage: reading the collected statistics rows,
+# their float64 sum and the centring (``benchmarks/work/stage.py`` mirrors
+# the names; the executor tasks' are in ``spark/device_aggregate.py``)
+SPAN_MERGE = "stage:merge"
+PHASE_MERGE = "stage/merge"
+
+
+def _collect_stats(mapped):
+    """(the statistics rows of a mapped frame, how they were collected):
+    as Arrow where the frame offers it (``DataFrame.toArrow()``, pyspark >=
+    4.0) — an n×n Gram is then read as a view of its buffer — else
+    ``collect()`` of ``Row``s, which is correct and slow: every Gram element
+    a Python float on the way (16.8 M a row at n = 4096)."""
+    to_arrow = getattr(mapped, "toArrow", None)
+    if to_arrow is not None:
+        return list(arrow_stats_rows(to_arrow())), "arrow"
+    return mapped.collect(), "rows"
 
 
 def _select_stats_plane(executor_device, device_fn, host_fn):
@@ -102,11 +122,25 @@ class _TpuPCAParams(HasInputCol, HasOutputCol):
         "over a joint jax.distributed mesh (no executor-to-driver "
         "partial shipping)",
         typeConverter=TypeConverters.toString)
+    batchRows = Param(
+        Params._dummy(), "batchRows",
+        "rows per device batch of an executor task's stream, whatever the "
+        "size of the record batches Spark hands it (the in-process "
+        "estimator's Param); 0 = auto-size so one f32 batch is ~128 MiB",
+        typeConverter=TypeConverters.toInt)
+    gramPrecision = Param(
+        Params._dummy(), "gramPrecision",
+        "MXU precision of the executors' Gram accumulate (the in-process "
+        "estimator's Param): 'auto' defers to TPUML_GRAM_PRECISION "
+        "(bfloat16_3x); 'bfloat16' is the single-pass arm with its relaxed "
+        "accuracy contract; 'float32'/'highest' force full-precision passes",
+        typeConverter=TypeConverters.toString)
 
     def __init__(self):
         super().__init__()
         self._setDefault(k=None, meanCentering=True, useXlaDot=True,
-                         useXlaSvd=True, deviceId=-1, executorDevice="auto")
+                         useXlaSvd=True, deviceId=-1, executorDevice="auto",
+                         batchRows=0, gramPrecision="auto")
 
     def getK(self):
         return self.getOrDefault(self.k)
@@ -126,6 +160,12 @@ class _TpuPCAParams(HasInputCol, HasOutputCol):
     def getExecutorDevice(self):
         return self.getOrDefault(self.executorDevice)
 
+    def getBatchRows(self):
+        return self.getOrDefault(self.batchRows)
+
+    def getGramPrecision(self):
+        return self.getOrDefault(self.gramPrecision)
+
 
 class PCA(Estimator, _TpuPCAParams):
     """``PCA(k=3, inputCol="features", outputCol="pca_features").fit(df)`` —
@@ -134,7 +174,8 @@ class PCA(Estimator, _TpuPCAParams):
     @keyword_only
     def __init__(self, *, k=None, inputCol=None, outputCol="pca_features",
                  meanCentering=True, useXlaDot=True, useXlaSvd=True,
-                 deviceId=-1, executorDevice="auto"):
+                 deviceId=-1, executorDevice="auto", batchRows=0,
+                 gramPrecision="auto"):
         super().__init__()
         self._setDefault(outputCol="pca_features")
         kwargs = self._input_kwargs
@@ -143,7 +184,8 @@ class PCA(Estimator, _TpuPCAParams):
     @keyword_only
     def setParams(self, *, k=None, inputCol=None, outputCol=None,
                   meanCentering=None, useXlaDot=None, useXlaSvd=None,
-                  deviceId=None, executorDevice=None):
+                  deviceId=None, executorDevice=None, batchRows=None,
+                  gramPrecision=None):
         kwargs = self._input_kwargs
         return self._set(**{k_: v for k_, v in kwargs.items() if v is not None})
 
@@ -171,10 +213,34 @@ class PCA(Estimator, _TpuPCAParams):
     def setExecutorDevice(self, value):
         return self._set(executorDevice=value)
 
+    def setBatchRows(self, value):
+        return self._set(batchRows=value)
+
+    def setGramPrecision(self, value):
+        return self._set(gramPrecision=value)
+
+    def _gram_precision(self):
+        """``gramPrecision`` as the accumulate programs take it: None for
+        'auto', else the validated value."""
+        value = self.getGramPrecision()
+        if value == "auto":
+            return None
+        from spark_rapids_ml_tpu.ops.covariance import resolve_gram_precision
+
+        return resolve_gram_precision(value)
+
+    @observed_fit("pca")
     def _fit(self, dataset) -> "PCAModel":
+        from spark_rapids_ml_tpu.models.pca import SPAN_FETCH
+        from spark_rapids_ml_tpu.utils.timing import PhaseTimer
+        from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+
+        timer = PhaseTimer()
         k = self.getK()
         if k is None:
             raise ValueError("k must be set before fit()")
+        if self.getBatchRows() < 0:
+            raise ValueError("batchRows must be 0 (auto) or positive")
         input_col = self.getInputCol()
         df = dataset.select(input_col)
         executor_device = self.getExecutorDevice()
@@ -229,40 +295,81 @@ class PCA(Estimator, _TpuPCAParams):
                     "scheduling: DataFrame.mapInArrow(barrier=True) "
                     "requires pyspark >= 3.5"
                 ) from exc
-            rows = mapped.collect()
         else:
             # 'auto'/'on' put the Gram on the executor's accelerator (the
             # reference's per-partition executor-GPU GEMM,
-            # RapidsRowMatrix.scala:168-202); host NumPy is the fallback
+            # RapidsRowMatrix.scala:168-202), each task a caller of the one
+            # streamed loop; host NumPy is the fallback
             from spark_rapids_ml_tpu.spark.device_aggregate import (
                 partition_gram_stats_device_arrow,
             )
 
+            batch_rows = self.getBatchRows()
+            precision = self._gram_precision()
             stats = _select_stats_plane(
                 executor_device,
                 lambda b_: partition_gram_stats_device_arrow(
-                    b_, input_col, device_id),
+                    b_, input_col, device_id, batch_rows, precision),
                 lambda b_: partition_gram_stats_arrow(b_, input_col),
             )
-            rows = df.mapInArrow(stats, stats_spark_ddl()).collect()
-        gram, col_sum, count = combine_stats(rows)
-        n_features = col_sum.shape[0]
-        if k > n_features:
-            raise ValueError(
-                f"k = {k} must be at most the number of features {n_features}"
+            mapped = df.mapInArrow(stats, stats_spark_ddl())
+        rows, collected_as = _collect_stats(mapped)
+        with timer.phase(PHASE_MERGE), TraceRange(SPAN_MERGE,
+                                                  TraceColor.PURPLE):
+            gram, col_sum, count = combine_stats(rows)
+            n_features = col_sum.shape[0]
+            if k > n_features:
+                raise ValueError(
+                    f"k = {k} must be at most the number of features "
+                    f"{n_features}")
+            # the sum is this fit's own array: centred where it lies
+            cov, mean = covariance_from_moments(
+                gram, col_sum, count, self.getMeanCentering(), out=gram)
+        self._note_stage(timer, len(rows), int(count), n_features,
+                         collected_as)
+        pc, evr, solver_used = solve_covariance(
+            cov, k, self.getUseXlaSvd(), self.getDeviceId(), timer)
+        with timer.phase("fetch"), TraceRange(SPAN_FETCH, TraceColor.CYAN):
+            from spark_rapids_ml_tpu.models.pca import (
+                PCAModel as LocalPCAModel,
             )
-        pc, evr, mean = finalize_pca_from_stats(
-            gram, col_sum, count, k,
-            mean_centering=self.getMeanCentering(),
-            use_xla_svd=self.getUseXlaSvd(),
-            device_id=self.getDeviceId(),
+
+            model = self._copyValues(PCAModel._from_local(LocalPCAModel(
+                pc=np.asarray(pc, dtype=np.float64),
+                explained_variance=np.asarray(evr, dtype=np.float64),
+                mean=mean)))
+        model.fit_timings_ = timer.as_dict()
+        model.svd_solver_used_ = solver_used
+        return model
+
+    @staticmethod
+    def _note_stage(timer, n_rows, count, n_features, collected_as) -> None:
+        """What the stage tells the fit's report: the data's size,
+        ``extra["stage"]``, and — where the executor tasks ran in this
+        process, so that their reports are here — the tasks' seconds summed
+        into ``timer`` (``stage/task``, ``stage/handback`` and the one
+        loop's ``covariance*`` keys) and their ``extra["ingest"]`` summed.
+        Tasks in other processes leave neither."""
+        from spark_rapids_ml_tpu.obs.report import current_fit
+        from spark_rapids_ml_tpu.spark.device_aggregate import (
+            sum_ingest_counters,
+            take_task_reports,
         )
-        model = PCAModel(
-            pc=DenseMatrix(n_features, k, pc.ravel(order="F").tolist()),
-            explainedVariance=DenseVector(evr.tolist()),
-            mean=DenseVector(mean.tolist()),
-        )
-        return self._copyValues(model)
+
+        fit = current_fit()
+        fit.set_data(rows=count, features=n_features,
+                     nbytes=count * n_features * 4)
+        reports = take_task_reports()
+        for report in reports:
+            for phase, seconds in report["timings"].items():
+                timer.add(phase, seconds)
+        streamed = [r["ingest"] for r in reports if r["ingest"] is not None]
+        if streamed:
+            fit.note(ingest=sum_ingest_counters(streamed))
+        fit.note(stage={
+            "tasks": len(reports), "stats_rows": n_rows,
+            "stats_row_bytes": 8 * (n_features * n_features + n_features + 1),
+            "collected_as": collected_as})
 
     def save(self, path: str, overwrite: bool = False) -> None:
         _save_estimator_params(self, path, overwrite=overwrite)
@@ -316,6 +423,19 @@ class PCAModel(Model, _TpuPCAParams):
 
         return dataset.withColumn(out_col, project(dataset[self.getInputCol()]))
 
+    @staticmethod
+    def _from_local(local) -> "PCAModel":
+        """The in-process model's arrays (``models.pca.PCAModel``: what a
+        fit assembles and what persistence stores) as pyspark linalg
+        values, column-major; no list of Python floats is made."""
+        n, k = local.pc.shape
+        return PCAModel(
+            pc=DenseMatrix(n, k, local.pc.ravel(order="F")),
+            explainedVariance=DenseVector(local.explained_variance),
+            mean=(DenseVector(local.mean)
+                  if local.mean is not None else None),
+        )
+
     # -- persistence (shared wire format) ---------------------------------
     def _to_local(self):
         from spark_rapids_ml_tpu.models.pca import PCAModel as LocalPCAModel
@@ -342,13 +462,7 @@ class PCAModel(Model, _TpuPCAParams):
         from spark_rapids_ml_tpu.models.pca import PCAModel as LocalPCAModel
 
         local = LocalPCAModel.load(path)
-        n, k = local.pc.shape
-        model = PCAModel(
-            pc=DenseMatrix(n, k, local.pc.ravel(order="F").tolist()),
-            explainedVariance=DenseVector(local.explained_variance.tolist()),
-            mean=(DenseVector(local.mean.tolist())
-                  if local.mean is not None else None),
-        )
+        model = PCAModel._from_local(local)
         model._resetUid(local.uid)
         for name in ("k", "inputCol", "outputCol", "meanCentering",
                      "useXlaDot", "useXlaSvd", "deviceId"):

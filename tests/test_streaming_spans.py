@@ -317,13 +317,15 @@ def test_the_benchmarks_span_list_is_what_the_program_emits():
 
 def test_every_span_constant_was_seen_in_some_fit():
     """The constants are not only declared: between them the CPU fits of
-    this file emitted every one but the Pallas accumulate (TPU only)."""
+    this file emitted every one but the Pallas accumulate (TPU only; in the
+    ring all the same where a file that stubs the platform ran earlier in
+    this worker)."""
     seen = {e.name for e in obs_spans.get_recorder().events()}
     for form, rows in FORMS.values():
         PCA().setK(K).set("batchRows", BATCH).fit(
             _dataset(form, _chunks(rows)))
     seen |= {e.name for e in obs_spans.get_recorder().events()}
-    assert set(streaming.STREAM_SPANS) - seen == {
+    assert set(streaming.STREAM_SPANS) - seen <= {
         streaming.SPAN_ACCUMULATE["pallas"]}
 
 
