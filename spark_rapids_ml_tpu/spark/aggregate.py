@@ -134,18 +134,27 @@ def stats_spark_ddl() -> str:
 
 
 def stats_record_batch(gram, col_sum, count):
-    """One stats row (schema ``stats_arrow_schema()``) as Arrow arrays over
-    the NumPy buffers of ``gram`` (n×n or flat) and ``col_sum``: float64
-    C-contiguous arrays are wrapped where they lie, and no Python object is
-    made per element — an n×n Gram as a Python list is n² floats (16.8 M at
-    n = 4096, seconds each way)."""
+    """One stats row (schema ``stats_arrow_schema()``) of the moments as a
+    task fetched them: ``gram`` (n×n or flat) and ``col_sum`` in the
+    device's dtype. The float64 form is made here, by Arrow's cast of a
+    zero-copy Arrow array over the fetched buffer: float32 → float64 is
+    exact, the values come out of Arrow's memory pool (whose freed pages
+    go to the next asker, where a new 134 MB NumPy array at n = 4096 pays
+    32,768 first touches) and belong to the row, so rows held side by side
+    never share one; float64 moments are wrapped where they lie. No Python
+    object is made per element — an n×n Gram as a Python list is n² floats
+    (16.8 M at n = 4096, seconds each way)."""
     import pyarrow as pa
 
     def one_list(values):
-        flat = np.ascontiguousarray(values, dtype=np.float64).reshape(-1)
+        flat = pa.array(values.reshape(-1))
         return pa.ListArray.from_arrays(
-            pa.array([0, flat.size], type=pa.int32()), pa.array(flat))
+            pa.array([0, len(flat)], type=pa.int32()),
+            flat.cast(pa.float64()))
 
+    gram, col_sum = np.ascontiguousarray(gram), np.ascontiguousarray(col_sum)
+    if gram.dtype != np.float64:
+        note_host_array("pooled")  # the cast writes a new float64 array
     return pa.RecordBatch.from_arrays(
         [one_list(gram), one_list(col_sum),
          pa.array([float(count)], type=pa.float64())],
@@ -173,6 +182,46 @@ def _float64_values(value) -> np.ndarray:
         return np.asarray(values.to_numpy(zero_copy_only=False),
                           dtype=np.float64)
     return np.asarray(values, dtype=np.float64).reshape(-1)
+
+
+# ``fit_report_.extra`` key under which a fit counts the Gram-sized host
+# arrays it makes: beside ``extra["stage"]``, not in it — the benchmark's own
+# test of the Spark-front cell compares that note whole
+HOST_ARRAYS = "host_arrays"
+
+
+def note_host_array(kind: str) -> None:
+    """Count one Gram-sized host array on the report of the fit in flight:
+    ``"numpy"`` — made by NumPy's allocator or the runtime's (a fetch from
+    a chip, a row's Python list read into an array), new pages on a host
+    that hands large blocks back — or ``"pooled"`` — written into memory
+    out of Arrow's pool. Counted where the array is made; outside a fit
+    (a task in another process than its driver) nothing is kept."""
+    from spark_rapids_ml_tpu.obs.report import current_fit
+
+    fit = current_fit()
+    made = fit.extra.get(HOST_ARRAYS, {"numpy": 0, "pooled": 0})
+    fit.note(**{HOST_ARRAYS: {**made, kind: made[kind] + 1}})
+
+
+def pooled_matrix(n: int, dtype=np.float64) -> np.ndarray:
+    """A new n×n array of ``dtype``, uninitialised and writeable, over a
+    buffer of Arrow's memory pool where ``pyarrow`` is there (a plain NumPy
+    array where it is not). The array owns the buffer: the memory goes back
+    to the pool when the last array over it is dropped — a device array the
+    CPU backend made of it included — and the pool, unlike glibc with a
+    block this large, hands a freed block's pages to the next asker while
+    its allocator has not purged them (``PERF.md`` §6 PR 37). Counted by
+    ``note_host_array``."""
+    dtype = np.dtype(dtype)
+    try:
+        import pyarrow as pa
+    except ImportError:
+        note_host_array("numpy")
+        return np.empty((n, n), dtype)
+    note_host_array("pooled")
+    return np.frombuffer(pa.allocate_buffer(n * n * dtype.itemsize),
+                         dtype=dtype).reshape(n, n)
 
 
 def partition_xy_stats(
@@ -797,26 +846,37 @@ def combine_stats(
     (``RapidsRowMatrix.scala:202``), summing n×n partials on the driver in
     float64 — but over ~P rows collected once, not a shuffle. A row is a
     dict or a ``Row``; its ``gram`` and ``col_sum`` may be Arrow-backed
-    (``arrow_stats_rows``: read as views), NumPy arrays or Python lists."""
-    gram = None
-    col_sum = None
+    (``arrow_stats_rows``: read as views), NumPy arrays or Python lists.
+    The sums are the caller's own arrays (a row's may be Arrow's,
+    read-only), the Gram's a ``pooled_matrix`` written once: the first two
+    rows' sum in one pass (a single row is copied), later rows added in
+    the order collected."""
+    first = gram = col_sum = None
     count = 0
     for row in rows:
         get = row.get if isinstance(row, dict) else row.__getitem__
-        g = _float64_values(get("gram"))
+        value = get("gram")
+        if isinstance(value, (list, tuple)):
+            note_host_array("numpy")  # n² Python floats read into an array
         s = _float64_values(get("col_sum"))
         n = s.shape[0]
-        if gram is None:
-            # the sums' own arrays: a row's may be Arrow's, read-only
-            gram = np.array(g.reshape(n, n))
-            col_sum = np.array(s)
-        else:
-            gram += g.reshape(n, n)
+        g = _float64_values(value).reshape(n, n)
+        if gram is not None:
+            gram += g
             col_sum += s
+        elif first is None:
+            first = (g, s)  # summed with the second row, or copied
+        else:
+            gram = np.add(first[0], g, out=pooled_matrix(n))
+            col_sum = first[1] + s
         c = get("count")  # Σw: fractional under weightCol
         count += float(c.as_py() if hasattr(c, "as_py") else c)
-    if gram is None:
+    if first is None:
         raise ValueError("no partition statistics to combine (empty dataset)")
+    if gram is None:
+        gram = pooled_matrix(first[1].shape[0])
+        np.copyto(gram, first[0])
+        col_sum = np.array(first[1])
     return gram, col_sum, count
 
 
@@ -831,10 +891,12 @@ def covariance_from_moments(
     centred once over all partitions: ``(G − N·μμᵀ)/(N − 1)``. Written a
     block of rows at a time, so that the temporaries stay a few MiB beside
     an n×n result (134 MB at n = 4096); the arithmetic of an element is the
-    same whatever the blocks. ``out`` (n×n float64; ``gram`` itself where
-    the caller owns it, as the sum ``combine_stats`` returns) takes the
-    covariance instead of a new array: on a host where every new array of
-    that size pays its first touches, one 134 MB array less is 0.1 s."""
+    same whatever the blocks. ``out`` (n×n) takes the covariance instead of
+    a new array: ``gram`` itself where the caller owns it (the sum
+    ``combine_stats`` returns), or an array of the dtype the solve will put
+    — every element is computed in float64 and rounded once, at the store,
+    to what ``.astype(out.dtype)`` of the float64 covariance reads, so a
+    float32 operand costs no pass of its own."""
     if count < 2 and mean_centering:
         raise ValueError("mean centering requires more than one row")
     denom = max(count - 1, 1)
@@ -847,8 +909,8 @@ def covariance_from_moments(
     for i in range(0, n, step):
         block = np.outer(mean[i:i + step], mean)
         block *= count
-        np.subtract(gram[i:i + step], block, out=cov[i:i + step])
-        cov[i:i + step] /= denom
+        np.subtract(gram[i:i + step], block, out=block)
+        np.divide(block, denom, out=cov[i:i + step])
     return cov, mean
 
 

@@ -24,8 +24,9 @@ Z = [X | y] — ride the ONE streamed loop every ``PCA.fit`` runs
 ``BatchSource`` that reads record batches as views and re-blocks them into
 ``batchRows`` device batches in lent staging buffers, at most two puts are
 in flight, the Gram kernel is ``accumulate_path``'s choice, and the spans
-and counters are the loop's own. The row a task hands back is Arrow over
-the NumPy buffers (``aggregate.stats_record_batch``), not n² Python floats.
+and counters are the loop's own. A task hands the row maker the moments as
+fetched; the row is Arrow (``aggregate.stats_record_batch``: its float64
+form by Arrow's cast, out of Arrow's pool), not n² Python floats.
 The other statistics families below (Newton partials, Lloyd half-steps,
 histograms) still pad their batches to power-of-two row buckets with a
 validity mask, so an arbitrary partition produces a handful of compiled
@@ -41,6 +42,7 @@ from typing import Dict, Iterable, Iterator, Optional
 import numpy as np
 
 from spark_rapids_ml_tpu.spark.aggregate import (
+    note_host_array,
     stats_record_batch,
     vector_column_to_matrix,
 )
@@ -95,15 +97,29 @@ def _from_first_row(chunks: Iterable):
     return None
 
 
+def float64_stats_row(gram, col_sum, count) -> Dict[str, object]:
+    """The stats row as a dict with the moments as float64 NumPy arrays
+    (the documented form of ``partition_gram_stats_device`` and
+    ``partition_xy_stats_device``), of the moments as a task fetched
+    them."""
+    if gram.dtype != np.float64:
+        note_host_array("numpy")  # NumPy's float64 form is a new array
+    return {"gram": np.asarray(gram, dtype=np.float64),
+            "col_sum": np.asarray(col_sum, dtype=np.float64),
+            "count": count}
+
+
 def _device_gram_stats(chunks: Iterable, input_col: Optional[str], device,
-                       dt, batch_rows: int = 0, precision=None, row=dict):
+                       dt, batch_rows: int = 0, precision=None,
+                       row=float64_stats_row):
     """One executor task of the covariance plane: the partition's chunks
     (record batches, whose column ``input_col`` is read by ``data.arrow`` —
     a null or a ragged row raises, nothing is padded or dropped — or plain
     (m, n) arrays) through the one streamed loop in its one-pass form, then
-    the hand-back: the Gram fetched, its float64 form, and the stats row
-    ``row(gram=, col_sum=, count=)`` makes of them. None for a partition
-    without a row."""
+    the hand-back: the moments fetched, and the stats row that
+    ``row(gram=, col_sum=, count=)`` makes of them as they are (the
+    device's dtype; the float64 form is the row maker's). None for a
+    partition without a row."""
     import jax
 
     from spark_rapids_ml_tpu.data.batches import streaming_source
@@ -111,6 +127,7 @@ def _device_gram_stats(chunks: Iterable, input_col: Optional[str], device,
     from spark_rapids_ml_tpu.ops.streaming import (
         SPAN_SYNC_COV,
         IngestTrace,
+        put_copies,
         stream_gram_stats,
     )
     from spark_rapids_ml_tpu.utils.timing import PhaseTimer
@@ -133,10 +150,13 @@ def _device_gram_stats(chunks: Iterable, input_col: Optional[str], device,
                 ingest.all_landed()  # every batch put is in ``stats``
             with timer.phase(PHASE_HANDBACK), TraceRange(
                     SPAN_HANDBACK, TraceColor.CYAN):
-                out = row(
-                    gram=np.asarray(stats.gram, dtype=np.float64),
-                    col_sum=np.asarray(stats.col_sum, dtype=np.float64),
-                    count=int(stats.count))
+                if put_copies(device):
+                    # a chip's memory is its own: the fetch is a new host
+                    # array (the CPU backend hands out a view of its own)
+                    note_host_array("numpy")
+                out = row(gram=np.asarray(stats.gram),
+                          col_sum=np.asarray(stats.col_sum),
+                          count=int(stats.count))
     _report_task(timer, counters)
     return out
 
@@ -196,7 +216,7 @@ def partition_gram_stats_device(
     dtype: str = "auto",
     batch_rows: int = 0,
     precision=None,
-    row=dict,
+    row=float64_stats_row,
 ) -> Iterator[Dict[str, object]]:
     """One partition's (Σxxᵀ, Σx, n), accumulated ON this executor's
     accelerator by the one streamed loop (``_device_gram_stats``).
@@ -238,7 +258,7 @@ def partition_xy_stats_device(
     label_col: str,
     device_id: int = -1,
     dtype: str = "auto",
-    row=dict,
+    row=float64_stats_row,
 ) -> Iterator[Dict[str, object]]:
     """Device counterpart of ``aggregate.partition_xy_stats``: the (n+1)²
     Gram of Z = [X | y] accumulated on this executor's accelerator (the
@@ -504,7 +524,7 @@ def partition_gram_stats_device_arrow(
 ):
     """``mapInArrow`` adapter for the device path — same output schema as
     the host adapter, so driver combine/finalize code is shared; the row is
-    Arrow over the NumPy buffers, made inside the task's hand-back."""
+    Arrow, made inside the task's hand-back of the moments as fetched."""
     yield from partition_gram_stats_device(
         batches, input_col, device_id, batch_rows=batch_rows,
         precision=precision, row=stats_record_batch)

@@ -45,14 +45,16 @@ from spark_rapids_ml_tpu.spark.aggregate import (
     combine_stats,
     covariance_from_moments,
     partition_gram_stats_arrow,
+    pooled_matrix,
     solve_covariance,
     stats_spark_ddl,
 )
 from spark_rapids_ml_tpu.obs import observed_fit, observed_transform
 
 # the driver's half of the stage: reading the collected statistics rows,
-# their float64 sum and the centring (``benchmarks/work/stage.py`` mirrors
-# the names; the executor tasks' are in ``spark/device_aggregate.py``)
+# their float64 sum and the centring into the solve's operand
+# (``benchmarks/work/stage.py`` mirrors the names; the executor tasks' are
+# in ``spark/device_aggregate.py``)
 SPAN_MERGE = "stage:merge"
 PHASE_MERGE = "stage/merge"
 
@@ -322,9 +324,9 @@ class PCA(Estimator, _TpuPCAParams):
                 raise ValueError(
                     f"k = {k} must be at most the number of features "
                     f"{n_features}")
-            # the sum is this fit's own array: centred where it lies
             cov, mean = covariance_from_moments(
-                gram, col_sum, count, self.getMeanCentering(), out=gram)
+                gram, col_sum, count, self.getMeanCentering(),
+                out=self._solve_operand(gram))
         self._note_stage(timer, len(rows), int(count), n_features,
                          collected_as)
         pc, evr, solver_used = solve_covariance(
@@ -341,6 +343,21 @@ class PCA(Estimator, _TpuPCAParams):
         model.fit_timings_ = timer.as_dict()
         model.svd_solver_used_ = solver_used
         return model
+
+    def _solve_operand(self, gram: np.ndarray) -> np.ndarray:
+        """The array the covariance is written into: of the dtype the
+        device solve puts (``models.pca.solve_on_chip``: float32 on a chip),
+        so that the centring's one store is the rounding and the solve
+        finds nothing to cast; for a float64 solve — the host's LAPACK, a
+        device in x64 — the sum itself, this fit's own array, centred where
+        it lies."""
+        if self.getUseXlaSvd():
+            from spark_rapids_ml_tpu.models.pca import _resolve_dtype
+
+            dtype = np.dtype(_resolve_dtype("auto"))
+            if dtype != gram.dtype:
+                return pooled_matrix(gram.shape[0], dtype)
+        return gram
 
     @staticmethod
     def _note_stage(timer, n_rows, count, n_features, collected_as) -> None:
