@@ -3,9 +3,9 @@
 
     python3 scripts/crossing_table.py <trace dir | recorded .json.gz> [--fits 3] [--json out.json]
 
-Reads a profiler trace of back-to-back fits on ONE chip (a ``--trace 1`` run
-of ``benchmarks/run.py`` leaves one under ``.bench_out/trace/<cell>``; the
-two recorded traces under ``benchmarks/testdata`` are in the plain form) and
+Reads a profiler trace of back-to-back fits (a ``--trace 1`` run of
+``benchmarks/run.py`` leaves one under ``.bench_out/trace/<cell>``; the
+recorded traces under ``benchmarks/testdata`` are in the plain form) and
 prints, per fit, seconds from the fit's start:
 
 - ``put``: start of each ``stream:put`` span (the main thread's call);
@@ -13,15 +13,23 @@ prints, per fit, seconds from the fit's start:
   re-tiling; long when several run at once);
 - ``dispatch``: the ``H2D Dispatch`` right behind that ``Linearize`` — the
   batch joins the link's queue;
-- ``landed``: the ``TransferToDevice=>IssueEvent=>Done`` of the batch — the
-  link is FIFO, so the i-th landing is the i-th dispatch's;
+- ``landed``: the end of each of the program's own landing spans
+  (``stream:landing/<device id>``, PR 38: one a put, on its chip's watcher's
+  line), **per chip** under ``landed_by_chip`` with each span's start under
+  ``outstanding_by_chip``; ``landed`` itself is the first chip's, the chip
+  whose steps are tabulated. Where the trace has no such span (a program
+  before PR 38: the two older recorded traces) it falls back to the
+  runtime's ``TransferToDevice=>IssueEvent=>Done`` of the batch — the link
+  is FIFO, so the i-th landing is the i-th dispatch's; those events do not
+  name their chip, so that reading is one chip's only. ``landed_runtime``
+  keeps the runtime's reading beside the program's (one chip);
 - ``step``: device start of each accumulate program (``XLA Modules``), and
   the landing it sits behind.
 
-This is the trace reading ``PERF.md`` §5 tabulates (ISSUE 32, step 0). It
-reads what the TPU runtime names its own host events, which no test of the
-program can hold: when a libtpu renames them the table comes out empty, it
-does not raise.
+This is the trace reading ``PERF.md`` §5 tabulates (ISSUE 32, step 0). But
+for ``landed`` it reads what the TPU runtime names its own host events, which
+no test of the program can hold: when a libtpu renames them those columns
+come out empty, they do not raise.
 """
 
 from __future__ import annotations
@@ -42,6 +50,7 @@ PUT_SPAN = "stream:put"
 RETILE = "Linearize"
 DISPATCH = "H2D Dispatch"
 LANDED = "tpu::System::TransferToDevice=>IssueEvent=>Done"
+LANDING_PREFIX = "stream:landing/"  # + the device id: the program's own
 STEPS = ("update_stats", "update_centered_gram", "update_mean_stats")
 BIG_RETILE_NS = 20e6  # a batch's re-tiling; a scalar's or a mask's is µs
 SAME_LANDING_NS = 2e6  # Done events this close are one landing
@@ -72,6 +81,13 @@ def fit_tables(planes: list) -> list:
         events.sort()
     done = sorted(s for _, (n, s, _) in host if n == LANDED)
     puts = sorted(s for _, (n, s, _) in host if n == PUT_SPAN)
+    landing_spans: dict = {}  # device id -> [(start, end)], in time order
+    for _, (n, s, d) in host:
+        chip = n[len(LANDING_PREFIX):]
+        if n.startswith(LANDING_PREFIX) and chip.isdigit():
+            landing_spans.setdefault(int(chip), []).append((s, s + d))
+    first_chip = (int(chips[0]["name"][len(xplane.DEVICE_PREFIX):])
+                  if chips else None)
     out = []
     for lo, hi in fits:
         batches = []  # (retile start, retile seconds, dispatch start)
@@ -95,6 +111,16 @@ def fit_tables(planes: list) -> list:
                 if landed and t - landed[-1] < SAME_LANDING_NS:
                     continue
                 landed.append(t)
+        runtime = landed
+        # a landing belongs to the fit its span ended in
+        by_chip = {}
+        for chip, spans in sorted(landing_spans.items()):
+            ended_here = sorted((a, b) for a, b in spans if lo <= b < hi)
+            if ended_here:
+                by_chip[chip] = ended_here
+        if by_chip:
+            mine = by_chip.get(first_chip) or next(iter(by_chip.values()))
+            landed = [b for _, b in mine]
         steps = []
         for s, d, name in modules:
             if lo <= s < hi:
@@ -107,6 +133,12 @@ def fit_tables(planes: list) -> list:
             "retile": [b[1] / 1e9 for b in batches],
             "dispatch": [(b[2] - lo) / 1e9 for b in batches],
             "landed": [(t - lo) / 1e9 for t in landed],
+            "landed_from": "program" if by_chip else "runtime",
+            "landed_runtime": [(t - lo) / 1e9 for t in runtime],
+            "landed_by_chip": {chip: [(b - lo) / 1e9 for _, b in spans]
+                               for chip, spans in by_chip.items()},
+            "outstanding_by_chip": {chip: [(a - lo) / 1e9 for a, _ in spans]
+                                    for chip, spans in by_chip.items()},
             "step": steps,
         })
     return out
@@ -116,6 +148,7 @@ def summary(tables: list) -> dict:
     """Medians over the fits: spacing of the landings, and for each step the
     landing it starts behind."""
     gaps = [b - a for t in tables for a, b in zip(t["landed"], t["landed"][1:])]
+    retiles = [r for t in tables for r in t["retile"]]
     n_steps = max((len(t["step"]) for t in tables), default=0)
     behind = []
     for i in range(n_steps):
@@ -124,18 +157,52 @@ def summary(tables: list) -> dict:
         behind.append(statistics.median_low(seen))
     tail = [t["step"][-1]["start"] + t["step"][-1]["seconds"] - t["landed"][-1]
             for t in tables if t["step"] and t["landed"]]
+    # the program's landing against the runtime's, where a fit has both and
+    # as many of one as of the other (one chip): the watcher's wake-up
+    late = [a - b for t in tables if t["landed_from"] == "program"
+            and len(t["landed"]) == len(t["landed_runtime"])
+            for a, b in zip(t["landed"], t["landed_runtime"])]
+    chips = sorted({chip for t in tables for chip in t["landed_by_chip"]})
+    by_chip = {}
+    for chip in chips:
+        mine = [t for t in tables if t["landed_by_chip"].get(chip)]
+        chip_gaps = [b - a for t in mine for a, b in zip(
+            t["landed_by_chip"][chip], t["landed_by_chip"][chip][1:])]
+        # a span starts at max(its put returned, the chip's landing before)
+        spans = [b - a for t in mine for a, b in zip(
+            t["outstanding_by_chip"][chip], t["landed_by_chip"][chip])]
+        by_chip[chip] = {
+            "landings_median": statistics.median(
+                len(t["landed_by_chip"][chip]) for t in mine),
+            "first_landing_median": statistics.median(
+                t["landed_by_chip"][chip][0] for t in mine),
+            "last_landing_median": statistics.median(
+                t["landed_by_chip"][chip][-1] for t in mine),
+            "landing_gap_median": statistics.median(chip_gaps)
+            if chip_gaps else None,
+            "outstanding_seconds_median": statistics.median(
+                sum(b - a for a, b in zip(t["outstanding_by_chip"][chip],
+                                          t["landed_by_chip"][chip]))
+                for t in mine),
+            "span_seconds_median": statistics.median(spans),
+        }
     return {
         "fits": len(tables),
         "wall_median": statistics.median(t["wall"] for t in tables)
         if tables else None,
         "landing_gap_median": statistics.median(gaps) if gaps else None,
         "landing_gap_max": max(gaps) if gaps else None,
-        "retile_median": statistics.median(
-            r for t in tables for r in t["retile"]) if gaps else None,
+        "retile_median": statistics.median(retiles) if retiles else None,
         "step_behind_landing": behind,
         # device work left after the last landing: what no crossing hides
         "exposed_after_last_landing_median":
             statistics.median(tail) if tail else None,
+        "landed_from": sorted({t["landed_from"] for t in tables}),
+        "program_minus_runtime_landing_median":
+            statistics.median(late) if late else None,
+        "program_minus_runtime_landing_max":
+            max(late, key=abs) if late else None,
+        "by_chip": by_chip,
     }
 
 
@@ -159,6 +226,12 @@ def main(argv=None) -> int:
         print(f"fit {i}: wall {t['wall']:.4f}s")
         for key in ("put", "retile", "dispatch", "landed"):
             print(f"  {key:9s}{_fmt(t[key])}")
+        if t["landed_from"] == "program":
+            print(f"  runtime  {_fmt(t['landed_runtime'])}")
+            for chip, landed in t["landed_by_chip"].items():
+                print(f"  chip {chip}   outstanding from "
+                      f"{_fmt(t['outstanding_by_chip'][chip])} landed "
+                      f"{_fmt(landed)}")
         for s in t["step"]:
             print(f"  step     {s['start']:.4f} +{s['seconds']:.4f} "
                   f"{s['program']} behind landing {s['behind_landing']}")

@@ -171,6 +171,7 @@ from spark_rapids_ml_tpu.obs.report import (  # noqa: F401
     fit_instrumentation,
     last_fit_report,
     observed_fit,
+    recent_fit_reports,
 )
 from spark_rapids_ml_tpu.obs.serving import (  # noqa: F401
     NUMERICS_SAMPLE_ENV,
@@ -314,6 +315,7 @@ __all__ = [
     "peak_bytes_in_use",
     "peak_flops_per_second",
     "profiler",
+    "recent_fit_reports",
     "recent_traces",
     "record_event",
     "record_memory_metrics",

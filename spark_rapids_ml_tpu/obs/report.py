@@ -23,13 +23,14 @@ call itself is exception-guarded.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import functools
 import threading
 import time
 from dataclasses import asdict, dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -244,6 +245,12 @@ _current_ctx: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 _last_reports: Dict[Optional[str], FitReport] = {}
+# the one door out for what fits counted: the newest reports of the
+# process, oldest first. Reports only — numbers and short strings, never a
+# model or an array — and bounded: a window of the benchmark's shortest
+# fits holds about fifty.
+RECENT_REPORTS = 256
+_recent_reports: collections.deque = collections.deque(maxlen=RECENT_REPORTS)
 _last_lock = threading.Lock()
 
 
@@ -251,6 +258,19 @@ def current_fit() -> FitContext:
     """The active fit's context, or a no-op context outside any fit."""
     ctx = _current_ctx.get()
     return ctx if ctx is not None else _NULL_CONTEXT
+
+
+def recent_fit_reports(n: Optional[int] = None,
+                       algo: Optional[str] = None) -> List[FitReport]:
+    """The newest ``n`` reports (all the ring holds: at most
+    ``RECENT_REPORTS``), optionally of one algo, oldest first — how a
+    harness or an operator reads what a run of fits counted
+    (``extra["ingest"]``, ``extra["stage"]``, …) without holding on to the
+    models."""
+    with _last_lock:
+        reports = [r for r in _recent_reports
+                   if algo is None or r.algo == algo]
+    return reports if n is None else reports[max(len(reports) - n, 0):]
 
 
 def last_fit_report(algo: Optional[str] = None) -> Optional[FitReport]:
@@ -479,12 +499,48 @@ def _record_metrics(report: FitReport) -> None:
         reg.gauge(
             "sparkml_device_count", "visible devices", ("platform",)
         ).set(report.device_count or 0, platform=report.device_platform)
+    ingest = report.extra.get("ingest")
+    if isinstance(ingest, dict):
+        _record_ingest_metrics(reg, algo, ingest)
+
+
+def _record_ingest_metrics(reg, algo: str, ingest: Dict[str, Any]) -> None:
+    """A streamed fit's ingest counters (``ops.streaming.IngestTrace``)
+    as process counters: what crossed to the chips, what the main thread
+    waited for the put window, how long each chip had a put outstanding,
+    and whether the keep and the staging buffers engaged."""
+    for name, key, text in (
+        ("sparkml_ingest_bytes_put_total", "bytes_put",
+         "bytes handed to device_put by streamed fits"),
+        ("sparkml_ingest_put_wait_seconds_total", "put_wait_seconds",
+         "seconds the main thread waited for a chip's put window"),
+        ("sparkml_ingest_batches_kept_total", "batches_kept",
+         "device batches of pass 1 kept for pass 2"),
+        ("sparkml_ingest_bytes_reblocked_total", "bytes_reblocked",
+         "bytes copied on the host to assemble device batches"),
+    ):
+        reg.counter(name, text, ("algo",)).inc(ingest.get(key, 0), algo=algo)
+    for chip in ingest.get("per_chip", ()):
+        reg.counter(
+            "sparkml_ingest_crossing_seconds_total",
+            "seconds during which a put of the chip was outstanding",
+            ("algo", "chip"),
+        ).inc(chip.get("crossing_seconds", 0.0), algo=algo,
+              chip=str(chip.get("device")))
+    for outcome in ("reused", "fresh"):
+        reg.counter(
+            "sparkml_ingest_staging_total",
+            "copied batches by where their staging buffer came from",
+            ("algo", "outcome"),
+        ).inc(ingest.get("staging_" + outcome, 0), algo=algo,
+              outcome=outcome)
 
 
 def _publish(report: FitReport) -> None:
     with _last_lock:
         _last_reports[report.algo] = report
         _last_reports[None] = report
+        _recent_reports.append(report)
     _record_metrics(report)
     spans.maybe_export_trace(report.trace_id, report.algo)
 

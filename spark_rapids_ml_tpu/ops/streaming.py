@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import queue
 import threading
 import time
 from functools import partial
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_ml_tpu.data.batches import SOURCE_COUNTERS
+from spark_rapids_ml_tpu.obs import spans as obs_spans
 from spark_rapids_ml_tpu.obs.memory import device_memory_stats
 from spark_rapids_ml_tpu.obs.report import current_fit
 from spark_rapids_ml_tpu.obs.xprof import tracked_jit
@@ -278,6 +280,12 @@ SPAN_NEXT_PART = {"read": "stream:next/read", "copy": "stream:next/copy"}
 # all-reduce (the chips' parts handed to the mesh program) and nothing else
 SPAN_COLLECTIVE = {"mean": "stream:collective/mean",
                    "gram": "stream:collective/gram"}
+# not on the main thread: one span a put, ``stream:landing/<device id>``, on
+# the line of the device's landing watcher (``_Watcher``), from the moment
+# the put is the chip's oldest outstanding to its landing. Not in
+# ``STREAM_SPANS`` either: ``benchmarks/work/crossing.py`` lists the name,
+# so the idle readers that keep only the listed spans read what they read.
+SPAN_LANDING = "stream:landing"
 
 PHASE_NEXT = "covariance/next"
 PHASE_NEXT_PART = {"read": "covariance/next/read",
@@ -286,6 +294,15 @@ PHASE_PUT = "covariance/put"
 PHASE_DISPATCH = "covariance/dispatch"
 PHASE_SYNC = "covariance/sync"
 PHASE_COLLECTIVE = "covariance/collective"
+# the seconds during which a put of the fit's fullest chip was outstanding
+# (that chip's ``crossing_seconds``). They OVERLAP the main thread's phases,
+# as ``covariance/put`` nests in ``covariance``: the link works while the
+# main thread reads, copies, dispatches and waits.
+PHASE_CROSSING = "covariance/crossing"
+# a chip's own, in ``per_chip[]``: its puts seen to land, the seconds one
+# was outstanding, and from the fit's first put's start to the chip's last
+# landing (where the chips meet: the collectives wait for the latest)
+LANDING_COUNTERS = ("landings", "crossing_seconds", "last_landing_seconds")
 
 
 def _boundary(span: str) -> str:
@@ -323,6 +340,15 @@ def wait_for_landing(x_dev) -> None:
     """Block until the device batch ``device_put`` returned is on its chip
     (``block_until_ready`` is a fence on the TPU runtime; it returns at once
     on the CPU). Tests patch this function to stand in for the chip."""
+    jax.block_until_ready(x_dev)
+
+
+def landing_of(x_dev) -> None:
+    """``wait_for_landing`` for the landing watchers: the same fence through
+    a seam of its own, so that a test (or a reader of a log) can tell the
+    main thread's waits — the put window's — from a watcher's, which no
+    step of the fit waits for. Tests patch this function to stand in for
+    the chip."""
     jax.block_until_ready(x_dev)
 
 
@@ -397,10 +423,84 @@ class StagingPool:
 STAGING = StagingPool()
 
 
+class _Watcher:
+    """A device's landing watcher, one for the process: a FIFO of the puts
+    to that chip and a daemon thread that takes them oldest first and, for
+    each, opens the span ``stream:landing/<device id>``, blocks until the
+    device batch is on the chip (``landing_of``) and closes the span. A span
+    therefore runs from max(the put's ``device_put`` returned, the chip's
+    previous landing) — the thread's wake-up later: 0.2 ms on the v5e's
+    host — to the landing, and the union of a chip's spans is the time
+    during which a put of that chip was outstanding: the runtime's
+    re-tiling of the batch, its wait in the link's queue and its crossing.
+    The span is a ``TraceRange`` on the watcher's own host line (the device
+    trace's clock, like every span of the fit); the same seconds go into
+    the ``obs.spans`` ring under the fit's trace id — with the chip, the
+    put's index in the fit and its bytes as args — and into the counters
+    the put came with (its chip's ``LANDING_COUNTERS``).
+
+    The watcher adds no wait to a fit and keeps no batch past its landing:
+    the main thread hands a put over and goes on, and the thread drops its
+    reference the moment ``landing_of`` returns. A fit that dies leaves its
+    puts to land into counters nobody reads. Kept for the process, not made
+    per fit: a hand-fed stream that is dropped unfinished would leave a
+    thread of its own waiting for ever, and this one costs a fit no thread
+    start. ``fence`` is how a fit knows the watcher is done with it."""
+
+    def __init__(self, device_id: int):
+        self.span = f"{SPAN_LANDING}/{device_id}"
+        self.device_id = device_id
+        self.fifo: queue.SimpleQueue = queue.SimpleQueue()
+        threading.Thread(target=self._run, name=self.span,
+                         daemon=True).start()
+
+    def fence(self) -> threading.Event:
+        """Set once every put handed over before it has been seen to land
+        and is counted."""
+        seen = threading.Event()
+        self.fifo.put(seen)
+        return seen
+
+    def _run(self) -> None:
+        while True:
+            put = self.fifo.get()
+            if isinstance(put, threading.Event):
+                put.set()
+                continue
+            x_dev, nbytes, index, since, trace_id, counters = put
+            del put
+            with TraceRange(self.span, TraceColor.ORANGE,
+                            record=False) as span:
+                try:
+                    landing_of(x_dev)
+                except Exception:  # noqa: BLE001 - the fit's own wait raises
+                    pass
+            del x_dev  # the device batch: not kept past its landing
+            now = time.perf_counter()
+            obs_spans.record_event(
+                self.span, now - span.elapsed, now, trace_id=trace_id,
+                color=TraceColor.ORANGE.name, chip=self.device_id, put=index,
+                bytes=nbytes)
+            counters["landings"] += 1
+            counters["crossing_seconds"] += span.elapsed
+            counters["last_landing_seconds"] = now - since
+
+
+_WATCHERS: dict = {}  # device id → its ``_Watcher``, made at its first put
+_WATCHERS_LOCK = threading.Lock()
+
+
+def watcher_of(device) -> _Watcher:
+    with _WATCHERS_LOCK:
+        if device.id not in _WATCHERS:
+            _WATCHERS[device.id] = _Watcher(device.id)
+        return _WATCHERS[device.id]
+
+
 class _Chip:
     """One chip's share of a streamed fit: where its batches go, what of the
-    keep budget is left there, the puts it has not seen land, and its own
-    counters."""
+    keep budget is left there, the puts it has not seen land, its device's
+    landing watcher (from its first put on) and its own counters."""
 
     def __init__(self, device):
         self.device = device  # None = JAX's default device, uncommitted
@@ -414,7 +514,10 @@ class _Chip:
             "bytes_put": 0, "batches_kept": 0, "bytes_kept": 0,
             "keep_budget_bytes": 0, "hbm_bytes_in_use": {},
             "puts_in_flight_max": 0, "put_waits": 0, "put_wait_seconds": 0.0,
+            "landings": 0, "crossing_seconds": 0.0,
+            "last_landing_seconds": 0.0,
         }
+        self.watcher: Optional[_Watcher] = None
 
     def stats_device(self):
         return self.device or jax.local_devices()[0]
@@ -437,12 +540,17 @@ class IngestTrace:
     ``_await_window``'s wait, or ``all_landed`` for the window's last —
     never sooner and never on a guess; a fit that dies drops what it has
     not seen land. With a copy of batch *i* + 2 assembled while puts *i*
-    and *i* + 1 are in flight, a chip cycles ``PUTS_IN_FLIGHT`` + 1."""
+    and *i* + 1 are in flight, a chip cycles ``PUTS_IN_FLIGHT`` + 1.
+    Every put is also handed to its device's landing watcher (``_Watcher``),
+    which sees it land from a thread of its own — the span
+    ``stream:landing/<device id>`` and the chip's ``LANDING_COUNTERS`` — and
+    takes no part in any of the above: the main thread waits where it
+    waited."""
 
     def __init__(self, timer: Optional[PhaseTimer] = None, device=None):
         self.timer = timer if timer is not None else PhaseTimer()
-        for phase in PHASE_NEXT_PART.values():
-            self.timer.add(phase, 0.0)  # there, at 0, in a fit with neither
+        for phase in (*PHASE_NEXT_PART.values(), PHASE_CROSSING):
+            self.timer.add(phase, 0.0)  # there, at 0, in a fit with none
         devices = device if isinstance(device, (list, tuple)) else (device,)
         self.chips = [_Chip(d) for d in devices]
         self.turn = 0  # batches dealt in this pass: the next goes to
@@ -457,6 +565,7 @@ class IngestTrace:
         self._assembling: Optional[np.ndarray] = None
         self._unlanded: list = []
         self._staging_most = (PUTS_IN_FLIGHT + 1) * len(self.chips)
+        self._first_put: Optional[float] = None  # when it started
         self.counters = {
             "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
             # counted by the source as it is walked (``staging_*``: by
@@ -466,6 +575,9 @@ class IngestTrace:
             "accumulate_calls": {"mean": 0, "pallas": 0, "xla": 0},
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
             "puts_in_flight_max": 0, "put_waits": 0, "put_wait_seconds": 0.0,
+            # the fullest chip's, at ``all_landed`` (``LANDING_COUNTERS``
+            # are each chip's own, in ``per_chip``)
+            "crossing_seconds": 0.0,
             "hbm_bytes_in_use": {},
             "chips": len(self.chips), "collective_bytes": {},
             "per_chip": [chip.counters for chip in self.chips],
@@ -574,11 +686,18 @@ class IngestTrace:
         c = self.turn % len(self.chips)
         chip = self.chips[c]
         self.turn += 1
+        if self._first_put is None:
+            self._first_put = time.perf_counter()
         with self.stage(SPAN_PUT, PHASE_PUT, "put_seconds_max"):
             self._await_window(chip)
             x = np.asarray(batch, dtype=dtype)
             x_dev = jax.device_put(x, chip.device)
             m_dev = None if mask is None else jax.device_put(mask, chip.device)
+        if chip.watcher is None:
+            chip.watcher = watcher_of(chip.stats_device())
+        chip.watcher.fifo.put((x_dev, x.nbytes, self.counters["batches"],
+                               self._first_put, obs_spans.current_trace_id(),
+                               chip.counters))
         staged, self._assembling = self._assembling, None
         if staged is not x:
             # not the batch lent for (passed over), or a cast made ``x``
@@ -649,7 +768,8 @@ class IngestTrace:
         chip's window): none outlives the walks over it. The staging
         buffers of the window's puts stay out of the pool until
         ``all_landed``; a fit that dies never says so, and they go with
-        it."""
+        it. A landing watcher lets go of a batch at its landing, not
+        here."""
         self.kept.clear()
         self._free_unput()
         for chip in self.chips:
@@ -660,10 +780,21 @@ class IngestTrace:
     def all_landed(self) -> None:
         """The host has read a value that every put of the fit fed (the
         covariance, the solve's result): the buffers ``release`` held back
-        are free."""
+        are free, and the landing watchers have seen the fit's last
+        landing — from here on none works for this fit, and its landing
+        counters and ``covariance/crossing`` are final."""
         for staged in self._unlanded:
             self._take_back(staged)
         self._unlanded = []
+        for seen in [chip.watcher.fence() for chip in self.chips
+                     if chip.watcher is not None]:
+            seen.wait()
+        fullest = max(chip.counters["crossing_seconds"]
+                      for chip in self.chips)
+        # (a hand-fed stream that goes on and says so again adds the rest)
+        self.timer.add(PHASE_CROSSING,
+                       fullest - self.counters["crossing_seconds"])
+        self.counters["crossing_seconds"] = fullest
 
     def accumulate(self, path: str):
         self.counters["accumulate_calls"][path] += 1

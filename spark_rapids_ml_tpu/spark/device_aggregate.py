@@ -183,9 +183,10 @@ def take_task_reports() -> list:
 
 def _one_counter(a, b, key: str):
     """A counter of ``extra["ingest"]`` over two tasks: counts and seconds
-    added; ``*_max`` / ``*_min``, ``chips``, the (unused) keep budget and
-    the boundaries' ``hbm_bytes_in_use`` by their extreme; dicts key by key
-    and ``per_chip`` chip by chip."""
+    added; ``*_max`` / ``*_min``, ``chips``, the (unused) keep budget, the
+    boundaries' ``hbm_bytes_in_use`` and a chip's ``last_landing_seconds``
+    (each task's from its own first put: the longest task's) by their
+    extreme; dicts key by key and ``per_chip`` chip by chip."""
     if a is None or b is None:
         return b if a is None else a
     if isinstance(b, dict):
@@ -199,7 +200,8 @@ def _one_counter(a, b, key: str):
     if key.endswith("_min"):
         return min(a, b)
     if key.endswith("_max") or key in ("chips", "keep_budget_bytes",
-                                       "hbm_bytes_in_use"):
+                                       "hbm_bytes_in_use",
+                                       "last_landing_seconds"):
         return max(a, b)
     return a + b
 

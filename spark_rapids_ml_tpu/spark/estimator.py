@@ -57,6 +57,13 @@ from spark_rapids_ml_tpu.obs import observed_fit, observed_transform
 # in ``spark/device_aggregate.py``)
 SPAN_MERGE = "stage:merge"
 PHASE_MERGE = "stage/merge"
+# the Spark action: ``_collect_stats`` runs the mapped frame — the executor
+# tasks, lazily — and collects their rows. ``stage:task`` nests in it where
+# the tasks run in this process, so its self time (``stage/action`` less
+# ``stage/task``) is Spark's share: scheduling, the rows' way to the driver.
+# Listed by ``benchmarks/work/crossing.py``, beside the landing spans.
+SPAN_ACTION = "stage:action"
+PHASE_ACTION = "stage/action"
 
 
 def _collect_stats(mapped):
@@ -315,7 +322,9 @@ class PCA(Estimator, _TpuPCAParams):
                 lambda b_: partition_gram_stats_arrow(b_, input_col),
             )
             mapped = df.mapInArrow(stats, stats_spark_ddl())
-        rows, collected_as = _collect_stats(mapped)
+        with timer.phase(PHASE_ACTION), TraceRange(SPAN_ACTION,
+                                                   TraceColor.YELLOW):
+            rows, collected_as = _collect_stats(mapped)
         with timer.phase(PHASE_MERGE), TraceRange(SPAN_MERGE,
                                                   TraceColor.PURPLE):
             gram, col_sum, count = combine_stats(rows)

@@ -779,6 +779,34 @@ def test_the_stage_leaves_its_spans_and_keys(monkeypatch, parts):
         timings[front.PHASE_MERGE])
 
 
+@pytest.mark.parametrize("parts", sorted(PARTITIONS))
+def test_the_action_span_holds_the_tasks(monkeypatch, parts):
+    """``stage:action`` wraps the call that runs the tasks lazily and
+    collects their rows: every ``stage:task`` lies inside it, the merge
+    after it, and its self time is what the tasks leave of it."""
+    fit = _stage(monkeypatch).fit(iter(_chunks(PARTITIONS[parts])))
+    events = obs_spans.get_recorder().events(fit.fit_report_.trace_id)
+    (action,) = [e for e in events if e.name == front.SPAN_ACTION]
+    tasks = [e for e in events if e.name == device_aggregate.SPAN_TASK]
+    (merge,) = [e for e in events if e.name == front.SPAN_MERGE]
+    assert len(tasks) == parts
+    for task in tasks:
+        assert action.ts_us <= task.ts_us
+        assert task.ts_us + task.dur_us <= action.ts_us + action.dur_us
+    assert merge.ts_us >= action.ts_us + action.dur_us
+    timings = fit.fit_timings_
+    assert timings[front.PHASE_ACTION] >= timings[device_aggregate.PHASE_TASK]
+    # the stand-in's IPC round trips happen inside the action, beside the
+    # tasks: Spark's share, seen from the program's own span
+    assert timings[front.PHASE_ACTION] - timings[
+        device_aggregate.PHASE_TASK] >= 0.9 * timings[
+            spark_stage.COLLECT_PHASE]
+    assert fit.fit_report_.phases[front.PHASE_ACTION] == pytest.approx(
+        timings[front.PHASE_ACTION])
+    # tasks in other processes: the action's span and key are still there
+    assert front.SPAN_ACTION not in streaming.STREAM_SPANS
+
+
 def test_the_stages_names_are_the_benchmarks():
     path = os.path.join(ROOT, "benchmarks", "work", "stage.py")
     spec = importlib.util.spec_from_file_location("stage_names", path)

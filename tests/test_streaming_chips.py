@@ -255,10 +255,13 @@ def _fit(chips: int, form: str, chunks: list):
 
 
 def _span_names(model) -> list:
+    """The main thread's spans in order (the landing watchers' are on lines
+    of their own: ``tests/test_streaming_landing.py``)."""
     events = sorted(
         obs_spans.get_recorder().events(model.fit_report_.trace_id),
         key=lambda e: (e.ts_us, -e.dur_us))
-    return [e.name for e in events]
+    return [e.name for e in events
+            if not e.name.startswith(streaming.SPAN_LANDING)]
 
 
 @pytest.mark.parametrize("chips", [2, 4])

@@ -256,8 +256,13 @@ def test_fit_reports_the_counters_and_emits_no_new_span(monkeypatch, n, rows):
                     key=lambda e: (e.ts_us, -e.dur_us))
     # pass 2 has no host stage left: its steps only (the source's own stages
     # inside ``stream:next`` are tests/test_arrow_ingest.py's)
+    # nor are the landing watchers', on lines of their own: one a put
+    # (``tests/test_streaming_landing.py``)
     inside_next = streaming.SPAN_NEXT_PART.values()
-    assert [e.name for e in events if e.name not in inside_next] == (
+    landed = [e for e in events if e.name.startswith(streaming.SPAN_LANDING)]
+    assert len(landed) == per_pass
+    assert [e.name for e in events
+            if e.name not in inside_next and e not in landed] == (
         [pca_module.SPAN_FIT, pca_module.SPAN_STREAMED_COV,
          streaming.SPAN_PASS_MEAN]
         + [streaming.SPAN_NEXT, streaming.SPAN_PUT,

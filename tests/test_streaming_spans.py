@@ -95,8 +95,11 @@ def fitted(request):
     model = PCA().setK(K).set("batchRows", BATCH).set("dtype", "float32").fit(
         _dataset(input_form, chunks))
     report = model.fit_report_
-    events = sorted(obs_spans.get_recorder().events(report.trace_id),
-                    key=lambda e: (e.ts_us, -e.dur_us))
+    # the main thread's spans: the landing watchers' run beside them on
+    # lines of their own (``tests/test_streaming_landing.py`` holds those)
+    events = sorted((e for e in obs_spans.get_recorder().events(
+        report.trace_id) if not e.name.startswith(streaming.SPAN_LANDING)),
+        key=lambda e: (e.ts_us, -e.dur_us))
     two_pass = input_form == "callable"
     return {"form": request.param, "input_form": input_form, "rows": rows,
             "chunks": chunks, "model": model, "report": report,
@@ -313,6 +316,28 @@ def test_the_benchmarks_span_list_is_what_the_program_emits():
         bench_spans.BENCH_SPAN, pca_module.SPAN_FIT,
         pca_module.SPAN_STREAMED_COV}
     assert pca_module.SPAN_FIT == f"fit:{PCA.fit.__obs_instrumented__}"
+
+
+def test_the_crossings_names_are_listed_apart_by_the_benchmark():
+    """The landing spans, ``covariance/crossing`` and the Spark front's
+    ``stage:action`` are in neither ``STREAM_SPANS`` nor the accepted
+    readers' ``PROGRAM_SPANS`` (so those read what they read);
+    ``benchmarks/work/crossing.py`` lists them, and a rename fails here."""
+    from spark_rapids_ml_tpu.spark import estimator as front
+
+    crossing = _bench_module("work/crossing.py")
+    bench_spans = _bench_module("work/spans.py")
+    assert crossing.LANDING_PREFIX == streaming.SPAN_LANDING + "/"
+    assert crossing.CROSSING_PHASE == streaming.PHASE_CROSSING
+    assert crossing.ACTION_SPAN == front.SPAN_ACTION
+    assert crossing.ACTION_PHASE == front.PHASE_ACTION
+    stage = _bench_module("work/stage.py")
+    for listed in (streaming.STREAM_SPANS, bench_spans.PROGRAM_SPANS,
+                   tuple(stage.SPANS.values())):
+        assert not any(name.startswith(streaming.SPAN_LANDING)
+                       for name in listed)
+        assert front.SPAN_ACTION not in listed
+    assert set(crossing.LANDING_COUNTERS) == set(streaming.LANDING_COUNTERS)
 
 
 def test_every_span_constant_was_seen_in_some_fit():
