@@ -23,8 +23,9 @@ prints, per fit, seconds from the fit's start:
   is FIFO, so the i-th landing is the i-th dispatch's; those events do not
   name their chip, so that reading is one chip's only. ``landed_runtime``
   keeps the runtime's reading beside the program's (one chip);
-- ``step``: device start of each accumulate program (``XLA Modules``), and
-  the landing it sits behind.
+- ``step``: device start of each accumulate program (``XLA Modules``; and of
+  ``recentre_gram``, which ends a two-pass fit's covariance phase), and the
+  landing it sits behind.
 
 This is the trace reading ``PERF.md`` §5 tabulates (ISSUE 32, step 0). But
 for ``landed`` it reads what the TPU runtime names its own host events, which
@@ -51,7 +52,10 @@ RETILE = "Linearize"
 DISPATCH = "H2D Dispatch"
 LANDED = "tpu::System::TransferToDevice=>IssueEvent=>Done"
 LANDING_PREFIX = "stream:landing/"  # + the device id: the program's own
-STEPS = ("update_stats", "update_centered_gram", "update_mean_stats")
+# the accumulate programs and, since PR 39, the two-pass fit's re-centring
+# of its shifted Gram: the last device work of its covariance phase
+STEPS = ("update_stats", "update_centered_gram", "update_mean_stats",
+         "recentre_gram")
 BIG_RETILE_NS = 20e6  # a batch's re-tiling; a scalar's or a mask's is µs
 SAME_LANDING_NS = 2e6  # Done events this close are one landing
 

@@ -192,6 +192,16 @@ class StreamingPCA:
 # ``PUTS_IN_FLIGHT`` batches in flight, whatever the stream's length), so
 # pass 1 keeps its device batches for pass 2 while ``keep_budget_bytes`` has
 # room: rows that fit on the chip cross once.
+#
+# Pass 2 is the fallback, not the rule. For any fixed c,
+# Σ(x−μ)(x−μ)ᵀ = Σ(x−c)(x−c)ᵀ − n·(μ−c)(μ−c)ᵀ exactly, so pass 1 also runs
+# the centred-Gram step of each batch as it lands, about c = the mean of the
+# chip's FIRST batch, and ``recentre_gram`` moves the sum to μ once μ is
+# known: the Gram steps run under the crossing instead of behind it, with
+# the programs pass 2 runs. The shift costs float32 digits only where c is
+# far from μ against the columns' spread; ``recentre_gram`` measures that
+# on the rows themselves (ρ) and the fit runs pass 2 over the kept batches,
+# as it always did, when ρ passes ``SHIFT_RATIO_MAX``.
 
 class MeanStats(NamedTuple):
     col_sum: jnp.ndarray
@@ -244,6 +254,49 @@ def update_centered_gram_auto(gram_acc, batch, mean, mask=None,
                                                    precision=precision)
     return update_centered_gram(gram_acc, batch, mean, mask,
                                 precision=precision)
+
+
+# The largest ρ a shifted Gram is accepted with, ρ = max_j of
+#
+#     n·|d_j|·(|d_j| + |μ_j| / 32) / G_jj,    d = μ − c,  G = Σ(x−c)(x−c)ᵀ.
+#
+# Its first term is the share of a column's shifted sum of squares that the
+# re-centring takes away again: the centred diagonal is G_jj·(1 − that), so
+# the shifted sum's own float32 rounding weighs 1/(1 − ρ) in the result — at
+# ρ ≤ 1/64 at most 1.6 % more than pass 2's rounding of the same sum, nothing
+# beside the margin the benchmark's ``ritz_gap`` keeps (sound 4.5e-6 on a
+# limit of 2e-5). Its second term is the float32 mean's own rounding, which
+# pass 2 feels at second order and the correction n·d dᵀ at first: μ is off
+# by a few ulp of |μ_j| (κ ≈ 6 by the benchmark's ``mean_gap``), which moves
+# the centred diagonal by 2κε·n|d_j μ_j| — with the weight 1/32 at most
+# κε ≈ 4e-7 of G_jj at ρ = 1/64, a tenth of the Gram's own rounding.
+# Rows in any order that is not sorted by value read d_j² ≈ σ_j²/batchRows
+# (i.i.d.: (1 − batchRows/n)·χ²₁ a column), so ρ ≈ 1/batchRows unless |μ| ≫ σ
+# by more than about √batchRows / 8; a frame sorted by a feature, a drifting
+# stream or a small batch of N(100, 1) reads more and gets pass 2, the case
+# it exists for. Not a Param: nothing a caller knows says more than the
+# rows do.
+SHIFT_RATIO_MAX = 1.0 / 64
+_MEAN_LEAK_WEIGHT = 1.0 / 32
+
+
+@partial(tracked_jit, donate_argnums=(0,))
+def recentre_gram(gram_acc, col_sum, count, shift, mean):
+    """(Σ(x−μ)(x−μ)ᵀ, ρ) of one chip's rows from their shifted Gram
+    Σ(x−c)(x−c)ᵀ (donated: re-centred in place), their (Σx, n), the shift c
+    and the mean μ the Gram is wanted about — the chip's own rows' on one
+    chip, all the chips' rows' on several: G − s dᵀ − d sᵀ + n d dᵀ with
+    s = Σ(x−c) = Σx − n c and d = μ − c (s = n d on one chip). ρ is what
+    ``SHIFT_RATIO_MAX`` bounds; 0/0, a column constant at c, reads 0, and a
+    NaN stays one (``ρ ≤ SHIFT_RATIO_MAX`` is then false)."""
+    n = count.astype(gram_acc.dtype)
+    d = mean - shift
+    s = col_sum - n * shift
+    moved = n * jnp.abs(d) * (jnp.abs(d) + _MEAN_LEAK_WEIGHT * jnp.abs(mean))
+    ratio = jnp.max(jnp.where(moved == 0, 0, moved / jnp.diagonal(gram_acc)))
+    centred = (gram_acc - jnp.outer(s, d) - jnp.outer(d, s)
+               + n * jnp.outer(d, d))
+    return centred, ratio
 
 
 # -- spans, sub-phases and counters of the streamed fit ---------------------
@@ -800,6 +853,19 @@ class IngestTrace:
         self.counters["accumulate_calls"][path] += 1
         return self.stage(SPAN_ACCUMULATE[path], PHASE_DISPATCH)
 
+    def shift_verdict(self, ratios: list) -> bool:
+        """Whether a two-pass fit's shifted Grams stand, from each chip's ρ
+        (``recentre_gram``; the fit's is the largest): noted as
+        ``gram_shift`` {``accepted``, ``ratio``}, per chip too."""
+        for chip, ratio in zip(self.chips, ratios):
+            chip.counters["gram_shift"] = {
+                "accepted": ratio <= SHIFT_RATIO_MAX, "ratio": ratio}
+        accepted = all(chip.counters["gram_shift"]["accepted"]
+                       for chip in self.chips)
+        self.counters["gram_shift"] = {"accepted": accepted,
+                                       "ratio": max(ratios)}
+        return accepted
+
     def collective(self, kind: str, nbytes: int):
         """The dispatch of one all-reduce whose operand is ``nbytes`` a
         chip."""
@@ -914,13 +980,25 @@ def stream_covariance(
     """Stream a ``data.batches.BatchSource`` into (covariance, mean, count).
 
     Two-pass (center → Gram) when the source is re-iterable and centering is
-    requested; one-pass sufficient statistics otherwise. Two passes over the
-    rows are not two crossings: pass 1 keeps its device batches while
+    requested; one-pass sufficient statistics otherwise. The two-pass fit
+    does not wait for the mean to start on the Gram: pass 1 runs, behind each
+    batch's mean step, the centred-Gram step pass 2 would run, about the mean
+    of the chip's first batch, and ``recentre_gram`` moves the sum to the
+    mean of all rows after the last batch — one rank-one correction, exact in
+    the algebra, so the Gram steps run while the rows still cross. The same
+    program reads from the rows how much of the shifted sum the correction
+    took away (ρ; ≈ 1/batchRows for rows in any order that is not sorted by
+    value), and the host's one blocking read (``stream:sync/count``) fetches
+    it with the count: at ρ ≤ ``SHIFT_RATIO_MAX`` the fit is done, in one
+    walk of the rows (``passes`` 1, ``gram_shift`` {accepted, ratio} in the
+    counters). Otherwise — a frame sorted by a feature, a drifting stream —
+    the shifted Gram is dropped and pass 2 runs as it always has, about the
+    mean of all rows: pass 1 keeps its device batches while
     ``keep_budget_bytes`` has room and pass 2 runs on those, so only the rows
     past the budget are walked and put a second time (all of them where the
-    backend reports no memory, as on the CPU). The arithmetic is the same
-    either way. Returns device arrays; covariance is normalized by n−1 as
-    everywhere in this package.
+    backend reports no memory, as on the CPU), and a factory that hands back
+    a stale iterator there still raises. Returns device arrays; covariance is
+    normalized by n−1 as everywhere in this package.
     A chip has at most two puts in flight (``PUTS_IN_FLIGHT``; the third
     waits inside ``IngestTrace.put`` for the first to land): the v5e traces
     show a landed batch's step starting only after every transfer queued
@@ -933,12 +1011,14 @@ def stream_covariance(
     keeps up to (``PUTS_IN_FLIGHT`` + 1) × chips such buffers between fits.
     ``device`` is one chip or a sequence of them. Over several, the host
     batches are dealt to the chips whole and in turn; each chip sums its own
-    with the programs the one-chip fit runs, keeps its own batches under its
-    own budget, and the chips meet in two all-reduces: after pass 1 the
-    column sums and counts (every chip gets the mean of all rows), after
-    pass 2 the Grams (one covariance, on the first chip, where the results
-    are returned). A one-pass fit has the second only. One chip runs no mesh
-    program at all.
+    with the programs the one-chip fit runs (about its own first batch's
+    mean), keeps its own batches under its own budget, and the chips meet in
+    two all-reduces: after pass 1 the column sums and counts (every chip gets
+    the mean of all rows and re-centres its own Gram about it), then the
+    Grams (one covariance, on the first chip, where the results are
+    returned; ρ is the chips' largest, and a refused shift sums pass 2's
+    Grams in a third). A one-pass fit has the second only. One chip runs no
+    mesh program at all.
     ``ingest`` (an ``IngestTrace`` on the fit's ``PhaseTimer``; it then names
     the chips) records the stages; without one they are traced and counted
     all the same. Each Gram step asks ``accumulate_path`` for its span's
@@ -956,43 +1036,73 @@ def stream_covariance(
                   for d in devices]
         itemsize = jnp.dtype(dtype).itemsize
         ingest.allow_keep(source.batch_rows * n * itemsize, n * n * itemsize)
+
+        def new_grams():
+            return [jnp.zeros((n, n), dtype=dtype, device=d) for d in devices]
+
+        def meet(grams):
+            """The chips' Grams as one, on the first chip."""
+            if several:
+                return collective_sum(ingest, [(g,) for g in grams])[0]
+            return grams[0]
+
+        grams = new_grams()
+        shifts = [None] * len(devices)  # a chip's c: its first batch's mean
         try:
             with ingest.walk(SPAN_PASS_MEAN):
                 for batch, mask in ingest.batches(source):
                     c, x_dev, m_dev = ingest.put(batch, mask, dtype)
                     with ingest.accumulate("mean"):
                         mstats[c] = update_mean_stats(mstats[c], x_dev, m_dev)
-                    ingest.keep(c, x_dev, m_dev)
+                    if shifts[c] is None:
+                        shifts[c] = mstats[c].col_sum / mstats[c].count
+                    with ingest.accumulate(
+                            accumulate_path(grams[c], x_dev, m_dev)):
+                        grams[c] = update_centered_gram_auto(
+                            grams[c], x_dev, shifts[c], m_dev,
+                            precision=precision)
+                    ingest.keep(c, x_dev, m_dev)  # for the fallback
             if several:
                 means, count = collective_mean(ingest, mstats)
             else:
                 count = mstats[0].count
                 means = [mstats[0].col_sum / count]
-            grams = [jnp.zeros((n, n), dtype=dtype, device=d)
-                     for d in devices]
-            with ingest.walk(SPAN_PASS_GRAM):
-                for c, x_dev, m_dev in ingest.replay(source, dtype):
-                    with ingest.accumulate(
-                            accumulate_path(grams[c], x_dev, m_dev)):
-                        grams[c] = update_centered_gram_auto(
-                            grams[c], x_dev, means[c], m_dev,
-                            precision=precision)
+            ratios = []
+            for c, stats in enumerate(mstats):
+                if shifts[c] is None:  # a chip no batch came to: G = 0
+                    shifts[c] = jnp.zeros_like(stats.col_sum)
+                grams[c], ratio = recentre_gram(
+                    grams[c], stats.col_sum, stats.count, shifts[c], means[c])
+                ratios.append(ratio)
+            gram_acc = meet(grams)
+            with ingest.sync(SPAN_SYNC_COUNT):
+                pass1_rows, *ratios = (
+                    v.item() for v in jax.device_get([count, *ratios]))
+            if not ingest.shift_verdict(ratios):
+                # the rows say the shift was poor: pass 2 over the kept
+                # batches (and the rest of the source, put again), as if
+                # pass 1 had summed nothing but the mean
+                del gram_acc
+                grams = new_grams()
+                with ingest.walk(SPAN_PASS_GRAM):
+                    for c, x_dev, m_dev in ingest.replay(source, dtype):
+                        with ingest.accumulate(
+                                accumulate_path(grams[c], x_dev, m_dev)):
+                            grams[c] = update_centered_gram_auto(
+                                grams[c], x_dev, means[c], m_dev,
+                                precision=precision)
+                gram_acc = meet(grams)
+                if ingest.pass_rows != pass1_rows:
+                    # A "re-iterable" factory that hands back a
+                    # partially-consumed iterator would silently zero the
+                    # Gram; fail instead.
+                    raise RuntimeError(
+                        f"two-pass streaming saw {pass1_rows} rows on pass "
+                        f"1 but {ingest.pass_rows} on pass 2; the source "
+                        f"factory must return a FRESH iterator on every call"
+                    )
         finally:
             ingest.release()  # no device batch outlives the walks over it
-        if several:
-            (gram_acc,) = collective_sum(ingest, [(g,) for g in grams])
-        else:
-            (gram_acc,) = grams
-        with ingest.sync(SPAN_SYNC_COUNT):
-            pass1_rows = int(count)
-        if ingest.pass_rows != pass1_rows:
-            # A "re-iterable" factory that hands back a partially-consumed
-            # iterator would silently zero the Gram; fail instead.
-            raise RuntimeError(
-                f"two-pass streaming saw {pass1_rows} rows on pass 1 but "
-                f"{ingest.pass_rows} on pass 2; the source factory must "
-                f"return a FRESH iterator on every call"
-            )
         ingest.set_data(n)
         denom = jnp.maximum(count - 1, 1)
         return gram_acc / denom, means[0], count

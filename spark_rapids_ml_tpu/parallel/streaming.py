@@ -8,7 +8,9 @@ are dealt to them whole and in turn, each chip folds its own into its own
 accumulator with the one-chip programs (NO collective per batch; the
 reference's analogue shipped one n×n partial per partition to the driver,
 ``RapidsRowMatrix.scala:168-202``), and the chips meet in all-reduces over
-ICI only — the mean after pass 1, the Grams after pass 2. The eigensolve
+ICI only — the mean after pass 1, then the Grams (summed in pass 1 about
+each chip's first batch's mean and re-centred; pass 2's where the rows
+refuse that). The eigensolve
 then runs once, on one chip, outside any mesh program.
 
 ``distributed_streaming_pca_fit`` is that loop under a mesh-shaped
@@ -114,8 +116,10 @@ def distributed_streaming_pca_fit(
     solver: str = "eigh",
 ) -> PCAFitResult:
     """Out-of-core fit of a ``data.batches.BatchSource`` over a mesh's
-    chips through ``stream_covariance``: two passes and two all-reduces for
-    a re-iterable source that is centred, one of each otherwise. The solve
+    chips through ``stream_covariance``: for a re-iterable source that is
+    centred two all-reduces and one walk of the rows (a second walk and a
+    third all-reduce where the rows refuse the shifted Gram), one of each
+    otherwise. The solve
     (``solver``, through the residual gate as in ``PCA.fit``) is one
     program on the first chip.
     """

@@ -78,9 +78,14 @@ def test_every_precision_fits_and_matches_oracle_on_cpu(rng, precision):
                                atol=1e-5)
 
 
-def test_precision_reaches_every_accumulate_step(rng, monkeypatch):
+@pytest.mark.parametrize("shift", ["refused", "accepted"])
+def test_precision_reaches_every_accumulate_step(rng, monkeypatch, shift):
     """The Param is the static ``precision`` of each Gram step of the
-    fit's stream, whole batches and the masked tail alike."""
+    fit's stream, whole batches and the masked tail alike: the steps of
+    pass 1 (about the first batch's mean) and, where the rows refuse that
+    shift (sorted by a feature here; mirrored pairs accept it), pass 2's."""
+    from shift_rows import mirrored_pairs, verdict
+
     from spark_rapids_ml_tpu.ops import streaming
 
     seen = []
@@ -92,10 +97,15 @@ def test_precision_reaches_every_accumulate_step(rng, monkeypatch):
 
     monkeypatch.setattr(streaming, "update_centered_gram_auto", recording)
     x = rng.normal(size=(1000, 32))
+    x = (x[np.argsort(x[:, 0])] if shift == "refused"
+         else mirrored_pairs([x], 0.0)[0])
     pc_exp, evr_exp = _oracle(x, 3)
     model = (PCA().setK(3).setInputCol("features")
              .set("gramPrecision", "bfloat16").set("batchRows", 256).fit(x))
-    assert seen == [(False, "bfloat16")] * 3 + [(True, "bfloat16")]
+    passes = 2 if shift == "refused" else 1
+    assert verdict(model.fit_report_.extra["ingest"]) == (
+        shift == "accepted", passes)
+    assert seen == ([(False, "bfloat16")] * 3 + [(True, "bfloat16")]) * passes
     np.testing.assert_allclose(np.abs(model.pc), np.abs(pc_exp), atol=1e-5)
 
 

@@ -79,8 +79,10 @@ def test_in_memory_fit_is_invariant_to_batch_rows(batch_rows):
     ingest = model.fit_report_.extra["ingest"]
     if batch_rows != 7:
         # a matrix under one batch is one batch of exactly its rows: the
-        # source clamps, nothing is padded
-        assert ingest["batches"] == 2 and ingest["rows_put"] == 2 * ROWS
+        # source clamps, nothing is padded — and one batch is its own mean,
+        # so the Gram of pass 1 stands and the rows cross once
+        assert ingest["batches"] == 1 and ingest["rows_put"] == ROWS
+        assert ingest["gram_shift"]["accepted"] and ingest["passes"] == 1
 
 
 def test_num_devices_is_honoured_for_a_matrix():

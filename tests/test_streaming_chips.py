@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shift_rows import mirrored_pairs, verdict
 
 from spark_rapids_ml_tpu import PCA
 from spark_rapids_ml_tpu.data.batches import BatchSource
@@ -53,13 +54,19 @@ def _bench_module(relpath: str):
     return module
 
 
-def _chunks(n: int, rows: tuple, seed: int = 13) -> list:
+def _chunks(n: int, rows: tuple, seed: int = 13,
+            shift: str = "refused") -> list:
     rng = np.random.default_rng(seed)
     # |mean| >> sigma in some columns, and a mean that drifts from chunk to
-    # chunk: a chip's own mean is then far from the mean of all rows
-    return [(rng.normal(size=(r, n)) * np.linspace(2.0, 0.5, n)
-             + 20.0 * (np.arange(n) % 3) + 3.0 * i).astype(np.float32)
-            for i, r in enumerate(rows)]
+    # chunk: a chip's own mean is then far from the mean of all rows — and
+    # a chip's first batch no stand-in for it: every two-pass fit of these
+    # rows refuses its shifted Gram and runs pass 2. ``accepted``: the same
+    # rows as mirrored pairs about the columns' centres (no drift left).
+    centre = 20.0 * (np.arange(n) % 3)
+    chunks = [(rng.normal(size=(r, n)) * np.linspace(2.0, 0.5, n)
+               + centre + 3.0 * i).astype(np.float32)
+              for i, r in enumerate(rows)]
+    return mirrored_pairs(chunks, centre) if shift == "accepted" else chunks
 
 
 def _dataset(form: str, chunks: list):
@@ -264,31 +271,46 @@ def _span_names(model) -> list:
             if not e.name.startswith(streaming.SPAN_LANDING)]
 
 
+@pytest.mark.parametrize("shift", ["refused", "accepted"])
 @pytest.mark.parametrize("chips", [2, 4])
-def test_two_pass_fit_dispatches_two_collectives(chips):
+def test_two_pass_fit_dispatches_two_collectives(chips, shift):
     n = 128
-    model = _fit(chips, "callable", _chunks(n, ROWS["tail7"]))
+    model = _fit(chips, "callable", _chunks(n, ROWS["tail7"], shift=shift))
+    refused = shift == "refused"
+    ingest = model.fit_report_.extra["ingest"]
+    assert verdict(ingest) == (not refused, 2 if refused else 1)
     names = _span_names(model)
     mean_at = names.index(streaming.SPAN_COLLECTIVE["mean"])
     gram_at = names.index(streaming.SPAN_COLLECTIVE["gram"])
     assert names.count(streaming.SPAN_COLLECTIVE["mean"]) == 1
-    assert names.count(streaming.SPAN_COLLECTIVE["gram"]) == 1
-    # (a) between the passes, (b) after pass 2 and before the first host read
-    assert (names.index(streaming.SPAN_PASS_MEAN) < mean_at
-            < names.index(streaming.SPAN_PASS_GRAM) < gram_at
+    # (b) once for the Grams summed in pass 1 and re-centred, and once more
+    # for pass 2's where the rows refused those
+    assert names.count(streaming.SPAN_COLLECTIVE["gram"]) == 1 + refused
+    # (a) after pass 1, (b) before the first host read, the verdict's
+    assert (names.index(streaming.SPAN_PASS_MEAN) < mean_at < gram_at
             < names.index(streaming.SPAN_SYNC_COUNT)
             < names.index(streaming.SPAN_SYNC_COV))
+    if refused:
+        again = len(names) - 1 - names[::-1].index(
+            streaming.SPAN_COLLECTIVE["gram"])
+        assert (names.index(streaming.SPAN_SYNC_COUNT)
+                < names.index(streaming.SPAN_PASS_GRAM) < again
+                < names.index(streaming.SPAN_SYNC_COV))
+    else:
+        assert streaming.SPAN_PASS_GRAM not in names
     # everything else is the one-chip fit's list
-    one = _span_names(_fit(1, "callable", _chunks(n, ROWS["tail7"])))
+    one = _span_names(_fit(1, "callable",
+                           _chunks(n, ROWS["tail7"], shift=shift)))
     assert [s for s in names
             if s not in streaming.SPAN_COLLECTIVE.values()] == one
     collective = _bench_module("work/collective.py")
-    ingest = model.fit_report_.extra["ingest"]
     assert ingest["collective_bytes"] == {
-        "mean": collective.mean_bytes(n), "gram": collective.gram_bytes(n)}
+        "mean": collective.mean_bytes(n),
+        "gram": (1 + refused) * collective.gram_bytes(n)}
     assert model.fit_report_.collectives["all_reduce"] == {
-        "count": 2,
-        "bytes": collective.mean_bytes(n) + collective.gram_bytes(n)}
+        "count": 2 + refused,
+        "bytes": collective.mean_bytes(n)
+        + (1 + refused) * collective.gram_bytes(n)}
     t = model.fit_timings_
     assert 0 < t[streaming.PHASE_COLLECTIVE] < t["covariance"]
     assert t[collective.PHASE] == t[streaming.PHASE_COLLECTIVE]
@@ -331,7 +353,8 @@ def test_hbm_is_read_on_every_chip_at_the_boundaries(monkeypatch):
     monkeypatch.setattr(streaming, "device_memory_stats", stats)
     report = _fit(2, "callable", _chunks(98, ROWS["few3"])).fit_report_
     ingest = report.extra["ingest"]
-    boundaries = ["pass/mean:end", "pass/gram:end", "sync/count", "sync/cov",
+    assert verdict(ingest) == (False, 2)  # the read comes before pass 2
+    boundaries = ["pass/mean:end", "sync/count", "pass/gram:end", "sync/cov",
                   "solve:start", "solve:end"]
     for chip in ingest["per_chip"]:
         assert list(chip["hbm_bytes_in_use"]) == boundaries
@@ -396,8 +419,9 @@ def test_more_chips_than_the_process_has_is_refused():
         PCA().set("numDevices", 0)
 
 
-def test_the_mesh_entry_point_reports_every_row_once():
-    chunks = _chunks(98, ROWS["tail7"])
+@pytest.mark.parametrize("shift", ["refused", "accepted"])
+def test_the_mesh_entry_point_reports_every_row_once(shift):
+    chunks = _chunks(98, ROWS["tail7"], shift=shift)
     x = np.concatenate(chunks)
     result = distributed_streaming_pca_fit(
         BatchSource(x, batch_rows=BATCH), K, data_mesh(4))
@@ -405,8 +429,12 @@ def test_the_mesh_entry_point_reports_every_row_once():
     assert len(rows) == 4 and sum(rows.values()) == x.shape[0]
     # seven batches in turn: the tail (40 rows) is chip 2's second batch
     assert list(rows.values()) == [128, 128, 64 + 40, 64]
-    assert result.fit_report_.extra["ingest"]["chips"] == 4
-    assert result.fit_report_.collectives["all_reduce"]["count"] == 2
+    ingest = result.fit_report_.extra["ingest"]
+    assert ingest["chips"] == 4
+    assert verdict(ingest)[0] == (shift == "accepted")
+    # the means, the Grams of pass 1 — and pass 2's where those were refused
+    assert result.fit_report_.collectives["all_reduce"]["count"] == (
+        3 if shift == "refused" else 2)
 
 
 # -- the Pallas path's name, per chip -----------------------------------------

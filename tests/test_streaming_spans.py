@@ -1,8 +1,10 @@
 """Spans, sub-phases and counters of the streamed PCA fit (CPU).
 
-One fit per source form — a callable (two passes), a one-shot iterator
-(one pass), and each of them with a ragged tail — then one test per
-(form, assertion group). The name guards at the end hold the benchmark's
+One fit per source form — a callable (two passes where its rows refuse
+the shifted Gram, as i.i.d. batches of 64 rows do; one walk where they
+accept it, the ``*_accepted`` forms: ``tests/test_streaming_shift.py``), a
+one-shot iterator (one pass), and each of them with a ragged tail — then
+one test per (form, assertion group). The name guards at the end hold the benchmark's
 lists (``benchmarks/work/spans.py``, ``benchmarks/work/gram.py``) against
 what the program emits, so a rename fails here instead of turning a
 per-layer metric into ``null``.
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shift_rows import mirrored_pairs, verdict
 
 from spark_rapids_ml_tpu import PCA
 from spark_rapids_ml_tpu.data.batches import BatchSource
@@ -36,6 +39,9 @@ FORMS = {
     "iterator": ("iterator", (128, 128)),
     "ragged_callable": ("callable", (128, 104)),
     "ragged_iterator": ("iterator", (128, 104)),
+    # the same rows as mirrored pairs: the two-pass fit's shift is accepted
+    "callable_accepted": ("callable", (128, 128)),
+    "ragged_callable_accepted": ("callable", (128, 104)),
 }
 SUB_PHASES = ("covariance/next", "covariance/put", "covariance/dispatch",
               "covariance/sync")
@@ -50,9 +56,11 @@ def _bench_module(relpath: str):
     return module
 
 
-def _chunks(rows: tuple, seed: int = 7) -> list:
+def _chunks(rows: tuple, seed: int = 7, accepted: bool = False) -> list:
     rng = np.random.default_rng(seed)
-    return [(rng.normal(size=(r, N)) + 0.5).astype(np.float32) for r in rows]
+    chunks = [(rng.normal(size=(r, N)) + 0.5).astype(np.float32)
+              for r in rows]
+    return mirrored_pairs(chunks, 0.5) if accepted else chunks
 
 
 def _dataset(input_form: str, chunks: list):
@@ -73,13 +81,21 @@ def _by_hand(input_form: str, chunks: list):
     if source.reiterable:
         mstats = streaming.MeanStats(jnp.zeros((N,), jnp.float32),
                                      jnp.zeros((), jnp.int32))
-        for batch, mask in source.batches():
-            mstats = streaming.update_mean_stats(mstats, *put(batch, mask))
-        mean = mstats.col_sum / mstats.count
-        gram = jnp.zeros((N, N), jnp.float32)
+        gram, shift = jnp.zeros((N, N), jnp.float32), None
         for batch, mask in source.batches():
             x, m = put(batch, mask)
-            gram = streaming.update_centered_gram_auto(gram, x, mean, m)
+            mstats = streaming.update_mean_stats(mstats, x, m)
+            if shift is None:
+                shift = mstats.col_sum / mstats.count
+            gram = streaming.update_centered_gram_auto(gram, x, shift, m)
+        mean = mstats.col_sum / mstats.count
+        gram, ratio = streaming.recentre_gram(gram, mstats.col_sum,
+                                              mstats.count, shift, mean)
+        if not float(ratio) <= streaming.SHIFT_RATIO_MAX:
+            gram = jnp.zeros((N, N), jnp.float32)
+            for batch, mask in source.batches():
+                x, m = put(batch, mask)
+                gram = streaming.update_centered_gram_auto(gram, x, mean, m)
         return gram / jnp.maximum(mstats.count - 1, 1), mean, mstats.count
     stats = streaming.init_stats(N)
     for batch, mask in source.batches():
@@ -91,7 +107,8 @@ def _by_hand(input_form: str, chunks: list):
 @pytest.fixture(scope="module", params=sorted(FORMS))
 def fitted(request):
     input_form, rows = FORMS[request.param]
-    chunks = _chunks(rows)
+    accepted = request.param.endswith("_accepted")
+    chunks = _chunks(rows, accepted=accepted)
     model = PCA().setK(K).set("batchRows", BATCH).set("dtype", "float32").fit(
         _dataset(input_form, chunks))
     report = model.fit_report_
@@ -101,9 +118,12 @@ def fitted(request):
         report.trace_id) if not e.name.startswith(streaming.SPAN_LANDING)),
         key=lambda e: (e.ts_us, -e.dur_us))
     two_pass = input_form == "callable"
+    if two_pass:  # the rows are on the side of the verdict they are meant for
+        assert verdict(report.extra["ingest"]) == (accepted,
+                                                   1 if accepted else 2)
     return {"form": request.param, "input_form": input_form, "rows": rows,
             "chunks": chunks, "model": model, "report": report,
-            "events": events, "two_pass": two_pass,
+            "events": events, "two_pass": two_pass, "accepted": accepted,
             "batches_per_pass": -(-sum(rows) // BATCH),
             "ragged": sum(rows) % BATCH != 0}
 
@@ -133,9 +153,15 @@ def _expected_names(f) -> list:
     # on the CPU every Gram goes the XLA way, the masked tail included
     names = [pca_module.SPAN_FIT, pca_module.SPAN_STREAMED_COV]
     if f["two_pass"]:
-        names += walk(streaming.SPAN_PASS_MEAN, ["mean"] * per_pass)
-        names += walk(streaming.SPAN_PASS_GRAM, ["xla"] * per_pass)
+        # pass 1: each batch's mean step, then its Gram step about the
+        # first batch's mean; the one host read is the verdict's, and only
+        # a refused shift walks the rows again
+        mean, gram = (streaming.SPAN_ACCUMULATE[p] for p in ("mean", "xla"))
+        for name in walk(streaming.SPAN_PASS_MEAN, ["mean"] * per_pass):
+            names += [name, gram] if name == mean else [name]
         names += [streaming.SPAN_SYNC_COUNT]
+        if not f["accepted"]:
+            names += walk(streaming.SPAN_PASS_GRAM, ["xla"] * per_pass)
     else:
         names += walk(streaming.SPAN_PASS_STATS, ["xla"] * per_pass)
     return names + [streaming.SPAN_SYNC_COV, pca_module.SPAN_XLA_EIGH,
@@ -201,7 +227,7 @@ def test_sub_phases_are_in_fit_timings(fitted):
 
 def test_ingest_counters(fitted):
     ingest = fitted["report"].extra["ingest"]
-    passes = 2 if fitted["two_pass"] else 1
+    passes = 2 if fitted["two_pass"] and not fitted["accepted"] else 1
     per_pass = fitted["batches_per_pass"]
     assert ingest["passes"] == passes
     assert ingest["batches"] == passes * per_pass
@@ -212,7 +238,8 @@ def test_ingest_counters(fitted):
     assert calls["mean"] == (per_pass if fitted["two_pass"] else 0)
     # the ragged tail is masked, and a masked batch goes the XLA way at
     # the full padded shape (ROADMAP M3) — as does everything on the CPU
-    assert calls["xla"] == per_pass and calls["pallas"] == 0
+    # (as dispatched: a refused shift's steps of pass 1 and of pass 2)
+    assert calls["xla"] == passes * per_pass and calls["pallas"] == 0
     assert 0 < ingest["put_seconds_max"] <= \
         fitted["model"].fit_timings_["covariance/put"]
     assert 0 < ingest["sync_seconds_max"] <= \
@@ -246,23 +273,29 @@ def test_result_is_bit_equal_to_the_bare_accumulate_calls(fitted):
                           np.asarray(want_mean, dtype=np.float64))
 
 
-@pytest.mark.parametrize("traffic", ["fit-1pass", "fit-2pass"])
-def test_bytes_put_are_the_bytes_the_benchmark_reckons(traffic):
+@pytest.mark.parametrize("traffic,accepted", [
+    ("fit-1pass", False), ("fit-2pass", False), ("fit-2pass", True)],
+    ids=["fit-1pass", "fit-2pass", "fit-2pass-accepted"])
+def test_bytes_put_are_the_bytes_the_benchmark_reckons(traffic, accepted):
     """``benchmarks/run.py`` hands the readers ``crossings x rows x n x 4``
-    as the bytes put; the program counts the same from the arrays."""
+    as the bytes put; the program counts the same from the arrays — on the
+    CPU, where nothing is kept, and where the rows refuse the shifted Gram
+    (pass 2 then puts them again). Rows that accept it cross once whatever
+    is kept, as they do on the chip."""
     with open(os.path.join(ROOT, "benchmarks", "traffic",
                            traffic + ".json")) as f:
         spec = json.load(f)
     chunk_rows, n_chunks = 2 * BATCH, spec["chunks_per_fit"]
-    chunks = _chunks((chunk_rows,) * n_chunks, seed=3)
+    chunks = _chunks((chunk_rows,) * n_chunks, seed=3, accepted=accepted)
     model = PCA().setK(K).set("batchRows", BATCH).set(
         "dtype", "float32").fit(_dataset(spec["input_form"], chunks))
     ingest = model.fit_report_.extra["ingest"]
     itemsize = np.dtype("float32").itemsize
+    crossings = 1 if accepted else spec["crossings"]
     assert ingest["bytes_put"] == (
-        spec["crossings"] * chunk_rows * n_chunks * N * itemsize)
-    assert ingest["passes"] == spec["crossings"]
-    assert ingest["rows_put"] == spec["crossings"] * chunk_rows * n_chunks
+        crossings * chunk_rows * n_chunks * N * itemsize)
+    assert ingest["passes"] == crossings
+    assert ingest["rows_put"] == crossings * chunk_rows * n_chunks
 
 
 def test_hbm_is_read_at_the_boundaries_only(monkeypatch):
@@ -278,16 +311,23 @@ def test_hbm_is_read_at_the_boundaries_only(monkeypatch):
     # the two-pass fit reads once more, before its first put: the budget of
     # the batches it may keep (``keep_budget_bytes``; no ``bytes_limit`` in
     # these stats, so it keeps none)
+    # (the verdict's read comes before a refused shift's pass 2)
     assert list(two.extra["ingest"]["hbm_bytes_in_use"].items()) == [
-        ("pass/mean:end", 1002), ("pass/gram:end", 1003),
-        ("sync/count", 1004), ("sync/cov", 1005),
+        ("pass/mean:end", 1002), ("sync/count", 1003),
+        ("pass/gram:end", 1004), ("sync/cov", 1005),
         ("solve:start", 1006), ("solve:end", 1007)]
     assert two.extra["ingest"]["batches_kept"] == 0
+    assert verdict(two.extra["ingest"]) == (False, 2)
+    accepted = PCA().setK(K).set("batchRows", BATCH).fit(
+        _dataset("callable", _chunks((128, 104), accepted=True))).fit_report_
+    assert list(accepted.extra["ingest"]["hbm_bytes_in_use"]) == [
+        "pass/mean:end", "sync/count", "sync/cov", "solve:start",
+        "solve:end"]
     one = PCA().setK(K).set("batchRows", BATCH).fit(
         _dataset("iterator", _chunks((128, 104)))).fit_report_
     assert list(one.extra["ingest"]["hbm_bytes_in_use"]) == [
         "pass/stats:end", "sync/cov", "solve:start", "solve:end"]
-    assert len(reads) == 11  # one a boundary, one the budget, none a batch
+    assert len(reads) == 17  # one a boundary, one the budget, none a batch
 
 
 def test_counters_outside_a_fit_go_nowhere():
@@ -381,4 +421,7 @@ def test_no_other_tracked_accumulate_function_hides_in_streaming():
     assert tracked == {
         "update_mean_stats", "update_centered_gram",
         "_update_centered_gram_fused_blocked", "update_stats",
-        "_update_stats_fused_blocked", "finalize_stats"}
+        "_update_stats_fused_blocked", "finalize_stats",
+        # no accumulate program: one elementwise pass over the n x n sum
+        # (``tests/test_streaming_shift.py`` holds its name off the list)
+        "recentre_gram"}

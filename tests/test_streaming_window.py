@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from shift_rows import mirrored_pairs, verdict
 
 from spark_rapids_ml_tpu import PCA
 from spark_rapids_ml_tpu.data.batches import BatchSource
@@ -27,20 +28,25 @@ from spark_rapids_ml_tpu.parallel.streaming import DistributedStreamingPCA
 N, BATCH, K = 24, 32, 3
 EVERYTHING = 1 << 40
 # how the rows are handed over → (input form, keep budget, passes that put)
+# (i.i.d. batches of 32 rows refuse the two-pass fit's shifted Gram, so pass
+# 2 runs; the ``_accepted`` form is the same rows as mirrored pairs: one walk)
 FORMS = {
     "one_pass": ("iterator", 0, 1),
     "two_pass": ("callable", 0, 2),  # the CPU as it is: both passes put
     "two_pass_kept": ("callable", EVERYTHING, 1),  # the chip: pass 1 only
+    "two_pass_accepted": ("callable", 0, 1),  # no pass 2 to put anything
 }
 BATCHES = (1, 2, 3, 6)
 
 
-def _chunks(batches: int, seed: int = 5) -> list:
+def _chunks(batches: int, seed: int = 5, form: str = "") -> list:
     rng = np.random.default_rng(seed)
     # two chunks, so a batch may straddle them; whole batches only
     rows = BATCH * batches
-    x = (rng.normal(size=(rows, N)) + 4.0 * (np.arange(N) % 3)).astype(
-        np.float32)
+    centre = 4.0 * (np.arange(N) % 3)
+    x = (rng.normal(size=(rows, N)) + centre).astype(np.float32)
+    if form.endswith("_accepted"):
+        (x,) = mirrored_pairs([x], centre)
     return [x] if batches == 1 else [x[: rows // 2], x[rows // 2:]]
 
 
@@ -115,8 +121,13 @@ def test_one_chip_waits_for_the_put_before_last(monkeypatch, form, batches):
     input_form, budget, putting_passes = FORMS[form]
     _budget(monkeypatch, budget)
     log = _Log(monkeypatch)
-    got, ingest = _stream(_dataset(input_form, _chunks(batches)))
+    got, ingest = _stream(_dataset(input_form, _chunks(batches, form=form)))
     assert got[2] == BATCH * batches
+    if input_form == "callable":
+        # one batch is its own mean: its shift is accepted whatever the rows
+        accepted = form == "two_pass_accepted" or batches == 1
+        assert verdict(ingest) == (accepted, 1 if accepted else 2)
+        putting_passes = 1 if accepted else putting_passes
     puts = putting_passes * batches
     c = ingest.counters
     assert c["batches"] == puts == len(log.puts())
@@ -135,7 +146,9 @@ def test_four_chips_each_wait_for_their_own_oldest_put(monkeypatch, form):
     input_form, budget, putting_passes = FORMS[form]
     _budget(monkeypatch, budget)
     log = _Log(monkeypatch)
-    _, ingest = _stream(_dataset(input_form, _chunks(16)), chips=4)
+    _, ingest = _stream(_dataset(input_form, _chunks(16, form=form)), chips=4)
+    if input_form == "callable":
+        assert verdict(ingest)[0] == (form == "two_pass_accepted")
     c = ingest.counters
     per_chip_puts = 4 * putting_passes
     assert c["batches"] == 4 * per_chip_puts
