@@ -33,6 +33,12 @@ program's place and computed in bfloat16, the step below the float32 the
 configurations state — rows and centred rows rounded to bfloat16 before
 they are multiplied (products still summed in float32, as the MXU does)
 and the column sums kept in a bfloat16 accumulator.
+
+``model_of`` is what the harness takes from a fitted model, inside every
+timed fit: the three host arrays compared. ``FAULTS`` are the breaks of
+the timed path that the comparison has to see, planted by the benchmark's
+tests (``test_benchmark.py``) with pytest's ``monkeypatch``: only they
+reach into the program, and only in a test.
 """
 
 from __future__ import annotations
@@ -122,6 +128,12 @@ def lower_precision_model(chunks, k: int, device=None,
             "mean": mean}
 
 
+def model_of(fitted) -> dict:
+    return {"pc": np.asarray(fitted.pc),
+            "explained_variance": np.asarray(fitted.explained_variance),
+            "mean": np.asarray(fitted.mean)}
+
+
 def gaps(model: dict, ref: dict) -> dict:
     """The three compared numbers for one fitted model."""
     pc = np.asarray(model["pc"], dtype=np.float64)
@@ -165,3 +177,77 @@ def compare(models: list, ref: dict, limits: dict):
         c["limit"] is not None and c["value"] <= c["limit"]
         for c in compared.values())
     return correct, compared
+
+
+# -- planted faults (the tests') ----------------------------------------------
+
+
+def _skip_second_call(real):
+    calls = {"n": 0}
+
+    def step(acc, *args, **kwargs):
+        calls["n"] += 1
+        return acc if calls["n"] % 4 == 2 else real(acc, *args, **kwargs)
+
+    return step
+
+
+def _half_batch(real):
+    def step(acc, batch, *args, **kwargs):
+        import jax.numpy as jnp
+
+        half = batch[: batch.shape[0] // 2]
+        return real(acc, jnp.concatenate([half, half]), *args, **kwargs)
+
+    return step
+
+
+def break_state_unchanged(monkeypatch):
+    """One accumulate step in four hands its state back unchanged."""
+    from spark_rapids_ml_tpu.ops import streaming
+
+    for name in ("update_centered_gram_auto", "update_stats_auto"):
+        monkeypatch.setattr(streaming, name,
+                            _skip_second_call(getattr(streaming, name)))
+
+
+def break_half_batch(monkeypatch):
+    """Every accumulate step (mean pass too) leaves half of its batch out
+    and takes the mean and the covariance over the rest. The rest stands
+    in twice, so that the row count stays what the program's own guard
+    (pass 1 against pass 2) expects."""
+    from spark_rapids_ml_tpu.ops import streaming
+
+    for name in ("update_mean_stats", "update_centered_gram_auto",
+                 "update_stats_auto"):
+        monkeypatch.setattr(streaming, name,
+                            _half_batch(getattr(streaming, name)))
+
+
+def _alter(field):
+    def breaker(monkeypatch):
+        from spark_rapids_ml_tpu.models import pca
+
+        real = pca.PCAModel.__init__
+
+        def init(self, *args, **kwargs):
+            if field == "explained_variance":
+                kwargs[field] = np.array(kwargs[field]) * 1.001
+            else:
+                kwargs[field] = np.array(kwargs[field])
+                kwargs[field][..., 0] += 1e-3
+            real(self, *args, **kwargs)
+
+        monkeypatch.setattr(pca.PCAModel, "__init__", init)
+
+    breaker.__doc__ = f"The answer's {field} altered where it is produced."
+    return breaker
+
+
+FAULTS = {
+    "state_unchanged": break_state_unchanged,
+    "half_batch": break_half_batch,
+    "pc_altered": _alter("pc"),
+    "variance_altered": _alter("explained_variance"),
+    "mean_altered": _alter("mean"),
+}

@@ -5,11 +5,16 @@
 
 Everything that belongs to a cell is data or a small file found by name
 from ``BENCHMARK.json``: the configuration (``configs/<config>.json``: the
-estimator, its Params, the rows recipe), the traffic mix
-(``traffic/<traffic>.json``: how the host chunks are handed to ``fit``),
-the cell's limits (``cells/<workload>.json``), the plain reference
-(``reference/<name>.py``) and one reader per per-layer metric
-(``metrics/<metric>.py``). This file names none of them.
+estimator, its Params, the rows recipe, and the names of its reference and
+its work), the traffic mix (``traffic/<traffic>.json``: how the host
+chunks are handed to ``fit``), the cell's limits (``cells/<workload>.json``),
+the plain reference (``reference/<reference>.py``: ``model_of``, which
+takes from a fitted model what is compared, ``reference``, ``compare`` and
+the planted ``FAULTS`` of its tests), the algorithm's work
+(``work/<work>.py``: ``flops``, ``least_seconds`` and, where a trace can
+name them, the ``PROGRAMS`` that do it) and one reader per per-layer
+metric (``metrics/<metric>.py``). This file names none of them, and no
+field of any fitted model.
 
 A run: make the rows from ``--seed`` (set-up), fit once to warm every
 program (set-up), then fit back to back for ``--seconds`` — a fit that has
@@ -67,9 +72,13 @@ def read_json(path: str):
         return json.load(f)
 
 
-def load_spec(workload: str) -> dict:
-    """Everything the cell is made of, resolved by name."""
-    bench = read_json(os.path.join(ROOT, "BENCHMARK.json"))
+def load_spec(workload: str, home: str = "") -> dict:
+    """Everything the cell is made of, resolved by name. ``home`` is the
+    directory under ``benchmarks/`` that holds the traffic, cells,
+    reference and work files, and a ``fixtures.json`` in the form of
+    ``BENCHMARK.json`` where it is not the benchmark's (the tests')."""
+    bench = read_json(os.path.join(HERE, home, "fixtures.json") if home
+                      else os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; "
@@ -81,6 +90,9 @@ def load_spec(workload: str) -> dict:
         return workload in metric.get("workloads", [workload])
 
     config = read_json(os.path.join(ROOT, entry["file"]))
+    if not config.get("work"):
+        raise SystemExit(f"configuration {entry['name']!r} names no work "
+                         f"module (\"work\": <name of work/<name>.py>)")
     # the deployment's settings of the TPU runtime; libtpu reads them when
     # JAX first touches the chip, so they are set before anything does
     for key, value in config.get("runtime_env", {}).items():
@@ -88,10 +100,11 @@ def load_spec(workload: str) -> dict:
     return {
         "cell": cell,
         "config": config,
+        "home": home,
         "traffic": read_json(os.path.join(
-            HERE, "traffic", cell["traffic"] + ".json")),
+            HERE, home, "traffic", cell["traffic"] + ".json")),
         "limits": read_json(os.path.join(
-            HERE, "cells", workload + ".json"))["limits"],
+            HERE, home, "cells", workload + ".json"))["limits"],
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
     }
@@ -172,18 +185,28 @@ def dataset_factory(traffic: dict, chunks: list):
     raise SystemExit(f"unknown input_form {form!r}")
 
 
-def model_of(fitted) -> dict:
-    import numpy as np
-
-    return {"pc": np.asarray(fitted.pc),
-            "explained_variance": np.asarray(fitted.explained_variance),
-            "mean": np.asarray(fitted.mean)}
+def reference_of(config: dict, home: str = ""):
+    """The configuration's plain reference module."""
+    return load_module(os.path.join(home, "reference",
+                                    config["reference"] + ".py"))
 
 
-def fit_once(config: dict, new_dataset, overrides: dict = None) -> dict:
-    """One ``fit`` from host chunks to a model on the host, timed."""
+def work_of(config: dict, home: str = ""):
+    """The configuration's work module, bound to the configuration where
+    the module asks for it (``bind(config)``: work that depends on a
+    Param as well as on rows and features)."""
+    work = load_module(os.path.join(home, "work", config["work"] + ".py"))
+    return work.bind(config) if hasattr(work, "bind") else work
+
+
+def fit_once(config: dict, new_dataset, overrides: dict = None,
+             model_of=None) -> dict:
+    """One ``fit`` from host chunks to a model on the host, timed: the
+    wall holds the reference's ``model_of``, which fetches what is
+    compared."""
     import jax
 
+    model_of = model_of or reference_of(config).model_of
     est = make_estimator(config, overrides)
     dataset = new_dataset()
     start = time.perf_counter()
@@ -197,14 +220,15 @@ def fit_once(config: dict, new_dataset, overrides: dict = None) -> dict:
             "model": model}
 
 
-def run_window(config: dict, new_dataset, seconds: float) -> tuple:
+def run_window(config: dict, new_dataset, seconds: float,
+               model_of=None) -> tuple:
     """Fits back to back until ``seconds`` have passed; a fit that has
     started runs to its end. (fits, failures)."""
     fits, failed = [], 0
     t0 = time.perf_counter()
     while time.perf_counter() - t0 < seconds:
         try:
-            fits.append(fit_once(config, new_dataset))
+            fits.append(fit_once(config, new_dataset, model_of=model_of))
         except Exception:  # noqa: BLE001 - counted, reported, not hidden
             failed += 1
             log(traceback.format_exc())
@@ -261,6 +285,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         require_chip: bool = True) -> dict:
     spec = load_spec(workload)
     config, traffic = spec["config"], spec["traffic"]
+    home = spec.get("home", "")
     import jax
 
     if require_chip:
@@ -281,7 +306,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         f"{time.perf_counter() - t:.2f}s")
     new_dataset = dataset_factory(traffic, chunks)
 
-    warm = fit_once(config, new_dataset)
+    ref_module, work = reference_of(config, home), work_of(config, home)
+    warm = fit_once(config, new_dataset, model_of=ref_module.model_of)
     log(f"warm-up fit: {warm['wall']:.2f}s {json.dumps(warm['timings'])} "
         f"solver={warm['solver']} compiles={compiles.count}")
 
@@ -294,7 +320,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     compiles_before = compiles.count
     window_start = time.perf_counter()
     setup_s = window_start - T_START
-    fits, failed = run_window(config, new_dataset, seconds)
+    fits, failed = run_window(config, new_dataset, seconds,
+                              model_of=ref_module.model_of)
     if trace:
         jax.profiler.stop_trace()
     compiles_in_window = compiles.count - compiles_before
@@ -316,8 +343,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
     # only now the reference: the peak above is the fits' own
     t = time.perf_counter()
-    ref_module = load_module(os.path.join("reference",
-                                          config["reference"] + ".py"))
     ref = ref_module.reference(chunks, devices[0])
     correct, compared = ref_module.compare(
         [f["model"] for f in fits], ref, spec["limits"])
@@ -327,7 +352,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         metrics = read_per_layer(spec, {
             "fits": fits, "window_s": window_s, "rows_per_fit": rows_per_fit,
-            "n_features": n,
+            "n_features": n, "chips": len(devices),
+            "work": work,
+            # the static count; the program's own, where its reports have
+            # it, is ``work/crossing.py`` ``window_ingest``
             "bytes_put_per_fit": traffic["crossings"] * rows_per_fit * n * 4,
             "first_fit_s": warm["wall"], "setup_s": setup_s,
             "compiles_in_window": compiles_in_window, "trace": trace_info,

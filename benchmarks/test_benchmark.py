@@ -12,6 +12,7 @@ false. Nothing here is a device number.
 from __future__ import annotations
 
 import copy
+import hashlib
 import importlib.util
 import json
 import os
@@ -90,7 +91,8 @@ def test_readers_on_the_recorded_trace(recorded):
                                                   "solve": 0.1}}],
            "window_s": 1.5563, "rows_per_fit": 524288, "n_features": 4096,
            "bytes_put_per_fit": 2 * 524288 * 4096 * 4, "first_fit_s": 5.9,
-           "compiles_in_window": 0, "peak": V5E,
+           "compiles_in_window": 0, "peak": V5E, "chips": 1,
+           "work": bench.load_module("work/gram.py"),
            "load_module": bench.load_module,
            "trace": {"planes": planes, "lo": lo, "hi": hi, "busy_s": busy_s,
                      "window_s": (hi - lo) / 1e9}}
@@ -106,6 +108,13 @@ def test_readers_on_the_recorded_trace(recorded):
     assert read("covariance_gbytes_per_s") == pytest.approx(
         2 * 524288 * 4096 * 4 / 1.4 / 1e9)
     assert read("compiles_in_window") == 0
+    # the same work on four chips is a quarter of their peak
+    assert read("fit_mfu") / bench.load_module("metrics/fit_mfu.py").read(
+        dict(ctx, chips=4)) == pytest.approx(4.0)
+    # a work module that names no programs has no roofline to read
+    assert read("accumulate_roofline") is not None
+    ctx["work"] = type("Work", (), {"flops": staticmethod(lambda r, n: r)})
+    assert read("accumulate_roofline") is None
     # nothing to read -> nothing reported, never a 0 share
     ctx["trace"] = None
     assert read("accumulate_roofline") is None
@@ -156,15 +165,33 @@ def test_cell_resolves_to_its_files(cell):
     assert all(key in config for key in config["reduced"])
     rows, chunks = bench.chunk_shape(config, traffic)
     assert rows * chunks == config["rows_per_fit"]
-    assert rows % config["params"]["batchRows"] == 0  # no host re-blocking
-    assert os.path.exists(os.path.join(
-        HERE, "reference", config["reference"] + ".py"))
-    assert set(spec["limits"]) == {"mean_gap", "ritz_gap", "miss_gap"}
+    if "batchRows" in config["params"]:
+        assert rows % config["params"]["batchRows"] == 0  # no re-blocking
+    reference = bench.reference_of(config)
+    assert callable(reference.model_of) and reference.FAULTS
+    work = bench.work_of(config)
+    assert callable(work.flops) and callable(work.least_seconds)
+    assert spec["limits"] and all(
+        isinstance(v, (int, float)) for v in spec["limits"].values())
     for metric in spec["per_layer"]:
         reader = bench.load_module(f"metrics/{metric['name']}.py")
         assert callable(reader.read)
-    assert {m["name"] for m in spec["end_to_end"]} == {"fit_rows_per_s",
-                                                       "setup_s"}
+    e2e = {m["name"] for m in spec["end_to_end"]}
+    assert "setup_s" in e2e and len(e2e) >= 2
+
+
+def test_a_configuration_without_its_work_is_refused(monkeypatch):
+    real = bench.read_json
+
+    def without_work(path):
+        data = real(path)
+        if os.sep + "configs" + os.sep in path:
+            data.pop("work")
+        return data
+
+    monkeypatch.setattr(bench, "read_json", without_work)
+    with pytest.raises(SystemExit, match="work"):
+        bench.load_spec(CELLS[0])
 
 
 def test_benchmark_json_keeps_to_the_contracts_shapes():
@@ -199,6 +226,26 @@ def test_same_seed_same_rows_and_large_seeds_differ():
     assert not np.array_equal(a[0], a[1])
 
 
+# sha256 of make_chunks(2**31 + 40, n_features, 64, 2, recipe) on the CPU,
+# recorded on the parent of PR 40 (before the recipe's optional groups)
+ROWS_SHA256 = {
+    4096: "7507afa0526678f403ee859443c2cfdc45da693cbba6e76b08303472b5234e0e",
+    784: "93138f20ef0e253e3a9121a82a030b09b4f4de78257964a6ce6389a4d9bc7ce6",
+}
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCHMARK["configs"]])
+def test_each_configurations_rows_are_the_parents_bytes(name):
+    entry = next(c for c in BENCHMARK["configs"] if c["name"] == name)
+    config = bench.read_json(os.path.join(ROOT, entry["file"]))
+    chunks = bench.load_module("rows.py").make_chunks(
+        2 ** 31 + 40, config["n_features"], 64, 2, config["rows"])
+    digest = hashlib.sha256()
+    for chunk in chunks:
+        digest.update(chunk.tobytes())
+    assert digest.hexdigest() == ROWS_SHA256[config["n_features"]]
+
+
 def test_reference_agrees_with_numpy_float64():
     rows = bench.load_module("rows.py")
     ref_module = bench.load_module("reference/pca.py")
@@ -230,9 +277,19 @@ def test_reference_agrees_with_numpy_float64():
 
 def tiny_spec(cell: str) -> dict:
     """The cell's own files with its sizes cut for the CPU: same estimator,
-    Params, traffic form, reference and LIMITS."""
-    spec = copy.deepcopy(bench.load_spec(cell))
+    Params, traffic form, reference and LIMITS. A configuration cuts itself
+    where it has a ``tiny`` group (its top-level keys replaced, its
+    ``params`` merged); otherwise the PCA configurations' cut below."""
+    return cut_for_cpu(copy.deepcopy(bench.load_spec(cell)))
+
+
+def cut_for_cpu(spec: dict) -> dict:
     config = spec["config"]
+    if "tiny" in config:
+        tiny = config.pop("tiny")
+        config["params"].update(tiny.pop("params", {}))
+        config.update(tiny)
+        return spec
     if config["n_features"] >= 1024:   # keeps svdSolver=auto on randomized
         config["n_features"], config["params"]["k"] = 1024, 64
     else:
@@ -256,84 +313,21 @@ def test_sound_run_is_correct_under_the_cells_own_limits(monkeypatch, cell):
     result = run_tiny(monkeypatch, cell, seed=2 ** 31 + 11)
     assert result["correct"], result["compared"]
     assert result["failed"] == 0 and result["attempted"] >= 1
-    assert set(result["metrics"]) == {"fit_rows_per_s", "setup_s"}
+    assert set(result["metrics"]) == {
+        m["name"] for m in bench.load_spec(cell)["end_to_end"]}
 
 
-def _skip_second_call(real):
-    calls = {"n": 0}
-
-    def step(acc, *args, **kwargs):
-        calls["n"] += 1
-        return acc if calls["n"] % 4 == 2 else real(acc, *args, **kwargs)
-
-    return step
+def faults_of(cell: str) -> dict:
+    """The planted faults of the cell's configuration's reference."""
+    return bench.reference_of(bench.load_spec(cell)["config"]).FAULTS
 
 
-def _half_batch(real):
-    def step(acc, batch, *args, **kwargs):
-        import jax.numpy as jnp
-
-        half = batch[: batch.shape[0] // 2]
-        return real(acc, jnp.concatenate([half, half]), *args, **kwargs)
-
-    return step
-
-
-def break_state_unchanged(monkeypatch):
-    """One accumulate step in four hands its state back unchanged."""
-    from spark_rapids_ml_tpu.ops import streaming
-
-    for name in ("update_centered_gram_auto", "update_stats_auto"):
-        monkeypatch.setattr(streaming, name,
-                            _skip_second_call(getattr(streaming, name)))
-
-
-def break_half_batch(monkeypatch):
-    """Every accumulate step (mean pass too) leaves half of its batch out
-    and takes the mean and the covariance over the rest. The rest stands
-    in twice, so that the row count stays what the program's own guard
-    (pass 1 against pass 2) expects."""
-    from spark_rapids_ml_tpu.ops import streaming
-
-    for name in ("update_mean_stats", "update_centered_gram_auto",
-                 "update_stats_auto"):
-        monkeypatch.setattr(streaming, name,
-                            _half_batch(getattr(streaming, name)))
-
-
-def _alter(field):
-    def breaker(monkeypatch):
-        from spark_rapids_ml_tpu.models import pca
-
-        real = pca.PCAModel.__init__
-
-        def init(self, *args, **kwargs):
-            if field == "explained_variance":
-                kwargs[field] = np.array(kwargs[field]) * 1.001
-            else:
-                kwargs[field] = np.array(kwargs[field])
-                kwargs[field][..., 0] += 1e-3
-            real(self, *args, **kwargs)
-
-        monkeypatch.setattr(pca.PCAModel, "__init__", init)
-
-    breaker.__doc__ = f"The answer's {field} altered where it is produced."
-    return breaker
-
-
-FAULTS = {
-    "state_unchanged": break_state_unchanged,
-    "half_batch": break_half_batch,
-    "pc_altered": _alter("pc"),
-    "variance_altered": _alter("explained_variance"),
-    "mean_altered": _alter("mean"),
-}
-
-
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell,fault", [
+    (cell, fault) for cell in CELLS for fault in sorted(faults_of(cell))],
+    ids=[f"{cell}-{fault}" for cell in CELLS
+         for fault in sorted(faults_of(cell))])
 def test_broken_timed_path_is_not_correct(monkeypatch, cell, fault):
-    FAULTS[fault](monkeypatch)
+    faults_of(cell)[fault](monkeypatch)
     result = run_tiny(monkeypatch, cell)
     assert not result["correct"], result["compared"]
 

@@ -317,9 +317,8 @@ def test_every_new_metric_is_listed_with_its_reader():
         assert name in listed, name
         assert callable(bench.load_module(f"metrics/{name}.py").read)
         assert set(listed[name].get("workloads", tb.CELLS)) <= set(tb.CELLS)
-    assert [m["name"] for m in tb.BENCHMARK["per_layer"]][-8:] == list(
-        NEW_READERS)
-    layers = {m["layer"] for m in tb.BENCHMARK["per_layer"][:-8]}
+    layers = {m["layer"] for m in tb.BENCHMARK["per_layer"]
+              if m["name"] not in NEW_READERS}
     assert {listed[name]["layer"] for name in NEW_READERS} <= layers
 
 
@@ -355,10 +354,19 @@ def test_a_traced_style_line_carries_every_reader_the_cell_lists(
     front = config["estimator"].endswith("SparkStagePCA")
     itemsize = 8 if front and jax.config.jax_enable_x64 else 4
     nbytes = rows * n_chunks * config["n_features"] * itemsize
-    crossings = spec["traffic"]["crossings"] if chips == 1 else 1
+    crossings = 1
     if spec["traffic"]["input_form"] == "callable":
-        crossings = 2  # nothing is kept on the CPU: both passes put
+        # nothing is kept on the CPU: every pass puts the rows, and the
+        # fit says how many it made (one where the shifted Gram stands)
+        ingest = _crossing().window_ingest(ctx)
+        assert all(i["batches_kept"] == 0 for i in ingest)
+        crossings = sum(i["passes"] for i in ingest) / len(ingest)
     assert _read("bytes_put_per_fit_gb", ctx) == pytest.approx(
         crossings * nbytes / 1e9)
+    # and the phase's rate reads the program's bytes, not the static count
+    seconds = sum(f["timings"]["covariance"] for f in fits)
+    assert _read("covariance_gbytes_per_s", dict(
+        ctx, bytes_put_per_fit=-1.0)) == pytest.approx(
+        crossings * nbytes * len(fits) / seconds / 1e9)
     assert 0.0 < _read("crossing_busy_pct", ctx) <= 100.0
     assert (_read("landing_skew_pct", ctx) is not None) == (chips > 1)

@@ -114,8 +114,8 @@ def test_the_cell_asks_for_what_the_issue_names():
         assert four[key] == one[key], key
     assert four["rows_per_fit"] == 4 * one["rows_per_fit"]
     new = [m["name"] for m in spec["per_layer"] if "workloads" in m]
-    assert new == ["collective_share_pct", "collective_device_ms_per_fit",
-                   "chip_busy_skew_pct"]
+    assert {"collective_share_pct", "collective_device_ms_per_fit",
+            "chip_busy_skew_pct"} <= set(new)
 
 
 # -- the three readers on a synthetic four-plane trace ------------------------
