@@ -117,9 +117,10 @@ _SPARK_CLASS_ALIASES = {
 _SPARK_PARAM_ALLOWLIST = {
     "PCA": {"k", "inputCol", "outputCol"},
     "PCAModel": {"k", "inputCol", "outputCol"},
-    "KMeans": {"k", "maxIter", "tol", "seed", "predictionCol", "weightCol"},
-    "KMeansModel": {"k", "maxIter", "tol", "seed", "predictionCol",
-                    "weightCol"},
+    "KMeans": {"k", "maxIter", "tol", "seed", "initMode", "predictionCol",
+               "weightCol"},
+    "KMeansModel": {"k", "maxIter", "tol", "seed", "initMode",
+                    "predictionCol", "weightCol"},
     "LinearRegression": {"labelCol", "predictionCol", "fitIntercept",
                          "regParam", "elasticNetParam", "weightCol"},
     "LinearRegressionModel": {"labelCol", "predictionCol", "fitIntercept",

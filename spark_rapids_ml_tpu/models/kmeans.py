@@ -1,11 +1,20 @@
 """KMeans Estimator / Model with the Spark ML param surface.
 
 Second-algorithm coverage (BASELINE.md config 5). Param names follow Spark's
-``org.apache.spark.ml.clustering.KMeans``: k, maxIter, tol, seed,
-featuresCol(=inputCol), predictionCol. The accelerated path runs k-means++
-seeding + Lloyd entirely on device (one compiled program,
-``ops/kmeans_kernel.py``); host fallback is a NumPy Lloyd with identical
-semantics for the no-accelerator case.
+``org.apache.spark.ml.clustering.KMeans``: k, maxIter, tol, seed, initMode,
+featuresCol(=inputCol), predictionCol. An in-memory fit runs seeding +
+Lloyd entirely on device (one compiled program, ``ops/kmeans_kernel.py``);
+host fallback is a NumPy Lloyd with identical semantics for the
+no-accelerator case.
+
+A re-iterable source (a callable of chunks, or a matrix over the streaming
+threshold) is fitted on the streamed loop PCA runs: ``data.batches
+.BatchSource`` in ``batchRows`` batches through ``ops.streaming.IngestTrace``
+(put window, staging, landing watcher). The rows cross once: the first walk
+puts every batch, keeps it on the chip while ``keep_budget_bytes`` has room
+and draws the initial centres; each Lloyd step then walks the kept batches
+(and puts the rest again) with one ``update_cluster_stats`` a batch and one
+host read, and a cost pass under the final centres ends the fit.
 """
 
 from __future__ import annotations
@@ -30,6 +39,23 @@ from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
 from spark_rapids_ml_tpu.utils.timing import PhaseTimer
 from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
 
+# Host spans of a streamed fit's Lloyd loop, inside ``fit:kmeans`` (opened
+# by @observed_fit("kmeans")); the ingest's own (``stream:next``,
+# ``stream:put``, ``stream:landing/<id>``) are ``ops.streaming``'s. Not in
+# its ``STREAM_SPANS``: the benchmark lists these apart
+# (``benchmarks/work/lloyd.py``), and a test holds the two lists equal.
+SPAN_INIT = "lloyd:init"  # the first walk: rows put (and kept), centres drawn
+SPAN_STEP = "lloyd:step"  # one Lloyd step: a walk over the rows
+SPAN_DISPATCH = "lloyd:dispatch"  # one batch's update_cluster_stats call
+SPAN_SYNC = "lloyd:sync"  # the step's one host read: how far a centre moved
+SPAN_COST = "lloyd:cost"  # the walk under the final centres and its read
+LLOYD_SPANS = (SPAN_INIT, SPAN_STEP, SPAN_DISPATCH, SPAN_SYNC, SPAN_COST)
+PHASE_INIT = "lloyd/init"
+PHASE_DISPATCH = "lloyd/dispatch"
+PHASE_SYNC = "lloyd/sync"
+PHASE_COST = "lloyd/cost"
+INIT_MODES = ("k-means||", "random")
+
 
 class KMeansParams(HasInputCol, HasDeviceId, HasWeightCol):
     k = Param("k", "number of clusters", 2,
@@ -38,8 +64,16 @@ class KMeansParams(HasInputCol, HasDeviceId, HasWeightCol):
                     validator=lambda v: isinstance(v, int) and v >= 0)
     tol = Param("tol", "center-shift convergence tolerance", 1e-4,
                 validator=lambda v: v >= 0)
-    seed = Param("seed", "random seed for k-means++ init", 0,
+    seed = Param("seed", "random seed of the initial centres", 0,
                  validator=lambda v: isinstance(v, int))
+    initMode = Param(
+        "initMode",
+        "initial centres: 'random' (k distinct rows drawn uniformly by "
+        "seed, Spark's takeSample) or 'k-means||' (Spark's default name; "
+        "here k-means++ — on the device over an in-memory matrix, on the "
+        "host over a uniform sample of max(4096, 8k) rows of a streamed "
+        "source — the stand-in for Spark's parallel k-means||)",
+        "k-means||", validator=lambda v: v in INIT_MODES)
     # weightCol (HasWeightCol): weighted Lloyd updates/cost and D^2*w
     # k-means++ sampling — Spark 3.0 weightCol semantics
     predictionCol = Param("predictionCol", "output cluster-id column",
@@ -50,6 +84,11 @@ class KMeansParams(HasInputCol, HasDeviceId, HasWeightCol):
         True, validator=lambda v: isinstance(v, bool))
     dtype = Param("dtype", "device compute dtype", "auto",
                   validator=lambda v: v in ("auto", "float32", "float64"))
+    batchRows = Param(
+        "batchRows",
+        "rows per device batch of a streamed fit; 0 = auto-size so one "
+        "f32 batch is ~128 MiB",
+        0, validator=lambda v: isinstance(v, int) and v >= 0)
 
 
 class KMeans(KMeansParams):
@@ -69,14 +108,14 @@ class KMeans(KMeansParams):
     @observed_fit("kmeans")
     def fit(self, dataset) -> "KMeansModel":
         """Also accepts an out-of-core source: a zero-arg callable returning
-        an iterable of row chunks (re-iterable — Lloyd needs one pass per
-        iteration); seeding runs k-means++ on a reservoir sample."""
+        an iterable of row chunks (re-iterable — Lloyd walks the rows once
+        a step, over the batches kept on the chip where it can)."""
         timer = PhaseTimer()
         k = self.getK()
 
         from spark_rapids_ml_tpu.data.batches import streaming_source
 
-        source = streaming_source(dataset, 0)
+        source = streaming_source(dataset, self.getBatchRows())
         weights = None
         if source is not None:
             self._reject_streamed_weights()
@@ -92,7 +131,7 @@ class KMeans(KMeansParams):
 
             if (self.getUseXlaDot() and weights is None
                     and x.nbytes > stream_threshold_bytes()):
-                source = BatchSource(x)
+                source = BatchSource(x, batch_rows=self.getBatchRows())
 
         if source is not None:
             if not source.reiterable:
@@ -101,23 +140,39 @@ class KMeans(KMeansParams):
                     "zero-arg callable returning a fresh chunk iterator): "
                     "Lloyd makes one pass per iteration"
                 )
-            centers, cost, n_iter = self._fit_streamed(source, k, timer)
+            rng = np.random.default_rng(self.getSeed())
+            init = (_RandomRows if self.getInitMode() == "random"
+                    else _SampledPlusPlus)(k, source.n_features, rng)
+            fit_streamed = (self._streamed_lloyd_xla if self.getUseXlaDot()
+                            else self._streamed_lloyd_host)
+            centers, cost, n_iter, initial = fit_streamed(source, init, timer)
         else:
             if k > x.shape[0]:
                 raise ValueError(
                     f"k = {k} must be at most the number of rows {x.shape[0]}"
                 )
             if self.getUseXlaDot():
-                centers, cost, n_iter = self._fit_xla(x, k, timer, weights)
+                centers, cost, n_iter, initial = self._fit_xla(
+                    x, k, timer, weights)
             else:
-                centers, cost, n_iter = self._fit_host(x, k, timer, weights)
+                centers, cost, n_iter, initial = self._fit_host(
+                    x, k, timer, weights)
         model = KMeansModel(cluster_centers=np.asarray(centers, dtype=np.float64))
         model.uid = self.uid
         model.copy_values_from(self)
         model.training_cost_ = float(cost)
         model.n_iter_ = int(n_iter)
+        model.initial_centers_ = np.asarray(initial, dtype=np.float64)
         model.fit_timings_ = timer.as_dict()
         return model
+
+    def _random_centers(self, x, k, weights=None) -> np.ndarray:
+        """``initMode`` "random" over an in-memory matrix: the streamed
+        fit's draw (``_RandomRows``) over its rows with a positive weight,
+        as one batch."""
+        init = _RandomRows(k, x.shape[1], np.random.default_rng(self.getSeed()))
+        init.see(x if weights is None else x[np.asarray(weights) > 0], None)
+        return init.host_centers()
 
     def _fit_xla(self, x, k, timer, weights=None):
         import jax
@@ -142,83 +197,103 @@ class KMeans(KMeansParams):
             )
         key = jax.random.PRNGKey(self.getSeed())
         with timer.phase("fit_kernel"), TraceRange("kmeans lloyd", TraceColor.GREEN):
-            init = kmeans_plus_plus_init(x_dev, k, key, mask=w_dev)
+            if self.getInitMode() == "random":
+                init = jax.device_put(jnp.asarray(
+                    self._random_centers(x, k, weights), dtype=dtype), device)
+            else:
+                init = kmeans_plus_plus_init(x_dev, k, key, mask=w_dev)
             result = jax.block_until_ready(
                 kmeans_fit_kernel(
                     x_dev, init, mask=w_dev,
                     max_iter=self.getMaxIter(), tol=self.getTol()
                 )
             )
-        return result.centers, result.cost, result.n_iter
+        return result.centers, result.cost, result.n_iter, init
 
-    def _fit_streamed(self, source, k, timer):
-        """Out-of-core Lloyd: one streamed pass per iteration, per-batch
-        (Σx, count, cost) folded into an accumulator — a donated device
-        accumulator (``ops.kmeans_kernel.update_cluster_stats``) when
-        ``useXlaDot``, NumPy float64 otherwise. Seeding is k-means++ on a
-        uniform reservoir sample — the sample-then-stream shape of scalable
-        k-means variants. As on the other fit paths, the reported cost is
-        measured under the FINAL centers (one extra stats pass)."""
-        rng = np.random.default_rng(self.getSeed())
-        with timer.phase("seed"), TraceRange("kmeans seed", TraceColor.ORANGE):
-            sample = _reservoir_sample(source, max(4096, 8 * k), rng)
-            if k > sample.shape[0]:
-                raise ValueError(
-                    f"k = {k} must be at most the number of rows "
-                    f"{sample.shape[0]}"
-                )
-            centers = _host_kmeans_pp(np.asarray(sample, dtype=np.float64), k, rng)
-
-        if self.getUseXlaDot():
-            return self._streamed_lloyd_xla(source, centers, timer)
-        return self._streamed_lloyd_host(source, centers, timer)
-
-    def _streamed_lloyd_xla(self, source, centers, timer):
-        import jax
+    def _streamed_lloyd_xla(self, source, init, timer):
+        """Lloyd on the streamed loop. The first walk (``lloyd:init``) puts
+        every batch through ``IngestTrace.put`` and keeps it on the chip
+        while the budget has room — its reserve the step's own
+        (``ops.kmeans_kernel.step_reserve_bytes``), not a Gram loop's — and
+        ``init`` sees each batch, kept or not, to draw the initial centres.
+        Each step (``lloyd:step``) walks the kept batches without letting
+        them go and puts the rest again (``IngestTrace.replay(drain=False)``,
+        pass 1's deal), one ``update_cluster_stats`` a batch into a donated
+        (Σx, count, cost) accumulator, then ``next_centers`` and one host
+        read of the largest shift (``lloyd:sync``): the steps stop at
+        ``maxIter`` or when every centre moved ≤ ``tol``. The reported cost
+        is measured under the FINAL centers, by one more walk
+        (``lloyd:cost``), as on the other fit paths. Counters under
+        ``fit_report_.extra["lloyd"]``; the ingest's under ``["ingest"]``."""
         import jax.numpy as jnp
 
-        from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
-        from spark_rapids_ml_tpu.ops.kmeans_kernel import update_cluster_stats
+        from spark_rapids_ml_tpu.obs.report import current_fit
+        from spark_rapids_ml_tpu.ops import kmeans_kernel as kk
+        from spark_rapids_ml_tpu.ops.streaming import IngestTrace
 
         device = _resolve_device(self.getDeviceId())
         dtype = _resolve_dtype(self.getDtype())
-        k, n = centers.shape
-        centers_dev = jax.device_put(jnp.asarray(centers, dtype=dtype), device)
+        k, n = self.getK(), source.n_features
+        itemsize = jnp.dtype(dtype).itemsize
+        ingest = IngestTrace(timer, device)
+        counters = {"steps": 0, "batches_from_kept": 0,
+                    "batches_put_again": 0, "sync_seconds": 0.0}
+        current_fit().note(lloyd=counters)  # filled as the fit goes
+        ingest.allow_keep(kk.step_reserve_bytes(source.batch_rows, k, n,
+                                                itemsize))
 
-        def pass_stats(c_dev):
-            carry = jax.device_put(
-                (
-                    jnp.zeros((k, n), dtype=dtype),
-                    # int32 counts: exact past 2^24 rows per cluster
-                    jnp.zeros((k,), dtype=jnp.int32),
-                    jnp.zeros((), dtype=dtype),
-                ),
-                device,
-            )
+        def walk_stats(centers):
+            """(Σx, count, cost) of every row under ``centers``: one walk."""
+            carry = (jnp.zeros((k, n), dtype=dtype, device=device),
+                     # int32 counts: exact past 2^24 rows per cluster
+                     jnp.zeros((k,), dtype=jnp.int32, device=device),
+                     jnp.zeros((), dtype=dtype, device=device))
+            put_before, stepped = ingest.counters["batches"], 0
+            for _, x_dev, m_dev in ingest.replay(source, dtype, drain=False):
+                with ingest.stage(SPAN_DISPATCH, PHASE_DISPATCH):
+                    carry = kk.update_cluster_stats(carry, centers, x_dev,
+                                                    m_dev)
+                stepped += 1
+            put_again = ingest.counters["batches"] - put_before
+            counters["batches_put_again"] += put_again
+            counters["batches_from_kept"] += stepped - put_again
+            return carry
+
+        try:
+            with timer.phase("fit_kernel"):
+                with timer.phase(PHASE_INIT), ingest.walk(SPAN_INIT):
+                    for batch, mask in ingest.batches(source):
+                        c, x_dev, m_dev = ingest.put(batch, mask, dtype)
+                        kept = ingest.keep(c, x_dev, m_dev)
+                        init.see(batch, mask,
+                                 len(ingest.kept) - 1 if kept else -1)
+                    centers = init.device_centers(ingest, dtype, device)
+                initial = centers
+                for _ in range(self.getMaxIter()):
+                    with ingest.walk(SPAN_STEP):
+                        centers, shift = kk.next_centers(
+                            walk_stats(centers), centers)
+                        with ingest.stage(SPAN_SYNC, PHASE_SYNC):
+                            moved = float(shift)
+                    counters["steps"] += 1
+                    counters["sync_seconds"] = timer.as_dict()[PHASE_SYNC]
+                    if moved <= self.getTol():
+                        break
+                with timer.phase(PHASE_COST), ingest.walk(SPAN_COST):
+                    cost = float(walk_stats(centers)[2])
+        finally:
+            ingest.release()  # no device batch outlives the walks over it
+        ingest.all_landed()  # the cost read every batch put
+        ingest.set_data(n)
+        return (np.asarray(centers), cost, counters["steps"],
+                np.asarray(initial))
+
+    def _streamed_lloyd_host(self, source, init, timer):
+        with timer.phase("seed"), TraceRange("kmeans seed", TraceColor.ORANGE):
             for batch, mask in source.batches():
-                carry = update_cluster_stats(
-                    carry, c_dev, jnp.asarray(batch, dtype=dtype),
-                    None if mask is None else jnp.asarray(mask))
-            return jax.block_until_ready(carry)
-
-        n_iter = 0
-        with timer.phase("fit_kernel"), TraceRange("kmeans streamed", TraceColor.GREEN):
-            for n_iter in range(1, self.getMaxIter() + 1):
-                sums, counts, _ = pass_stats(centers_dev)
-                safe = jnp.maximum(counts, 1).astype(dtype)[:, None]
-                new_centers = jnp.where(
-                    counts[:, None] > 0, sums / safe, centers_dev
-                )
-                moved = float(jnp.sqrt(
-                    jnp.max(jnp.sum((new_centers - centers_dev) ** 2, axis=1))
-                ))
-                centers_dev = new_centers
-                if moved <= self.getTol():
-                    break
-            _, _, cost_dev = pass_stats(centers_dev)
-        return np.asarray(centers_dev), float(cost_dev), n_iter
-
-    def _streamed_lloyd_host(self, source, centers, timer):
+                init.see(batch, mask)
+            centers = init.host_centers()
+        initial = centers
         k, n = centers.shape
 
         def pass_stats(c):
@@ -251,14 +326,18 @@ class KMeans(KMeansParams):
                 if moved <= self.getTol():
                     break
             _, _, cost = pass_stats(centers)
-        return centers, cost, n_iter
+        return centers, cost, n_iter, initial
 
     def _fit_host(self, x, k, timer, weights=None):
         """NumPy Lloyd with the same init/update/empty-cluster semantics."""
         rng = np.random.default_rng(self.getSeed())
         w = np.ones(x.shape[0]) if weights is None else weights
         with timer.phase("fit_kernel"), TraceRange("kmeans host", TraceColor.ORANGE):
-            centers = _host_kmeans_pp(x, k, rng, weights=weights)
+            if self.getInitMode() == "random":
+                centers = self._random_centers(x, k, weights)
+            else:
+                centers = _host_kmeans_pp(x, k, rng, weights=weights)
+            initial = centers
             n_iter = 0
             for n_iter in range(1, self.getMaxIter() + 1):
                 d = _sqdist(x, centers)
@@ -276,7 +355,7 @@ class KMeans(KMeansParams):
                 if moved <= self.getTol():
                     break
             cost = (_sqdist(x, centers).min(axis=1) * w).sum()
-        return centers, cost, n_iter
+        return centers, cost, n_iter, initial
 
 
 def _sqdist(x, centers):
@@ -285,37 +364,116 @@ def _sqdist(x, centers):
     return np.maximum(x2 + c2 - 2.0 * (x @ centers.T), 0.0)
 
 
-def _reservoir_sample(source, size: int, rng) -> np.ndarray:
-    """Uniform-ish sample of up to ``size`` rows in one streamed pass.
+def _valid_rows(batch, mask) -> int:
+    """Rows of a ``BatchSource`` batch that are data: all of them, or the
+    head its mask marks (only a padded tail has one)."""
+    return batch.shape[0] if mask is None else int(np.count_nonzero(mask))
 
-    Vectorized batch reservoir: row t (0-based global index) replaces a
-    random slot with probability size/(t+1) — per-batch vectorization of
-    Algorithm R, accepted approximation for seeding purposes."""
-    reservoir = None
-    filled = 0
-    seen = 0
-    for batch, mask in source.batches():
+
+class _RandomRows:
+    """``initMode`` "random", the one draw of every fit path: k distinct
+    rows drawn uniformly by ``seed`` while the rows are walked once —
+    Algorithm R over the row indices, one draw a row, a batch at a time (an
+    in-memory matrix is one batch) — so no walk is made for it. A row of a
+    batch a streamed fit keeps on the chip is remembered by
+    where it is and gathered there (``ops.kmeans_kernel.take_rows``); any
+    other is copied from the host batch while the walk has it."""
+
+    def __init__(self, k: int, n_features: int, rng):
+        self.k, self.rng, self.seen = k, rng, 0
+        self.host = np.zeros((k, n_features))
+        self.kept = np.full(k, -1)  # the kept batch a slot's row is in
+        self.row = np.zeros(k, dtype=np.int32)  # its row there
+
+    def see(self, batch, mask, kept: int = -1) -> None:
+        """The walk's next batch; ``kept``: its index among the batches the
+        fit keeps, -1 where it keeps it not."""
+        valid = _valid_rows(batch, mask)
+        t = self.seen + np.arange(valid)
+        slot = np.where(t < self.k, t, self.rng.integers(0, t + 1))
+        hit = np.flatnonzero(slot < self.k)
+        # a later row of the batch takes a slot over from an earlier one
+        slots, last = np.unique(slot[hit][::-1], return_index=True)
+        rows = hit[::-1][last]
+        if kept < 0:
+            self.host[slots] = batch[rows]
+        self.kept[slots] = kept
+        self.row[slots] = rows
+        self.seen += valid
+
+    def _check(self) -> None:
+        if self.k > self.seen:
+            raise ValueError(
+                f"k = {self.k} must be at most the number of rows {self.seen}")
+
+    def host_centers(self) -> np.ndarray:
+        self._check()
+        return self.host
+
+    def device_centers(self, ingest, dtype, device):
+        import jax
+        import jax.numpy as jnp
+
+        from spark_rapids_ml_tpu.ops import kmeans_kernel as kk
+
+        self._check()
+        centers = jax.device_put(np.asarray(self.host, dtype=dtype), device)
+        rows = jax.device_put(self.row, device)
+        for b in np.unique(self.kept[self.kept >= 0]):
+            centers = kk.take_rows(centers, ingest.kept[b][3], rows,
+                                   jnp.asarray(self.kept == b, device=device))
+        return centers
+
+
+class _SampledPlusPlus:
+    """``initMode`` "k-means||" on a streamed source — the stand-in for
+    Spark's parallel k-means||: a uniform sample of up to max(4096, 8k)
+    rows drawn while the rows are walked once, then k-means++ over it on
+    the host in float64 (``_host_kmeans_pp``).
+
+    The sample is a vectorized batch reservoir: row t (0-based global
+    index) replaces a random slot with probability size/(t+1) — per-batch
+    vectorization of Algorithm R, accepted approximation for seeding
+    purposes."""
+
+    def __init__(self, k: int, n_features: int, rng):
+        self.k, self.rng = k, rng
+        self.size = max(4096, 8 * k)
+        self.reservoir = np.empty((self.size, n_features), dtype=np.float64)
+        self.filled = self.seen = 0
+
+    def see(self, batch, mask, kept: int = -1) -> None:
         rows = batch if mask is None else batch[mask]
-        if reservoir is None:
-            reservoir = np.empty((size, rows.shape[1]), dtype=np.float64)
-        take = min(size - filled, rows.shape[0])
+        take = min(self.size - self.filled, rows.shape[0])
         if take > 0:
-            reservoir[filled:filled + take] = rows[:take]
-            filled += take
-            seen += take
+            self.reservoir[self.filled:self.filled + take] = rows[:take]
+            self.filled += take
+            self.seen += take
             rows = rows[take:]
         if rows.shape[0] == 0:
-            continue
-        t = seen + np.arange(rows.shape[0])
-        keep = rng.random(rows.shape[0]) < size / (t + 1)
+            return
+        t = self.seen + np.arange(rows.shape[0])
+        keep = self.rng.random(rows.shape[0]) < self.size / (t + 1)
         idx = np.nonzero(keep)[0]
         if idx.size:
-            slots = rng.integers(0, size, size=idx.size)
-            reservoir[slots] = rows[idx]
-        seen += rows.shape[0]
-    if reservoir is None:
-        raise ValueError("empty dataset")
-    return reservoir[:filled] if filled < size else reservoir
+            slots = self.rng.integers(0, self.size, size=idx.size)
+            self.reservoir[slots] = rows[idx]
+        self.seen += rows.shape[0]
+
+    def host_centers(self) -> np.ndarray:
+        sample = self.reservoir[:self.filled]
+        if self.k > sample.shape[0]:
+            raise ValueError(
+                f"k = {self.k} must be at most the number of rows "
+                f"{sample.shape[0]}"
+            )
+        return _host_kmeans_pp(sample, self.k, self.rng)
+
+    def device_centers(self, ingest, dtype, device):
+        import jax
+
+        return jax.device_put(np.asarray(self.host_centers(), dtype=dtype),
+                              device)
 
 
 def _host_kmeans_pp(x, k, rng, weights=None):
@@ -343,12 +501,14 @@ class KMeansModel(KMeansParams):
         self.cluster_centers = cluster_centers
         self.training_cost_ = None
         self.n_iter_ = None
+        self.initial_centers_ = None
         self.fit_timings_ = {}
 
     def _copy_internal_state(self, other: "KMeansModel") -> None:
         other.cluster_centers = self.cluster_centers
         other.training_cost_ = self.training_cost_
         other.n_iter_ = self.n_iter_
+        other.initial_centers_ = self.initial_centers_
 
     # Spark API naming
     def clusterCenters(self):

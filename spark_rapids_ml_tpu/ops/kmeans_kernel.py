@@ -246,6 +246,41 @@ def update_cluster_stats(
     return sums + s, counts + c.astype(counts.dtype), cost + co
 
 
+@tracked_jit
+def next_centers(carry, centers: jnp.ndarray):
+    """The end of one streamed Lloyd step: (each centre the mean of the rows
+    the step gave it — an empty cluster keeps its centre —, the largest
+    distance a centre moved). One program, whose scalar the fit reads."""
+    sums, counts, _ = carry
+    new = jnp.where(counts[:, None] > 0,
+                    sums / jnp.maximum(counts, 1).astype(sums.dtype)[:, None],
+                    centers)
+    return new, jnp.sqrt(jnp.max(jnp.sum((new - centers) ** 2, axis=1)))
+
+
+@tracked_jit
+def take_rows(centers: jnp.ndarray, batch: jnp.ndarray, rows: jnp.ndarray,
+              take: jnp.ndarray) -> jnp.ndarray:
+    """``centers`` with each centre ``i`` where ``take[i]`` replaced by row
+    ``rows[i]`` of the device ``batch``: initial centres gathered from the
+    batches a streamed fit keeps on the chip. Fixed shapes (k,), whatever
+    how many rows a batch gives."""
+    return jnp.where(take[:, None], batch[rows].astype(centers.dtype),
+                     centers)
+
+
+def step_reserve_bytes(batch_rows: int, k: int, n_features: int,
+                       itemsize: int) -> int:
+    """Device bytes a streamed Lloyd step needs beside the batches a fit
+    keeps (``ops.streaming.keep_budget_bytes``' reserve): three batches
+    (two puts in flight and the one being stepped, where nothing is kept),
+    the (rows, k) distances and one-hot, should the compiler hold them (the
+    v5e's fuses them away), and four (k, n): the carry's sums, their
+    donated successor, the centres and the next centres."""
+    return itemsize * (3 * batch_rows * n_features + 2 * batch_rows * k
+                       + 4 * k * n_features)
+
+
 @partial(tracked_jit, static_argnames=("max_iter",))
 def kmeans_fit_kernel(
     x: jnp.ndarray,

@@ -363,20 +363,28 @@ def _boundary(span: str) -> str:
     return span.partition(":")[2]
 
 
-def keep_budget_bytes(device, batch_nbytes: int, gram_nbytes: int) -> int:
-    """Bytes of pass-1 device batches a two-pass fit may keep on ``device``
-    for pass 2: what ``memory_stats()`` reports free now, less what the loop
-    needs when it keeps nothing — three batches (one landing, one being
-    summed, the XLA Gram's centred copy of one) and three n×n (the
-    accumulator, a step's product before it is added, the normalised
-    covariance or, on several chips, the all-reduced Gram). 0 where the
-    backend reports no ``memory_stats()`` (the CPU): nothing is kept. Tests
-    patch this function to stand in for the chip."""
+def keep_budget_bytes(device, reserve_nbytes: int) -> int:
+    """Bytes of pass-1 device batches a fit may keep on ``device`` for its
+    later walks: what ``memory_stats()`` reports free now, less
+    ``reserve_nbytes``, what the consumer's loop needs when it keeps
+    nothing (a Gram loop's: ``gram_reserve_bytes``; a Lloyd step's:
+    ``ops.kmeans_kernel.step_reserve_bytes``). 0 where the backend reports
+    no ``memory_stats()`` (the CPU): nothing is kept. Tests patch this
+    function to stand in for the chip."""
     stats = device_memory_stats(device)
     if stats is None or not {"bytes_limit", "bytes_in_use"} <= set(stats):
         return 0
     free = int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
-    return max(0, free - 3 * batch_nbytes - 3 * gram_nbytes)
+    return max(0, free - reserve_nbytes)
+
+
+def gram_reserve_bytes(batch_nbytes: int, gram_nbytes: int) -> int:
+    """Device bytes the Gram loop needs beside the batches a fit keeps:
+    three batches (one landing, one being summed, the XLA Gram's centred
+    copy of one) and three n×n (the accumulator, a step's product before it
+    is added, the normalised covariance or, on several chips, the
+    all-reduced Gram)."""
+    return 3 * batch_nbytes + 3 * gram_nbytes
 
 
 # Puts a chip may have in flight: the batch crossing and the one being
@@ -772,40 +780,47 @@ class IngestTrace:
             counters["bytes_put"] += x.nbytes
         return c, x_dev, m_dev
 
-    def allow_keep(self, batch_nbytes: int, gram_nbytes: int) -> None:
-        """Size each chip's budget of ``keep`` from the chip as it is now."""
+    def allow_keep(self, reserve_nbytes: int) -> None:
+        """Size each chip's budget of ``keep`` from the chip as it is now,
+        less ``reserve_nbytes``: what the consumer's loop needs when it
+        keeps nothing (``keep_budget_bytes``)."""
         for chip in self.chips:
             chip.keep_room = keep_budget_bytes(chip.stats_device(),
-                                               batch_nbytes, gram_nbytes)
+                                               reserve_nbytes)
             chip.counters["keep_budget_bytes"] = chip.keep_room
         self.counters["keep_budget_bytes"] = sum(
             chip.keep_room for chip in self.chips)
 
-    def keep(self, c: int, x_dev, m_dev) -> None:
-        """Hold the device batch just put on chip ``c`` for pass 2 if the
-        chip's budget has room for it. Only a prefix of a chip's batches is
-        kept: the first that finds no room closes that chip's budget."""
+    def keep(self, c: int, x_dev, m_dev) -> bool:
+        """Hold the device batch just put on chip ``c`` for the later walks
+        if the chip's budget has room for it; whether it did. Only a prefix
+        of a chip's batches is kept: the first that finds no room closes
+        that chip's budget."""
         chip = self.chips[c]
         if x_dev.nbytes > chip.keep_room:
             chip.keep_room = 0
-            return
+            return False
         chip.keep_room -= x_dev.nbytes
         self.kept.append((self.turn - 1, c, self.put_rows, x_dev, m_dev))
         for counters in (self.counters, chip.counters):
             counters["batches_kept"] += 1
             counters["bytes_kept"] += x_dev.nbytes
+        return True
 
-    def replay(self, source, dtype):
-        """Pass 2's device batches, each with its chip: the kept ones from
-        the chips in pass 1's order — each reference dropped as it is handed
-        out, so HBM drains as the Gram steps run — then the rest of the
-        source, dealt as in pass 1 and put again. With everything kept the
+    def replay(self, source, dtype, drain: bool = True):
+        """A later walk's device batches, each with its chip: the kept ones
+        from the chips in pass 1's order, then the rest of the source, dealt
+        as in pass 1 and put again. ``drain`` (pass 2 of a Gram): each kept
+        reference is dropped as it is handed out, so HBM drains as the Gram
+        steps run. Otherwise the kept batches stay kept, for a consumer that
+        walks the rows once a step (a Lloyd fit). With everything kept the
         source is not walked; otherwise the kept host batches are passed
         over with their rows untouched."""
         kept_turns = {k[0] for k in self.kept}
         everything = len(kept_turns) == self.counters["batches"]  # pass 1's
-        while self.kept:
-            _, c, valid, x_dev, m_dev = self.kept.popleft()
+        kept = self.kept if drain else collections.deque(self.kept)
+        while kept:
+            _, c, valid, x_dev, m_dev = kept.popleft()
             self._count_rows(c, valid)
             yield c, x_dev, m_dev
         if everything:
@@ -1035,7 +1050,8 @@ def stream_covariance(
                             jnp.zeros((), dtype=jnp.int32, device=d))
                   for d in devices]
         itemsize = jnp.dtype(dtype).itemsize
-        ingest.allow_keep(source.batch_rows * n * itemsize, n * n * itemsize)
+        ingest.allow_keep(gram_reserve_bytes(source.batch_rows * n * itemsize,
+                                             n * n * itemsize))
 
         def new_grams():
             return [jnp.zeros((n, n), dtype=dtype, device=d) for d in devices]

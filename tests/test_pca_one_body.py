@@ -155,7 +155,7 @@ def test_matrix_rows_that_fit_the_chip_cross_once(monkeypatch):
     chip's (``tests/test_streaming_keep.py``): pass 1's batches of a
     matrix are kept and pass 2 puts nothing."""
     monkeypatch.setattr(streaming, "keep_budget_bytes",
-                        lambda device, batch_nbytes, gram_nbytes: 1 << 40)
+                        lambda device, reserve_nbytes: 1 << 40)
     x = _rows()
     model = PCA().setK(K).setBatchRows(64).fit(x)
     _assert_oracle(model, x)

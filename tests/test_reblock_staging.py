@@ -152,9 +152,13 @@ def chip(monkeypatch):
 
 
 def _keep(monkeypatch, batches: int) -> None:
-    monkeypatch.setattr(
-        streaming, "keep_budget_bytes",
-        lambda device, batch_nbytes, gram_nbytes: batches * batch_nbytes)
+    def rule(device, reserve_nbytes):
+        # the Gram loop's reserve: three (BATCH, N) batches and three N x N
+        # of the device's dtype
+        itemsize = reserve_nbytes // (3 * BATCH * N + 3 * N * N)
+        return batches * BATCH * N * itemsize
+
+    monkeypatch.setattr(streaming, "keep_budget_bytes", rule)
 
 
 # -- (a) lent again only after the wait on the put that read it ---------------

@@ -183,7 +183,7 @@ def _budgets(monkeypatch, by_chip: dict) -> None:
     (position among the local devices → bytes)."""
     local = jax.local_devices()
 
-    def rule(device, batch_nbytes, gram_nbytes):
+    def rule(device, reserve_nbytes):
         return by_chip[local.index(device)]
 
     monkeypatch.setattr(streaming, "keep_budget_bytes", rule)

@@ -82,8 +82,8 @@ def _budget(monkeypatch, nbytes: int) -> list:
     says. Returns the arguments the rule was asked with."""
     asked = []
 
-    def rule(device, batch_nbytes, gram_nbytes):
-        asked.append((batch_nbytes, gram_nbytes))
+    def rule(device, reserve_nbytes):
+        asked.append(reserve_nbytes)
         return nbytes
 
     monkeypatch.setattr(streaming, "keep_budget_bytes", rule)
@@ -116,7 +116,7 @@ def test_everything_kept_crosses_once(monkeypatch, n, rows, shift):
     assert kept[2] == sum(rows)
     per_pass = _batches_per_pass(rows)
     c = ingest.counters
-    assert asked == [(_batch_nbytes(n), n * n * 4)]
+    assert asked == [3 * _batch_nbytes(n) + 3 * n * n * 4]
     assert c["keep_budget_bytes"] == EVERYTHING
     assert c["batches_kept"] == per_pass
     assert c["bytes_kept"] == per_pass * _batch_nbytes(n)
@@ -359,15 +359,17 @@ GIB = 1 << 30
 ])
 def test_the_budget_is_what_is_free_less_what_the_loop_needs(
         stats, batch, gram, want):
-    assert streaming.keep_budget_bytes(_Device(stats), batch, gram) == want
+    assert streaming.keep_budget_bytes(
+        _Device(stats), streaming.gram_reserve_bytes(batch, gram)) == want
 
 
 def test_the_budget_leaves_three_batches_and_three_grams():
     device = _Device({"bytes_limit": 1000, "bytes_in_use": 100})
-    assert streaming.keep_budget_bytes(device, 100, 10) == 900 - 300 - 30
+    assert streaming.gram_reserve_bytes(100, 10) == 300 + 30
+    assert streaming.keep_budget_bytes(device, 330) == 900 - 300 - 30
     # and what is kept never passes it: 5 batches of 100 fit in 570
     ingest = streaming.IngestTrace()
-    ingest.chips[0].keep_room = streaming.keep_budget_bytes(device, 100, 10)
+    ingest.chips[0].keep_room = streaming.keep_budget_bytes(device, 330)
     batch = np.zeros((25,), dtype=np.float32)  # 100 bytes
     for _ in range(8):
         ingest.put_rows = 25
@@ -380,4 +382,4 @@ def test_the_budget_leaves_three_batches_and_three_grams():
 def test_the_real_cpu_device_gives_no_budget():
     import jax
 
-    assert streaming.keep_budget_bytes(jax.local_devices()[0], 1, 1) == 0
+    assert streaming.keep_budget_bytes(jax.local_devices()[0], 1) == 0

@@ -57,7 +57,7 @@ def _dataset(input_form: str, chunks: list):
 
 def _budget(monkeypatch, nbytes: int) -> None:
     monkeypatch.setattr(streaming, "keep_budget_bytes",
-                        lambda device, batch_nbytes, gram_nbytes: nbytes)
+                        lambda device, reserve_nbytes: nbytes)
 
 
 def _all_watchers() -> list:

@@ -249,7 +249,7 @@ class _Factory:
 
 def _budget(monkeypatch, nbytes: int) -> None:
     monkeypatch.setattr(streaming, "keep_budget_bytes",
-                        lambda device, batch_nbytes, gram_nbytes: nbytes)
+                        lambda device, reserve_nbytes: nbytes)
 
 
 @pytest.mark.parametrize("refused", [True, False], ids=["refused", "accepted"])

@@ -103,7 +103,7 @@ class _Log:
 
 def _budget(monkeypatch, nbytes: int) -> None:
     monkeypatch.setattr(streaming, "keep_budget_bytes",
-                        lambda device, batch_nbytes, gram_nbytes: nbytes)
+                        lambda device, reserve_nbytes: nbytes)
 
 
 def _stream(dataset, chips=None, **kwargs):
