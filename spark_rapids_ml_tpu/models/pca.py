@@ -151,7 +151,8 @@ class PCAParams(HasInputCol, HasOutputCol, HasDeviceId):
         "how many of the process's local chips a fit may use: the "
         "chip deviceId resolves to and the next numDevices-1 local ones "
         "(the in-process form of spark.executor.resource.tpu.amount). "
-        "Host batches are dealt to the chips whole and in turn, each chip "
+        "The slices that cross (a host batch over 1 GiB crosses in equal "
+        "row slices) are dealt to the chips in turn, each chip "
         "accumulates its own with the one-chip programs, and two "
         "all-reduces join them (ops.streaming.stream_covariance). "
         "1 (default) = the one chip deviceId names, whatever the host has.",

@@ -394,7 +394,37 @@ def gram_reserve_bytes(batch_nbytes: int, gram_nbytes: int) -> int:
 # front every accumulate step ran behind the LAST landing; with two, step i
 # runs at landing i+1, under crossing i+2. One batch's re-tiling (0.06 s at
 # 4096) fits inside one crossing (0.15 s), so two keep the link full.
+# They count the slices that cross (``PUT_BYTES_MAX``), not host batches.
 PUTS_IN_FLIGHT = 2
+
+# The most bytes one put hands the link. A host batch is the source's unit
+# of re-blocking and host copy; what crosses is a row slice of it. The
+# runtime re-tiles a put before it joins the link's queue, with the link
+# idle ahead of a fit's first put, and a step runs only once every
+# transfer queued before its batch's landing has landed, so the last
+# ``PUTS_IN_FLIGHT`` puts' steps run after the last landing: both the head
+# and the tail of a crossing scale with the rows of one put, not with
+# ``batchRows`` (v5e traces, ``PERF.md`` §5). 1 GiB is two slices of the
+# 4096-wide cells' 2 GiB batches; the estimators' default 128 MiB batches
+# cross whole. Not a Param: the cut follows the batch's bytes.
+PUT_BYTES_MAX = 1 << 30
+
+
+def put_slices(rows: int, nbytes: int) -> int:
+    """Into how many equal row slices a host batch of ``rows`` rows and
+    ``nbytes`` bytes is cut for the link: ⌈nbytes ÷ ``PUT_BYTES_MAX``⌉, or
+    1 (the batch whole) where that many do not cut its rows evenly, or
+    would cut a batch whose rows are a multiple of the Pallas row block
+    into slices that are not (``accumulate_path`` would then leave the
+    kernel for the XLA Gram)."""
+    from spark_rapids_ml_tpu.ops.pallas_gram import _BLOCK_R
+
+    pieces = -(-nbytes // PUT_BYTES_MAX)
+    if pieces <= 1 or rows % pieces:
+        return 1
+    if rows % _BLOCK_R == 0 and (rows // pieces) % _BLOCK_R:
+        return 1
+    return pieces
 
 
 def wait_for_landing(x_dev) -> None:
@@ -566,8 +596,8 @@ class _Chip:
     def __init__(self, device):
         self.device = device  # None = JAX's default device, uncommitted
         self.keep_room = 0  # bytes of the chip's budget not taken yet
-        # (device batch, the staging buffer it was put from or None) of the
-        # puts here not yet waited for, oldest first: at most
+        # (device batch, the staging buffer its slice was put from or None)
+        # of the puts here not yet waited for, oldest first: at most
         # ``PUTS_IN_FLIGHT``
         self.in_flight = collections.deque()
         self.counters = {
@@ -587,18 +617,23 @@ class _Chip:
 class IngestTrace:
     """What one streamed fit tells about its ingest: the spans above, the
     ``covariance/*`` seconds summed in ``timer``, and the counters that
-    reach ``fit_report_.extra["ingest"]``. It deals the host batches to the
-    fit's chips (``device``: one, or a sequence of them) whole and in turn,
-    and holds the device batches a two-pass fit keeps from pass 1 for
-    pass 2 (``keep`` / ``replay``), each chip under its own budget. A chip
-    has at most ``PUTS_IN_FLIGHT`` (two) puts in flight: its third waits for
-    its first to land, because a landed batch's step starts only after the
+    reach ``fit_report_.extra["ingest"]``. It cuts each host batch the
+    source yields into the row slices that cross the link (``batches``,
+    ``put_slices``: a batch over ``PUT_BYTES_MAX`` crosses in equal
+    slices, views each with its slice of the mask) and from there on a
+    slice is what every consumer calls a batch: it deals them to the fit's
+    chips (``device``: one, or a sequence of them) whole and in turn, and
+    holds the device batches a two-pass fit keeps from pass 1 for pass 2
+    (``keep`` / ``replay``), each chip under its own budget. A chip has at
+    most ``PUTS_IN_FLIGHT`` (two) puts in flight: its third waits for its
+    first to land, because a landed batch's step starts only after the
     transfers queued behind it (v5e traces: behind the fit's last landing
     when all puts went out at once).
     It also lends the source the arrays it assembles copied batches into
     (``staging``, out of the process's ``STAGING`` pool) and alone says when
-    one is free again: when the put that read it is seen to land —
-    ``_await_window``'s wait, or ``all_landed`` for the window's last —
+    one is free again: when every slice of it is seen to land —
+    ``_await_window``'s wait, or ``all_landed`` for the window's last — or
+    was never put (passed over, or cast into a new array by ``put``),
     never sooner and never on a guess; a fit that dies drops what it has
     not seen land. With a copy of batch *i* + 2 assembled while puts *i*
     and *i* + 1 are in flight, a chip cycles ``PUTS_IN_FLIGHT`` + 1.
@@ -621,14 +656,22 @@ class IngestTrace:
         self.itemsize = 0
         # (turn, chip index, valid rows, x_dev, m_dev) of pass 1, in order
         self.kept = collections.deque()
-        # staging buffers: the one lent to the source and not put yet, and
-        # those whose puts were still in flight at ``release``
+        # staging buffers: the one lent to the source and not yielded yet;
+        # id → [buffer, its slices not yet seen landed or passed over] of
+        # those yielded; (the slice just yielded, its buffer) until it is
+        # put or passed over; those whose puts were still in flight at
+        # ``release``, one entry a slice
         self._assembling: Optional[np.ndarray] = None
+        self._unsettled: dict = {}
+        self._lent: Optional[tuple] = None
         self._unlanded: list = []
         self._staging_most = (PUTS_IN_FLIGHT + 1) * len(self.chips)
         self._first_put: Optional[float] = None  # when it started
         self.counters = {
-            "passes": 0, "batches": 0, "rows_put": 0, "bytes_put": 0,
+            # ``batches``: puts, i.e. slices; ``host_batches``: the batches
+            # the source yielded in the fit's walks
+            "passes": 0, "batches": 0, "host_batches": 0, "rows_put": 0,
+            "bytes_put": 0,
             # counted by the source as it is walked (``staging_*``: by
             # ``staging``, which the source asks)
             **SOURCE_COUNTERS,
@@ -637,8 +680,10 @@ class IngestTrace:
             "put_seconds_max": 0.0, "sync_seconds_max": 0.0,
             "puts_in_flight_max": 0, "put_waits": 0, "put_wait_seconds": 0.0,
             # the fullest chip's, at ``all_landed`` (``LANDING_COUNTERS``
-            # are each chip's own, in ``per_chip``)
-            "crossing_seconds": 0.0,
+            # are each chip's own, in ``per_chip``); and from the fit's last
+            # landing to that call: the device work and reads the link
+            # left behind it
+            "crossing_seconds": 0.0, "landing_tail_seconds": 0.0,
             "hbm_bytes_in_use": {},
             "chips": len(self.chips), "collective_bytes": {},
             "per_chip": [chip.counters for chip in self.chips],
@@ -692,7 +737,6 @@ class IngestTrace:
     def staging(self, shape, dtype) -> np.ndarray:
         """The array the source assembles its next copied batch into: one
         the pool had (its pages touched) or, failing that, a new one."""
-        self._free_unput()
         buffer, reused = STAGING.lend(shape, dtype, self._staging_most)
         self.counters["staging_reused" if reused else "staging_fresh"] += 1
         self._assembling = buffer
@@ -703,12 +747,28 @@ class IngestTrace:
             STAGING.take_back(buffer, self._staging_most)
 
     def _free_unput(self) -> None:
-        """A buffer lent and never put (a kept turn passed over in
-        ``replay``) was read by nobody: free when the source moves on."""
+        """A buffer lent and never yielded was read by nobody."""
         self._take_back(self._assembling)
         self._assembling = None
 
+    def _settle(self, buffer: Optional[np.ndarray]) -> None:
+        """One slice of ``buffer`` is read by nobody any more (seen to land,
+        passed over, or cast into a new array): the buffer goes back to the
+        pool with its last."""
+        entry = None if buffer is None else self._unsettled.get(id(buffer))
+        if entry is None:
+            return
+        entry[1] -= 1
+        if not entry[1]:
+            del self._unsettled[id(buffer)]
+            self._take_back(buffer)
+
     def batches(self, source):
+        """The slices that cross the link, as (rows, mask) pairs: each host
+        batch of ``source.batches()`` cut by ``put_slices`` into views, each
+        with its slice of the mask (one slice, the batch itself, at or
+        under ``PUT_BYTES_MAX``). A slice of a staging buffer that the
+        consumer asks past without putting it was read by nobody."""
         # the source reports its reads, copies and counts here
         # (``BatchSource.batches``; a stand-in that does not is left alone)
         source.trace = self
@@ -718,7 +778,23 @@ class IngestTrace:
                 item = next(batches, None)
             if item is None:
                 return
-            yield item
+            batch, mask = item
+            self.counters["host_batches"] += 1
+            staged, self._assembling = self._assembling, None
+            rows = batch.shape[0]
+            pieces = put_slices(rows, batch.nbytes)
+            if staged is not None:
+                self._unsettled[id(staged)] = [staged, pieces]
+            step = rows // pieces
+            cuts = [(batch, mask)] if pieces == 1 else [
+                (batch[s:s + step], None if mask is None else mask[s:s + step])
+                for s in range(0, rows, step)]
+            for piece, piece_mask in cuts:
+                self._lent = None if staged is None else (piece, staged)
+                yield piece, piece_mask
+                if self._lent is not None:  # not put: passed over
+                    self._settle(self._lent[1])
+                    self._lent = None
 
     def _count_rows(self, c: int, valid: int) -> None:
         self.pass_rows += valid
@@ -727,13 +803,13 @@ class IngestTrace:
     def _await_window(self, chip: _Chip) -> None:
         """Before a put that would be ``chip``'s third in flight: wait for
         its oldest to land, and let go of it — its staging buffer, if it
-        had one, back to the pool."""
+        had one, back to the pool once its last slice has landed."""
         if len(chip.in_flight) < PUTS_IN_FLIGHT:
             return
         t0 = time.perf_counter()
         x_dev, staged = chip.in_flight.popleft()
         wait_for_landing(x_dev)
-        self._take_back(staged)
+        self._settle(staged)
         seconds = time.perf_counter() - t0
         for counters in (self.counters, chip.counters):
             counters["put_waits"] += 1
@@ -759,14 +835,17 @@ class IngestTrace:
         chip.watcher.fifo.put((x_dev, x.nbytes, self.counters["batches"],
                                self._first_put, obs_spans.current_trace_id(),
                                chip.counters))
-        staged, self._assembling = self._assembling, None
-        if staged is not x:
-            # not the batch lent for (passed over), or a cast made ``x``
-            # another array: nobody reads the lent one
-            self._take_back(staged)
-            staged = None
-        elif not put_copies(chip.stats_device()):
-            staged = None  # the device array's from now on
+        staged = None
+        if self._lent is not None and self._lent[0] is batch:
+            staged, self._lent = self._lent[1], None
+            if x is not batch:
+                # a cast made ``x`` another array: nobody reads the slice
+                self._settle(staged)
+                staged = None
+            elif not put_copies(chip.stats_device()):
+                # the device array's from now on: never lent again
+                self._unsettled.pop(id(staged), None)
+                staged = None
         chip.in_flight.append((x_dev, staged))
         for counters in (self.counters, chip.counters):
             counters["puts_in_flight_max"] = max(
@@ -850,9 +929,12 @@ class IngestTrace:
         covariance, the solve's result): the buffers ``release`` held back
         are free, and the landing watchers have seen the fit's last
         landing — from here on none works for this fit, and its landing
-        counters and ``covariance/crossing`` are final."""
+        counters and ``covariance/crossing`` are final. ``landing_tail_seconds``
+        is from the fit's last landing (the latest chip's, on the
+        watchers' clock) to this call."""
+        called = time.perf_counter()
         for staged in self._unlanded:
-            self._take_back(staged)
+            self._settle(staged)
         self._unlanded = []
         for seen in [chip.watcher.fence() for chip in self.chips
                      if chip.watcher is not None]:
@@ -863,6 +945,11 @@ class IngestTrace:
         self.timer.add(PHASE_CROSSING,
                        fullest - self.counters["crossing_seconds"])
         self.counters["crossing_seconds"] = fullest
+        if self._first_put is not None:
+            last = max(chip.counters["last_landing_seconds"]
+                       for chip in self.chips)
+            self.counters["landing_tail_seconds"] = \
+                called - (self._first_put + last)
 
     def accumulate(self, path: str):
         self.counters["accumulate_calls"][path] += 1
@@ -1019,13 +1106,17 @@ def stream_covariance(
     show a landed batch's step starting only after every transfer queued
     behind it, so a loop that issues all its puts at once runs every step
     after the last landing, and one that issues two runs step i under
-    crossing i+2.
+    crossing i+2. What crosses, and what each step sums, is a row slice of
+    a host batch (``IngestTrace.batches``: equal slices of at most
+    ``PUT_BYTES_MAX``), so that the steps left behind the last landing and
+    the first put's re-tiling are those of a slice.
     A batch the source assembles by a host copy is written into a staging
     buffer lent by the ``IngestTrace`` out of the process's ``STAGING`` pool
-    and taken back when its put has landed (``StagingPool``): the process
-    keeps up to (``PUTS_IN_FLIGHT`` + 1) × chips such buffers between fits.
-    ``device`` is one chip or a sequence of them. Over several, the host
-    batches are dealt to the chips whole and in turn; each chip sums its own
+    and taken back when the puts of all its slices have landed
+    (``StagingPool``): the process keeps up to (``PUTS_IN_FLIGHT`` + 1) ×
+    chips such buffers between fits.
+    ``device`` is one chip or a sequence of them. Over several, the slices
+    are dealt to the chips in turn; each chip sums its own
     with the programs the one-chip fit runs (about its own first batch's
     mean), keeps its own batches under its own budget, and the chips meet in
     two all-reduces: after pass 1 the column sums and counts (every chip gets
@@ -1063,15 +1154,19 @@ def stream_covariance(
             return grams[0]
 
         grams = new_grams()
-        shifts = [None] * len(devices)  # a chip's c: its first batch's mean
+        # a chip's c: the mean of its first batch with a row (0 before it:
+        # a slice of a padded tail may hold padding only)
+        shifts = [jnp.zeros((n,), dtype=dtype, device=d) for d in devices]
+        shifted = [False] * len(devices)
         try:
             with ingest.walk(SPAN_PASS_MEAN):
                 for batch, mask in ingest.batches(source):
                     c, x_dev, m_dev = ingest.put(batch, mask, dtype)
                     with ingest.accumulate("mean"):
                         mstats[c] = update_mean_stats(mstats[c], x_dev, m_dev)
-                    if shifts[c] is None:
+                    if not shifted[c] and ingest.put_rows:
                         shifts[c] = mstats[c].col_sum / mstats[c].count
+                        shifted[c] = True
                     with ingest.accumulate(
                             accumulate_path(grams[c], x_dev, m_dev)):
                         grams[c] = update_centered_gram_auto(
@@ -1085,8 +1180,7 @@ def stream_covariance(
                 means = [mstats[0].col_sum / count]
             ratios = []
             for c, stats in enumerate(mstats):
-                if shifts[c] is None:  # a chip no batch came to: G = 0
-                    shifts[c] = jnp.zeros_like(stats.col_sum)
+                # (a chip no row came to: G = 0 about c = 0)
                 grams[c], ratio = recentre_gram(
                     grams[c], stats.col_sum, stats.count, shifts[c], means[c])
                 ratios.append(ratio)

@@ -3,8 +3,9 @@
 The north-star config (BASELINE.md #4: 10M×4096 over a multi-chip slice)
 needs BOTH halves at once: rows too many for host/HBM (stream them) and
 chips to spread them over (shard them). There is one streamed fit body,
-``ops.streaming.stream_covariance``, and it takes the chips: host batches
-are dealt to them whole and in turn, each chip folds its own into its own
+``ops.streaming.stream_covariance``, and it takes the chips: the slices
+that cross (host batches, cut at 1 GiB) are dealt to them in turn, each
+chip folds its own into its own
 accumulator with the one-chip programs (NO collective per batch; the
 reference's analogue shipped one n×n partial per partition to the driver,
 ``RapidsRowMatrix.scala:168-202``), and the chips meet in all-reduces over
