@@ -107,6 +107,33 @@ def columnar_chunks(input_col: Optional[str] = None):
 _EXHAUSTED = object()  # what a source's iterator gives when it has no more
 
 
+class ColumnBlocks:
+    """A chunk whose rows are side-by-side column blocks of one height —
+    a labelled chunk's X and y — joined only where a batch is written
+    (``BatchSource``): the join is the re-blocking's one host copy into
+    the batch, never a copy of the whole chunk first. ``viewed``: the
+    blocks are the caller's arrays or views of them."""
+
+    def __init__(self, blocks, dtype, viewed: bool = True):
+        self.blocks = tuple(blocks)
+        self.dtype = np.dtype(dtype)
+        self.viewed = viewed
+        self.shape = (self.blocks[0].shape[0],
+                      sum(block.shape[1] for block in self.blocks))
+
+    def __getitem__(self, rows: slice) -> "ColumnBlocks":
+        return ColumnBlocks([block[rows] for block in self.blocks],
+                            self.dtype, self.viewed)
+
+    def write_into(self, out: np.ndarray) -> None:
+        """The joined rows into ``out`` (this chunk's shape), cast to its
+        dtype."""
+        col = 0
+        for block in self.blocks:
+            out[:, col:col + block.shape[1]] = block
+            col += block.shape[1]
+
+
 def _as_chunk(chunk) -> np.ndarray:
     arr = np.asarray(chunk)
     if arr.ndim == 1:
@@ -165,7 +192,9 @@ class BatchSource:
     ``batch_rows`` buckets. Every yielded batch has shape
     ``(batch_rows, n_features)``; the final bucket is zero-padded with
     ``mask`` marking valid rows (``mask is None`` for full buckets — the
-    jitted accumulators trace the mask-free fast path for those).
+    jitted accumulators trace the mask-free fast path for those). A chunk
+    a ``chunk_transform`` gives as ``ColumnBlocks`` (a labelled chunk's X
+    and y) is joined into each batch by that batch's one host copy.
     """
 
     def __init__(self, source, batch_rows: int = 0,
@@ -250,6 +279,8 @@ class BatchSource:
         raw = chunk
         if self._transform is not None:
             chunk = self._transform(chunk)
+        if isinstance(chunk, ColumnBlocks):
+            return chunk, chunk.viewed
         arr = _as_chunk(chunk)
         return arr, arr is raw or not arr.flags.owndata or arr.size == 0
 
@@ -292,10 +323,30 @@ class BatchSource:
         """A ``(batch_rows, n_features)`` array of the trace's giving with
         ``pieces`` (``rows`` rows in all) written into its head, in the
         dtype ``np.concatenate`` would give them; rows past ``rows`` are
-        the caller's to fill."""
+        the caller's to fill. A ``ColumnBlocks`` piece is joined as it is
+        written."""
         dtype = np.result_type(*(piece.dtype for piece in pieces))
         batch = trace.staging((self.batch_rows, self.n_features), dtype)
-        np.concatenate(pieces, axis=0, out=batch[:rows])
+        if not any(isinstance(piece, ColumnBlocks) for piece in pieces):
+            np.concatenate(pieces, axis=0, out=batch[:rows])
+            return batch
+        start = 0
+        for piece in pieces:
+            out = batch[start:start + piece.shape[0]]
+            if isinstance(piece, ColumnBlocks):
+                piece.write_into(out)
+            else:
+                out[...] = piece
+            start += piece.shape[0]
+        return batch
+
+    def _copied(self, pieces: list, trace) -> np.ndarray:
+        """A full batch assembled from ``pieces`` by one host copy, counted
+        and inside the trace's ``copy`` stage."""
+        with trace.next_stage("copy"):
+            batch = self._join(pieces, self.batch_rows, trace)
+        trace.counters["batches_copied"] += 1
+        trace.counters["bytes_reblocked"] += batch.nbytes
         return batch
 
     def batches(self) -> Iterator[Tuple[np.ndarray, Optional[np.ndarray]]]:
@@ -340,15 +391,17 @@ class BatchSource:
                 carry_rows += take
                 start = take
                 if carry_rows == b:
-                    with trace.next_stage("copy"):
-                        batch = self._join(carry, b, trace)
-                    counters["batches_copied"] += 1
-                    counters["bytes_reblocked"] += batch.nbytes
-                    yield batch, None
+                    yield self._copied(carry, trace), None
                     carry, carry_rows = [], 0
             while chunk.shape[0] - start >= b:
-                counters["batches_viewed"] += 1
-                yield chunk[start:start + b], None
+                # a slice of a whole chunk leaves the rows where they are;
+                # one of column blocks is joined into a batch here
+                piece = chunk[start:start + b]
+                if isinstance(piece, ColumnBlocks):
+                    piece = self._copied([piece], trace)
+                else:
+                    counters["batches_viewed"] += 1
+                yield piece, None
                 start += b
             if start < chunk.shape[0]:
                 carry.append(chunk[start:])

@@ -305,13 +305,19 @@ def _extract_weights(est, frame, n_rows):
     return est._extract_weights(frame, n_rows)
 
 
-def _zip_xy(chunk) -> np.ndarray:
-    """(X_chunk, y_chunk) → Z_chunk = [X | y]."""
+def _zip_xy(chunk):
+    """(X_chunk, y_chunk) → the chunk Z = [X | y] as column blocks: each
+    batch of it is joined by the source's one host copy into the batch
+    (``data.batches.ColumnBlocks``), not by a copy of the whole chunk."""
+    from spark_rapids_ml_tpu.data.batches import ColumnBlocks
+
     if not (isinstance(chunk, tuple) and len(chunk) == 2):
         raise ValueError(
             "streamed LinearRegression chunks must be (X, y) tuples"
         )
     x, y = chunk
+    # arrays are the caller's rows as they are; a list is densified here
+    viewed = isinstance(x, np.ndarray) and isinstance(y, np.ndarray)
     x = np.asarray(x)
     if x.ndim == 1:
         x = x[None, :]
@@ -319,17 +325,16 @@ def _zip_xy(chunk) -> np.ndarray:
     # Promote to a common float dtype (at least f32) — casting y to x's
     # dtype would silently floor float labels when X chunks are integer.
     dt = np.promote_types(np.result_type(x.dtype, y.dtype), np.float32)
-    x = x.astype(dt, copy=False)
-    y = y.astype(dt, copy=False).reshape(-1, 1)
-    if y.shape[0] != x.shape[0]:
+    if y.size != x.shape[0]:
         raise ValueError(
-            f"chunk labels length {y.shape[0]} != chunk rows {x.shape[0]}"
+            f"chunk labels length {y.size} != chunk rows {x.shape[0]}"
         )
-    return np.concatenate([x, y], axis=1)
+    return ColumnBlocks((x, y.reshape(-1, 1)), dt, viewed)
 
 
-def _streaming_xy_source(dataset, labels):
-    """BatchSource over Z=[X|y] for generator/callable inputs, else None.
+def _streaming_xy_source(dataset, labels, batch_rows: int = 0):
+    """BatchSource over Z=[X|y] for generator/callable inputs, else None;
+    ``batch_rows`` rows a batch (0: auto-sized, ≈128 MiB).
 
     The user's callable/iterator goes to BatchSource UNWRAPPED (``_zip_xy``
     rides along as ``chunk_transform``) so the non-fresh-factory detection
@@ -337,7 +342,8 @@ def _streaming_xy_source(dataset, labels):
     from spark_rapids_ml_tpu.data.batches import BatchSource
 
     if labels is None and (callable(dataset) or hasattr(dataset, "__next__")):
-        return BatchSource(dataset, batch_rows=0, chunk_transform=_zip_xy)
+        return BatchSource(dataset, batch_rows=batch_rows,
+                           chunk_transform=_zip_xy)
     return None
 
 

@@ -6,12 +6,16 @@ probabilityCol, maxIter, tol, regParam (L2 / elasticNetParam=0),
 fitIntercept — the same objective convention ((1/n)·logloss + λ/2·||w||²,
 intercept unpenalized). Accelerated path: Newton-IRLS compiled into one
 XLA program (``ops/logreg_kernel.py``); host fallback is a NumPy IRLS
-with identical math; out-of-core sources stream one (gradient, Hessian)
-accumulation pass per Newton step.
+with identical math. A binary fit over a re-iterable source of ``(X, y)``
+chunks runs on the streamed loop PCA and KMeans run: the labelled rows
+cross once in ``batchRows`` batches and stay on the chip where they fit,
+and each Newton step sums its weighted Gram over them and solves on the
+chip (``_newton_streamed_xla``).
 """
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import numpy as np
@@ -30,6 +34,21 @@ from spark_rapids_ml_tpu.models.params import (
 from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
 from spark_rapids_ml_tpu.utils.timing import PhaseTimer
 from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+
+# Host spans of a streamed binary fit's Newton loop, inside ``fit:logreg``
+# (opened by @observed_fit("logreg")); the ingest's own (``stream:next``,
+# ``stream:put``, ``stream:landing/<id>``) are ``ops.streaming``'s. The
+# benchmark lists these apart (``benchmarks/work/newton.py``), and a test
+# holds the two lists equal.
+SPAN_INIT = "newton:init"  # the first walk: rows put (and kept), step 1 summed
+SPAN_STEP = "newton:step"  # each later step: a walk over the rows
+SPAN_DISPATCH = "newton:dispatch"  # one batch's update_logreg_stats call
+SPAN_SYNC = "newton:sync"  # the step's one host read: max|Δ|
+NEWTON_SPANS = (SPAN_INIT, SPAN_STEP, SPAN_DISPATCH, SPAN_SYNC)
+PHASE_INIT = "newton/init"
+PHASE_STEP = "newton/step"
+PHASE_DISPATCH = "newton/dispatch"
+PHASE_SYNC = "newton/sync"
 
 
 class LogisticRegressionParams(HasInputCol, HasDeviceId, HasWeightCol,
@@ -63,6 +82,11 @@ class LogisticRegressionParams(HasInputCol, HasDeviceId, HasWeightCol,
         True, validator=lambda v: isinstance(v, bool))
     dtype = Param("dtype", "device compute dtype", "auto",
                   validator=lambda v: v in ("auto", "float32", "float64"))
+    batchRows = Param(
+        "batchRows",
+        "rows per device batch of a streamed fit; 0 = auto-size so one "
+        "f32 [X | y] batch is ~128 MiB",
+        0, validator=lambda v: isinstance(v, int) and v >= 0)
 
 
 class LogisticRegression(LogisticRegressionParams):
@@ -89,7 +113,7 @@ class LogisticRegression(LogisticRegressionParams):
             _streaming_xy_source,
         )
 
-        source = _streaming_xy_source(dataset, labels)
+        source = _streaming_xy_source(dataset, labels, self.getBatchRows())
         if source is not None:
             self._reject_streamed_weights()
             if (float(self.getElasticNetParam()) > 0.0
@@ -447,109 +471,133 @@ class LogisticRegression(LogisticRegressionParams):
         return coef, intercept, n_iter
 
     def _fit_streamed(self, source, timer):
-        """Newton with one streamed accumulation pass per iteration.
-
-        Requires a re-iterable source. Per pass, each fixed-shape batch
-        contributes its (Xᵀr, XᵀWX, Σr, ΣW, n) partials on device via a
-        donated accumulator; the (n+1)² solve happens on host in f64.
-        """
+        """Newton with one walk over a re-iterable source a step: on the
+        streamed loop (``_newton_streamed_xla``), or in NumPy float64
+        with ``useXlaDot`` off."""
         if not source.reiterable:
             raise ValueError(
                 "LogisticRegression streaming requires a re-iterable source "
                 "(a zero-arg callable returning a fresh chunk iterator): "
                 "Newton makes one pass per iteration"
             )
-        use_xla = self.getUseXlaDot()
-        if use_xla:
-            import jax
-            import jax.numpy as jnp
-
-            from spark_rapids_ml_tpu.ops.logreg_kernel import (
-                update_logreg_stats,
-            )
-
-            device = _resolve_device(self.getDeviceId())
-            dtype = _resolve_dtype(self.getDtype())
-        nz = source.n_features          # n_features + 1 (label column)
-        n = nz - 1
+        if self.getUseXlaDot():
+            return self._newton_streamed_xla(source, timer)
+        n = source.n_features - 1     # the last column is the label
         lam = float(self.getRegParam())
         fit_b = self.getFitIntercept()
-        w = np.zeros(n)
-        b = 0.0
-        n_iter = 0
+
+        def grad_hess(w, b):
+            carry = [np.zeros(n), np.zeros((n, n)), np.zeros(n),
+                     0.0, 0.0, 0.0]
+            for batch, mask in source.batches():
+                zb = np.asarray(batch if mask is None else batch[mask],
+                                dtype=np.float64)
+                xb, yb = zb[:, :n], zb[:, n]
+                # labels checked on every walk: the host plane is the slow
+                # one anyway, and the first walk is the one that raises
+                _check_binary(yb)
+                p = _sigmoid(xb @ w + b)
+                r = p - yb
+                s = p * (1.0 - p)
+                carry[0] += xb.T @ r
+                carry[1] += xb.T @ (xb * s[:, None])
+                carry[2] += xb.T @ s
+                carry[3] += float(r.sum())
+                carry[4] += float(s.sum())
+                carry[5] += float(len(yb))
+            return _assemble_newton(*carry, w, lam, fit_b)
+
         with timer.phase("fit_kernel"), TraceRange(
-            "logreg streamed",
-            TraceColor.GREEN if use_xla else TraceColor.ORANGE,
-        ):
-            for n_iter in range(1, self.getMaxIter() + 1):
-                if use_xla:
-                    carry = _init_logreg_carry(n, dtype, device)
-                    w_dev = jnp.asarray(w, dtype=dtype)
-                    b_dev = jnp.asarray(b, dtype=dtype)
-                else:
-                    carry = [np.zeros(n), np.zeros((n, n)), np.zeros(n),
-                             0.0, 0.0, 0.0]
-                for batch, mask in source.batches():
-                    if n_iter == 1:
-                        # labels only need validating once; the jitted
-                        # accumulator can't raise, so check on host here
-                        yb = batch[:, -1] if mask is None else batch[mask, -1]
-                        _check_binary(np.asarray(yb, dtype=np.float64))
-                    if use_xla:
-                        carry = update_logreg_stats(
-                            carry, jnp.asarray(batch, dtype=dtype), w_dev,
-                            b_dev,
-                            None if mask is None else jnp.asarray(mask))
-                    else:
-                        zb = np.asarray(
-                            batch if mask is None else batch[mask],
-                            dtype=np.float64,
-                        )
-                        xb, yb = zb[:, :n], zb[:, n]
-                        p = _sigmoid(xb @ w + b)
-                        r = p - yb
-                        s = p * (1.0 - p)
-                        carry[0] += xb.T @ r
-                        carry[1] += xb.T @ (xb * s[:, None])
-                        carry[2] += xb.T @ s
-                        carry[3] += float(r.sum())
-                        carry[4] += float(s.sum())
-                        carry[5] += float(len(yb))
-                if use_xla:
-                    carry = jax.block_until_ready(carry)
-                gx, hxx, hxb, rsum, ssum, cnt = (
-                    np.asarray(v, dtype=np.float64) for v in carry
-                )
-                g, h = _assemble_newton(
-                    gx, hxx, hxb, float(rsum), float(ssum), float(cnt),
-                    w, lam, fit_b,
-                )
-                delta = np.linalg.solve(h, g)
-                w = w - delta[:n]
-                if fit_b:
-                    b = b - delta[n]
-                if np.max(np.abs(delta)) <= float(self.getTol()):
-                    break
-        return w, b, n_iter
+                "logreg streamed", TraceColor.ORANGE):
+            return _host_newton(grad_hess, n, self.getMaxIter(),
+                                float(self.getTol()), fit_b)
 
+    def _newton_streamed_xla(self, source, timer):
+        """Binary Newton on the streamed loop PCA and KMeans run. The first
+        walk (``newton:init``) puts every ``[X | y]`` batch through
+        ``IngestTrace.put``, checks its labels on the host as it passes,
+        keeps it on the chip while the budget has room — its reserve the
+        step's own (``ops.logreg_kernel.step_reserve_bytes``) — and folds
+        step 1's partials as it lands. Each later step (``newton:step``)
+        walks the kept batches without letting them go and puts the rest
+        again (``IngestTrace.replay(drain=False)``). A step is one
+        ``update_logreg_stats`` a batch (``newton:dispatch``) into a
+        donated accumulator, then ``newton_step``, which assembles the
+        system and solves it on the chip, and one host read of max|Δ|
+        (``newton:sync``): the steps stop at ``maxIter`` or when max|Δ| ≤
+        ``tol``. w and b are fetched once, at the end. Counters under
+        ``fit_report_.extra["newton"]``; the ingest's under ``["ingest"]``.
+        """
+        import jax
+        import jax.numpy as jnp
 
-def _init_logreg_carry(n: int, dtype, device):
-    """The (gx, hxx, hxb, rsum, ssum, cnt) device accumulator all logreg
-    planes share — ONE site for the carry contract."""
-    import jax
-    import jax.numpy as jnp
+        from spark_rapids_ml_tpu.obs.report import current_fit
+        from spark_rapids_ml_tpu.ops import logreg_kernel as lk
+        from spark_rapids_ml_tpu.ops.streaming import IngestTrace
 
-    return jax.device_put(
-        (
-            jnp.zeros((n,), dtype=dtype),
-            jnp.zeros((n, n), dtype=dtype),
-            jnp.zeros((n,), dtype=dtype),
-            jnp.zeros((), dtype=dtype),
-            jnp.zeros((), dtype=dtype),
-            jnp.zeros((), dtype=dtype),
-        ),
-        device,
-    )
+        device = _resolve_device(self.getDeviceId())
+        dtype = _resolve_dtype(self.getDtype())
+        n = source.n_features - 1     # the last column is the label
+        lam, fit_b = float(self.getRegParam()), self.getFitIntercept()
+        ingest = IngestTrace(timer, device)
+        counters = {"steps": 0, "batches_from_kept": 0,
+                    "batches_put_again": 0, "sync_seconds": 0.0,
+                    "solve_seconds": 0.0, "final_step_max": None}
+        current_fit().note(newton=counters)  # filled as the fit goes
+        ingest.allow_keep(lk.step_reserve_bytes(
+            source.batch_rows, source.n_features, jnp.dtype(dtype).itemsize))
+
+        def first_walk():
+            for batch, mask in ingest.batches(source):
+                labels = batch[:, -1] if mask is None else batch[mask, -1]
+                _check_binary(np.asarray(labels, dtype=np.float64))
+                c, x_dev, m_dev = ingest.put(batch, mask, dtype)
+                ingest.keep(c, x_dev, m_dev)
+                yield x_dev, m_dev
+
+        def later_walk():
+            put_before, stepped = ingest.counters["batches"], 0
+            for _, x_dev, m_dev in ingest.replay(source, dtype, drain=False):
+                stepped += 1
+                yield x_dev, m_dev
+            put_again = ingest.counters["batches"] - put_before
+            counters["batches_put_again"] += put_again
+            counters["batches_from_kept"] += stepped - put_again
+
+        coef = jnp.zeros((n,), dtype=dtype, device=device)
+        b = jnp.zeros((), dtype=dtype, device=device)
+        try:
+            with timer.phase("fit_kernel"):
+                for step in range(self.getMaxIter()):
+                    first = step == 0
+                    with timer.phase(PHASE_INIT if first else PHASE_STEP), \
+                            ingest.walk(SPAN_INIT if first else SPAN_STEP):
+                        carry = lk.init_logreg_carry(n, dtype, device)
+                        for x_dev, m_dev in (first_walk() if first
+                                             else later_walk()):
+                            with ingest.stage(SPAN_DISPATCH, PHASE_DISPATCH):
+                                carry = lk.update_logreg_stats(
+                                    carry, x_dev, coef, b, m_dev)
+                        coef, b, moved = lk.newton_step(
+                            carry, coef, b, lam, fit_intercept=fit_b)
+                        with ingest.stage(SPAN_SYNC, PHASE_SYNC):
+                            jax.block_until_ready(carry)  # the step's Gram
+                            summed = time.perf_counter()
+                            moved = float(moved)
+                        counters["solve_seconds"] += \
+                            time.perf_counter() - summed
+                    counters["steps"] += 1
+                    counters["sync_seconds"] = timer.as_dict()[PHASE_SYNC]
+                    counters["final_step_max"] = moved
+                    if moved <= float(self.getTol()):
+                        break
+                with timer.phase("fetch"):
+                    coef, b = jax.device_get((coef, b))
+        finally:
+            ingest.release()  # no device batch outlives the walks over it
+        ingest.all_landed()  # max|Δ| was read behind every batch put
+        ingest.set_data(n)
+        return np.asarray(coef), float(b), counters["steps"]
 
 
 def _xla_logloss_grad_hess(z_dev, w, b, w_mask, device, dtype, fit_b):
@@ -558,10 +606,13 @@ def _xla_logloss_grad_hess(z_dev, w, b, w_mask, device, dtype, fit_b):
     import jax
     import jax.numpy as jnp
 
-    from spark_rapids_ml_tpu.ops.logreg_kernel import update_logreg_stats
+    from spark_rapids_ml_tpu.ops.logreg_kernel import (
+        init_logreg_carry,
+        update_logreg_stats,
+    )
 
     n = z_dev.shape[1] - 1
-    carry = _init_logreg_carry(n, dtype, device)
+    carry = init_logreg_carry(n, dtype, device)
     carry = jax.block_until_ready(update_logreg_stats(
         carry, z_dev, jnp.asarray(w, dtype=dtype),
         jnp.asarray(b, dtype=dtype), w_mask,

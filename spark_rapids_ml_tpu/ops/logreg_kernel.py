@@ -13,7 +13,10 @@ solved by Newton-IRLS — each iteration is two MXU matmuls (the logits
 solve, the same "big matmul + small dense solve" shape as every other
 algorithm here. The iteration is a ``lax.while_loop`` compiled into the
 program; masked (padding) rows contribute nothing to loss, gradient, or
-Hessian.
+Hessian. A streamed fit runs the same step as two programs: the partials
+folded in a batch at a time (``update_logreg_stats``), then the system
+assembled and solved on the chip (``newton_step``) — the assembly and the
+Cholesky solve are ``newton_delta`` in both.
 """
 
 from __future__ import annotations
@@ -35,34 +38,37 @@ class LogRegResult(NamedTuple):
     converged: jnp.ndarray      # scalar bool
 
 
-def _grad_hess(w, x, y, valid, reg_param, fit_intercept, reduce_fn):
-    """(gradient, Hessian) of the Spark-convention objective at w.
-
-    ``w`` is (n+1,): coefficients ++ intercept slot (zero-pinned when
-    ``fit_intercept`` is False). ``reduce_fn`` combines the per-shard
-    (Xᵀr, XᵀWX, Σr, ΣW, n) partials — identity on one device, ``psum``
-    over the mesh in the distributed form.
-    """
-    n_feat = x.shape[1]
-    coef, b = w[:n_feat], w[n_feat]
-    z = x @ coef + b
-    p = jax.nn.sigmoid(z)
+def logreg_raw_stats(x, y, coef, b, valid):
+    """One batch's Newton partials at (coef, b): (Xᵀr, XᵀWX, Xᵀs, Σr, Σs,
+    n) with r = σ(Xw + b) − y and s = σ'(Xw + b) = W's diagonal, masked
+    by ``valid``; every product at ``HIGHEST``. Additive across batches
+    and shards."""
+    dims = (((0,), (0,)), ((), ()))
+    p = jax.nn.sigmoid(lax.dot_general(
+        x, coef, (((1,), (0,)), ((), ())),
+        precision=lax.Precision.HIGHEST) + b)
     r = (p - y) * valid                 # residual, masked
     s = p * (1.0 - p) * valid           # IRLS weights, masked
-    gx = lax.dot_general(x, r, (((0,), (0,)), ((), ())),
-                         precision=lax.Precision.HIGHEST)
     # Hessian core: Xᵀ diag(s) X — one MXU matmul of the s-scaled rows
     xs = x * s[:, None]
-    hxx = lax.dot_general(x, xs, (((0,), (0,)), ((), ())),
-                          precision=lax.Precision.HIGHEST)
-    hxb = jnp.sum(xs, axis=0)
-    stats = reduce_fn((gx, hxx, hxb, jnp.sum(r), jnp.sum(s),
-                       jnp.sum(valid)))
-    gx, hxx, hxb, rsum, ssum, cnt = stats
-    inv_n = 1.0 / jnp.maximum(cnt, 1.0)
+    return (lax.dot_general(x, r, dims, precision=lax.Precision.HIGHEST),
+            lax.dot_general(x, xs, dims, precision=lax.Precision.HIGHEST),
+            jnp.sum(xs, axis=0), jnp.sum(r), jnp.sum(s), jnp.sum(valid))
 
+
+def newton_delta(stats, w, reg_param, fit_intercept):
+    """The Newton step Δ = H⁻¹g of the Spark-convention objective at ``w``
+    from the partials ``stats`` (``logreg_raw_stats``, summed over every
+    row), by Cholesky: g and H scaled by 1/n, λ on the coefficients, the
+    intercept unpenalised. ``w`` is (n+1,): coefficients ++ intercept slot
+    (zero-pinned when ``fit_intercept`` is False: unit diagonal, zero
+    gradient). Shared by the in-memory program and the streamed fit's
+    finishing program."""
+    gx, hxx, hxb, rsum, ssum, cnt = stats
+    n_feat = gx.shape[0]
+    inv_n = 1.0 / jnp.maximum(cnt, 1.0)
     g = jnp.zeros_like(w)
-    g = g.at[:n_feat].set(gx * inv_n + reg_param * coef)
+    g = g.at[:n_feat].set(gx * inv_n + reg_param * w[:n_feat])
     h = jnp.zeros((n_feat + 1, n_feat + 1), dtype=w.dtype)
     h = h.at[:n_feat, :n_feat].set(
         hxx * inv_n + reg_param * jnp.eye(n_feat, dtype=w.dtype)
@@ -73,9 +79,10 @@ def _grad_hess(w, x, y, valid, reg_param, fit_intercept, reduce_fn):
         h = h.at[n_feat, :n_feat].set(hxb * inv_n)
         h = h.at[n_feat, n_feat].set(ssum * inv_n)
     else:
-        # pin the intercept slot: unit diagonal, zero gradient
         h = h.at[n_feat, n_feat].set(1.0)
-    return g, h
+    # Damped-free Newton; the ridge term (or the pinned intercept slot)
+    # keeps H positive definite.
+    return jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(h), g)
 
 
 def newton_iterations(
@@ -98,10 +105,11 @@ def newton_iterations(
 
     def step(state):
         w, _, it, _ = state
-        g, h = _grad_hess(w, x, y, valid, reg_param, fit_intercept, reduce_fn)
-        # Damped-free Newton with a Cholesky solve; the ridge term (or the
-        # pinned intercept slot) keeps H positive definite.
-        delta = jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(h), g)
+        # ``reduce_fn`` combines the per-shard partials: identity on one
+        # device, ``psum`` over the mesh in the distributed form
+        stats = reduce_fn(logreg_raw_stats(x, y, w[:n_feat], w[n_feat],
+                                           valid))
+        delta = newton_delta(stats, w, reg_param, fit_intercept)
         w_new = w - delta
         moved = jnp.max(jnp.abs(delta))
         return w_new, moved, it + 1, moved <= tol
@@ -131,33 +139,51 @@ def logreg_fit_kernel(
     )
 
 
+def init_logreg_carry(n: int, dtype, device=None):
+    """The (gx, hxx, hxb, rsum, ssum, cnt) accumulator of
+    ``update_logreg_stats``, zero, on ``device`` — ONE site for the carry
+    contract."""
+    return tuple(jnp.zeros(shape, dtype=dtype, device=device)
+                 for shape in ((n,), (n, n), (n,), (), (), ()))
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def update_logreg_stats(carry, batch_z, w, b, mask=None):
     """Out-of-core Newton building block: fold one ``[X | y]`` batch's
     (Xᵀr, XᵀWX, Xᵀs, Σr, Σs, n) partials at the current (w, b) into a
     donated accumulator. One streamed pass with this per batch = one
     Newton gradient/Hessian evaluation over the full dataset."""
-    gx, hxx, hxb, rsum, ssum, cnt = carry
-    x = batch_z[:, :-1].astype(gx.dtype)
-    y = batch_z[:, -1].astype(gx.dtype)
+    x = batch_z[:, :-1].astype(carry[0].dtype)
+    y = batch_z[:, -1].astype(carry[0].dtype)
     valid = (
         jnp.ones(x.shape[0], dtype=x.dtype) if mask is None
         else mask.astype(x.dtype)
     )
-    p = jax.nn.sigmoid(x @ w + b)
-    r = (p - y) * valid
-    s = p * (1.0 - p) * valid
-    xs = x * s[:, None]
-    return (
-        gx + lax.dot_general(x, r, (((0,), (0,)), ((), ())),
-                             precision=lax.Precision.HIGHEST),
-        hxx + lax.dot_general(x, xs, (((0,), (0,)), ((), ())),
-                              precision=lax.Precision.HIGHEST),
-        hxb + jnp.sum(xs, axis=0),
-        rsum + jnp.sum(r),
-        ssum + jnp.sum(s),
-        cnt + jnp.sum(valid),
-    )
+    return tuple(a + s for a, s in
+                 zip(carry, logreg_raw_stats(x, y, w, b, valid)))
+
+
+@partial(tracked_jit, static_argnames=("fit_intercept",))
+def newton_step(carry, coef, b, reg_param, fit_intercept: bool = True):
+    """The end of one streamed Newton step: the system assembled from the
+    summed partials ``carry`` and solved on the chip (``newton_delta``),
+    then (coefficients − Δ, intercept − Δ's last, max|Δ|). One program,
+    whose scalar the fit reads."""
+    w = jnp.concatenate([coef, b[None]])
+    delta = newton_delta(carry, w, reg_param, fit_intercept)
+    w = w - delta
+    return w[:-1], w[-1], jnp.max(jnp.abs(delta))
+
+
+def step_reserve_bytes(batch_rows: int, width: int, itemsize: int) -> int:
+    """Device bytes a streamed Newton step needs beside the batches a fit
+    keeps (``ops.streaming.keep_budget_bytes``' reserve), for ``[X | y]``
+    batches ``width`` = features + 1 wide: one batch in flight beyond those
+    kept, the step's two batch-sized temporaries should the compiler hold
+    them (X cut from the batch and its s-scaled copy; the v5e's fuses both
+    away), and two width² accumulators (the carry and its donated
+    successor)."""
+    return itemsize * (3 * batch_rows * width + 2 * width * width)
 
 
 @tracked_jit

@@ -302,7 +302,10 @@ def partition_logreg_stats_device(
 
     from spark_rapids_ml_tpu.models.logistic_regression import _check_binary
     from spark_rapids_ml_tpu.models.pca import _resolve_device, _resolve_dtype
-    from spark_rapids_ml_tpu.ops.logreg_kernel import update_logreg_stats
+    from spark_rapids_ml_tpu.ops.logreg_kernel import (
+        init_logreg_carry,
+        update_logreg_stats,
+    )
 
     device = _resolve_device(device_id)
     dt = _resolve_dtype(dtype)
@@ -319,17 +322,7 @@ def partition_logreg_stats_device(
         rows_seen += m
         _check_binary(y)
         if carry is None:
-            carry = jax.device_put(
-                (
-                    jnp.zeros((n,), dtype=dt),
-                    jnp.zeros((n, n), dtype=dt),
-                    jnp.zeros((n,), dtype=dt),
-                    jnp.zeros((), dtype=dt),
-                    jnp.zeros((), dtype=dt),
-                    jnp.zeros((), dtype=dt),
-                ),
-                device,
-            )
+            carry = init_logreg_carry(n, dt, device)
             w_dev = jax.device_put(jnp.asarray(w, dtype=dt), device)
             b_dev = jax.device_put(jnp.asarray(float(b), dtype=dt), device)
         bucket = _bucket_rows(m)
