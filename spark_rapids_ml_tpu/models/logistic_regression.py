@@ -542,7 +542,11 @@ class LogisticRegression(LogisticRegressionParams):
         ingest = IngestTrace(timer, device)
         counters = {"steps": 0, "batches_from_kept": 0,
                     "batches_put_again": 0, "sync_seconds": 0.0,
-                    "solve_seconds": 0.0, "final_step_max": None}
+                    "solve_seconds": 0.0, "final_step_max": None,
+                    # the step's weighted Gram: its column panels, and
+                    # their MXU work ÷ the full n × n product's
+                    "gram_panels": len(lk.gram_panels(n)),
+                    "gram_work_share": lk.gram_work_share(n)}
         current_fit().note(newton=counters)  # filled as the fit goes
         ingest.allow_keep(lk.step_reserve_bytes(
             source.batch_rows, source.n_features, jnp.dtype(dtype).itemsize))

@@ -17,6 +17,7 @@ from __future__ import annotations
 import importlib.util
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -254,7 +255,10 @@ def test_the_fit_emits_the_newton_spans_in_order(monkeypatch):
         assert timings[phase] > 0, phase
     newton = model.fit_report_.extra["newton"]
     assert set(newton) == {"steps", "batches_from_kept", "batches_put_again",
-                           "sync_seconds", "solve_seconds", "final_step_max"}
+                           "sync_seconds", "solve_seconds", "final_step_max",
+                           "gram_panels", "gram_work_share"}
+    # N features fit one panel: the full product
+    assert newton["gram_panels"] == 1 and newton["gram_work_share"] == 1.0
     assert newton["steps"] == 3
     assert newton["sync_seconds"] == timings[lr_module.PHASE_SYNC]
     assert 0 < newton["solve_seconds"] <= newton["sync_seconds"]
@@ -305,3 +309,122 @@ def test_the_in_memory_program_and_the_step_share_the_solve(monkeypatch):
         np.column_stack([x, y[:100]]), np.zeros(n), np.float64(0.0))
     logreg_kernel.newton_step(carry, np.zeros(n), np.float64(0.0), LAM)
     assert calls == [(n + 1,), (n + 1,)]
+
+
+# -- the weighted Gram's upper panels ---------------------------------------
+
+PANEL = logreg_kernel.GRAM_PANEL
+
+
+def _computed_share(n: int) -> float:
+    """The entries a panelled Gram computes, counted one by one: row i's
+    from its panel's first column on."""
+    i, j = np.indices((n, n))
+    return float(np.mean(j >= (i // PANEL) * PANEL))
+
+
+@pytest.mark.parametrize("n, masked", [
+    (PANEL // 6, False), (PANEL, False), (3 * PANEL + 7, False),
+    (3 * PANEL + 7, True)], ids=["under", "one-panel", "off-grid", "masked"])
+def test_the_weighted_gram_is_the_full_product_mirrored(n, masked):
+    rng = np.random.default_rng(n)
+    rows = 96
+    x = rng.normal(size=(rows, n)).astype(np.float32)
+    s = rng.uniform(0.01, 0.25, size=rows).astype(np.float32)
+    h = np.asarray(jax.jit(logreg_kernel.weighted_gram)(x, s))
+    full = np.asarray(jax.jit(lambda x, s: jax.lax.dot_general(
+        x, x * s[:, None], (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST))(x, s))
+    panels = logreg_kernel.gram_panels(n)
+    assert h.shape == (n, n) and h.dtype == np.float32
+    np.testing.assert_allclose(h, full, rtol=0,
+                               atol=32 * np.finfo(np.float32).eps
+                               * np.abs(full).max())
+    np.testing.assert_array_equal(h, h.T)  # bit for bit
+    assert len(panels) == -(-n // PANEL)
+    assert sum(w for _, w in panels) == n
+    assert logreg_kernel.gram_work_share(n) == pytest.approx(
+        _computed_share(n), rel=1e-12)
+
+    # a batch through the step's program: padded rows (garbage behind a
+    # false mask) add nothing, and the carry keeps its shapes
+    z = np.column_stack([x, (rng.uniform(size=rows) < 0.5)]).astype(
+        np.float32)
+    coef = (0.05 * rng.normal(size=n)).astype(np.float32)
+    b = np.float32(0.2)
+    mask, keep = None, rows
+    if masked:
+        keep = rows - 17
+        mask = np.arange(rows) < keep
+        z[keep:] = 1e3 * rng.normal(size=(rows - keep, n + 1))
+    carry = logreg_kernel.init_logreg_carry(n, np.float32)
+    shapes = [a.shape for a in carry]
+    carry = logreg_kernel.update_logreg_stats(carry, z, coef, b, mask)
+    assert [a.shape for a in carry] == shapes
+    only = logreg_kernel.update_logreg_stats(
+        logreg_kernel.init_logreg_carry(n, np.float32), z[:keep], coef, b)
+    for got, want in zip(carry, only):
+        want = np.asarray(want)
+        np.testing.assert_allclose(
+            np.asarray(got), want, rtol=0,
+            atol=32 * np.finfo(np.float32).eps * max(np.abs(want).max(), 1))
+    np.testing.assert_array_equal(np.asarray(carry[1]),
+                                  np.asarray(carry[1]).T)
+    assert float(carry[5]) == keep
+
+
+def test_a_wide_streamed_fit_reports_its_panels():
+    """Past one panel's width the streamed fit sums the upper panels, says
+    so, and is still the in-memory program's Newton."""
+    n = PANEL + 16
+    rng = np.random.default_rng(11)
+    x = rng.normal(size=(ROWS, n))
+    y = (x @ rng.normal(size=n) / np.sqrt(n) + 0.3 * rng.normal(size=ROWS)
+         > 0).astype(np.float64)
+    streamed = _estimator(maxIter=3).fit(_factory(x, y))
+    newton = streamed.fit_report_.extra["newton"]
+    assert newton["gram_panels"] == 2
+    assert newton["gram_work_share"] == pytest.approx(_computed_share(n))
+    assert newton["gram_work_share"] < 1.0
+    oneshot = _estimator(maxIter=3).fit(x, y)
+    assert streamed.n_iter_ == oneshot.n_iter_ == 3
+    np.testing.assert_allclose(streamed.coefficients, oneshot.coefficients,
+                               rtol=0, atol=1e-10)
+    assert streamed.intercept == pytest.approx(oneshot.intercept, abs=1e-10)
+
+
+def _dot_precisions(jaxpr) -> list:
+    """The precision of every ``dot_general`` in a jaxpr, its inner jaxprs
+    (a jitted call's body) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)  # a ClosedJaxpr's
+            if hasattr(inner, "eqns"):
+                found += _dot_precisions(inner)
+    return found
+
+
+@pytest.mark.parametrize("program", ["weighted_gram", "update_logreg_stats"])
+def test_every_product_of_the_step_is_at_highest(program):
+    """A CPU dot is float32 at any precision setting, so no result of the
+    suite would see a panel fall to one bfloat16 pass: read the programs'
+    products instead."""
+    n, rows = 3 * PANEL + 7, 16
+    x = np.zeros((rows, n), np.float32)
+    if program == "weighted_gram":
+        jaxpr = jax.make_jaxpr(logreg_kernel.weighted_gram)(
+            x, np.ones(rows, np.float32))
+        products = len(logreg_kernel.gram_panels(n))
+    else:
+        jaxpr = jax.make_jaxpr(logreg_kernel.update_logreg_stats)(
+            logreg_kernel.init_logreg_carry(n, np.float32),
+            np.zeros((rows, n + 1), np.float32), np.zeros(n, np.float32),
+            np.float32(0.0))
+        products = len(logreg_kernel.gram_panels(n)) + 2  # X·w and Xᵀr
+    precisions = _dot_precisions(jaxpr.jaxpr)
+    assert len(precisions) == products
+    highest = jax.lax.Precision.HIGHEST
+    assert all(p == (highest, highest) for p in precisions), precisions
