@@ -109,10 +109,13 @@ _EXHAUSTED = object()  # what a source's iterator gives when it has no more
 
 class ColumnBlocks:
     """A chunk whose rows are side-by-side column blocks of one height —
-    a labelled chunk's X and y — joined only where a batch is written
-    (``BatchSource``): the join is the re-blocking's one host copy into
-    the batch, never a copy of the whole chunk first. ``viewed``: the
-    blocks are the caller's arrays or views of them."""
+    a labelled chunk's X and y — never joined whole on the host. A full
+    batch of a traced walk that is a row slice of one such chunk stays
+    these blocks, and ``ops.streaming.IngestTrace.put`` puts each block
+    and joins them on the chip; any other batch (assembled from several
+    chunks, padded, or of a walk nobody traces) is joined by the
+    re-blocking's one host copy into the batch (``write_into``).
+    ``viewed``: the blocks are the caller's arrays or views of them."""
 
     def __init__(self, blocks, dtype, viewed: bool = True):
         self.blocks = tuple(blocks)
@@ -120,6 +123,11 @@ class ColumnBlocks:
         self.viewed = viewed
         self.shape = (self.blocks[0].shape[0],
                       sum(block.shape[1] for block in self.blocks))
+
+    @property
+    def nbytes(self) -> int:
+        """What the joined rows weigh in ``dtype``."""
+        return self.shape[0] * self.shape[1] * self.dtype.itemsize
 
     def __getitem__(self, rows: slice) -> "ColumnBlocks":
         return ColumnBlocks([block[rows] for block in self.blocks],
@@ -194,7 +202,10 @@ class BatchSource:
     ``mask`` marking valid rows (``mask is None`` for full buckets — the
     jitted accumulators trace the mask-free fast path for those). A chunk
     a ``chunk_transform`` gives as ``ColumnBlocks`` (a labelled chunk's X
-    and y) is joined into each batch by that batch's one host copy.
+    and y) is joined into each batch by that batch's one host copy, but
+    for a full batch of a traced walk that is a row slice of the chunk:
+    that one is yielded as its blocks, to be joined on the chip
+    (``batches``).
     """
 
     def __init__(self, source, batch_rows: int = 0,
@@ -352,8 +363,13 @@ class BatchSource:
     def batches(self) -> Iterator[Tuple[np.ndarray, Optional[np.ndarray]]]:
         """Yield fixed-shape ``(batch, mask)`` pairs; mask None = all valid.
 
-        ``self.trace`` (an ``ops.streaming.IngestTrace``, where a streamed
-        fit has set one) is told what the walk does: ``next_stage("read")``
+        The walk takes ``self.trace`` (an ``ops.streaming.IngestTrace``,
+        where a streamed fit has set one for it) and clears it, so a later
+        walk nobody traces reports nowhere and gets host arrays. A traced
+        walk yields a full batch that is a row slice of one
+        ``ColumnBlocks`` chunk as that slice, counted viewed and not
+        copied: ``IngestTrace.put`` joins its blocks on the chip. The
+        trace is told what the walk does: ``next_stage("read")``
         wraps the reading of each chunk, ``next_stage("copy")`` each host
         copy of re-blocking, and its ``counters`` get ``SOURCE_COUNTERS``'
         keys counted. A copied batch is written into the array
@@ -367,7 +383,8 @@ class BatchSource:
         shared, partially-exhausted underlying iterator (one the identity
         check in ``__init__`` cannot see, e.g. ``lambda: map(f, shared_gen)``)
         would otherwise silently zero out second-pass accumulations."""
-        trace = self.trace or _Untraced()
+        trace, self.trace = self.trace or _Untraced(), None
+        traced = not isinstance(trace, _Untraced)
         counters = trace.counters
         b, n = self.batch_rows, self.n_features
         carry: list = []
@@ -395,9 +412,10 @@ class BatchSource:
                     carry, carry_rows = [], 0
             while chunk.shape[0] - start >= b:
                 # a slice of a whole chunk leaves the rows where they are;
-                # one of column blocks is joined into a batch here
+                # one of column blocks too where the put joins it on the
+                # chip, and is joined into a batch here where nobody traces
                 piece = chunk[start:start + b]
-                if isinstance(piece, ColumnBlocks):
+                if isinstance(piece, ColumnBlocks) and not traced:
                     piece = self._copied([piece], trace)
                 else:
                     counters["batches_viewed"] += 1

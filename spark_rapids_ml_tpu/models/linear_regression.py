@@ -306,9 +306,11 @@ def _extract_weights(est, frame, n_rows):
 
 
 def _zip_xy(chunk):
-    """(X_chunk, y_chunk) → the chunk Z = [X | y] as column blocks: each
-    batch of it is joined by the source's one host copy into the batch
-    (``data.batches.ColumnBlocks``), not by a copy of the whole chunk."""
+    """(X_chunk, y_chunk) → the chunk Z = [X | y] as column blocks
+    (``data.batches.ColumnBlocks``), never a copy of the whole chunk: a
+    traced walk puts a batch that is a row slice of it as its X and y and
+    joins them on the chip (``ops.streaming.IngestTrace.put``); any other
+    batch is joined by the source's one host copy into the batch."""
     from spark_rapids_ml_tpu.data.batches import ColumnBlocks
 
     if not (isinstance(chunk, tuple) and len(chunk) == 2):

@@ -515,7 +515,11 @@ class LogisticRegression(LogisticRegressionParams):
     def _newton_streamed_xla(self, source, timer):
         """Binary Newton on the streamed loop PCA and KMeans run. The first
         walk (``newton:init``) puts every ``[X | y]`` batch through
-        ``IngestTrace.put``, checks its labels on the host as it passes,
+        ``IngestTrace.put`` (a full batch that is a row slice of one
+        ``(X, y)`` chunk crosses as its X and y and is joined on the chip;
+        a batch assembled from several chunks, and the padded tail, is
+        joined by the source's one host copy), checks its labels on the
+        host as it passes,
         keeps it on the chip while the budget has room — its reserve the
         step's own (``ops.logreg_kernel.step_reserve_bytes``) — and folds
         step 1's partials as it lands. Each later step (``newton:step``)
@@ -531,6 +535,7 @@ class LogisticRegression(LogisticRegressionParams):
         import jax
         import jax.numpy as jnp
 
+        from spark_rapids_ml_tpu.data.batches import ColumnBlocks
         from spark_rapids_ml_tpu.obs.report import current_fit
         from spark_rapids_ml_tpu.ops import logreg_kernel as lk
         from spark_rapids_ml_tpu.ops.streaming import IngestTrace
@@ -553,7 +558,11 @@ class LogisticRegression(LogisticRegressionParams):
 
         def first_walk():
             for batch, mask in ingest.batches(source):
-                labels = batch[:, -1] if mask is None else batch[mask, -1]
+                # the label column alone; a slice the chip joins holds it
+                # as a block of its own
+                y = batch.blocks[-1] if isinstance(batch, ColumnBlocks) \
+                    else batch[:, -1:]
+                labels = y[:, 0] if mask is None else y[mask, 0]
                 _check_binary(np.asarray(labels, dtype=np.float64))
                 c, x_dev, m_dev = ingest.put(batch, mask, dtype)
                 ingest.keep(c, x_dev, m_dev)

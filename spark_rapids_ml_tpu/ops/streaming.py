@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_rapids_ml_tpu.data.batches import SOURCE_COUNTERS
+from spark_rapids_ml_tpu.data.batches import SOURCE_COUNTERS, ColumnBlocks
 from spark_rapids_ml_tpu.obs import spans as obs_spans
 from spark_rapids_ml_tpu.obs.memory import device_memory_stats
 from spark_rapids_ml_tpu.obs.report import current_fit
@@ -427,6 +427,14 @@ def put_slices(rows: int, nbytes: int) -> int:
     return pieces
 
 
+@tracked_jit
+def join_columns(blocks: tuple) -> jnp.ndarray:
+    """The device batch from its column blocks as they were put, side by
+    side: the join of a labelled batch's X and y, an HBM copy of the slice
+    on the chip instead of a host copy."""
+    return jnp.concatenate(blocks, axis=1)
+
+
 def wait_for_landing(x_dev) -> None:
     """Block until the device batch ``device_put`` returned is on its chip
     (``block_until_ready`` is a fence on the TPU runtime; it returns at once
@@ -596,7 +604,8 @@ class _Chip:
     def __init__(self, device):
         self.device = device  # None = JAX's default device, uncommitted
         self.keep_room = 0  # bytes of the chip's budget not taken yet
-        # (device batch, the staging buffer its slice was put from or None)
+        # (what crossed — the device batch, or the blocks of one joined on
+        # the chip —, the staging buffer its slice was put from or None)
         # of the puts here not yet waited for, oldest first: at most
         # ``PUTS_IN_FLIGHT``
         self.in_flight = collections.deque()
@@ -606,7 +615,7 @@ class _Chip:
             "keep_budget_bytes": 0, "hbm_bytes_in_use": {},
             "puts_in_flight_max": 0, "put_waits": 0, "put_wait_seconds": 0.0,
             "landings": 0, "crossing_seconds": 0.0,
-            "last_landing_seconds": 0.0,
+            "last_landing_seconds": 0.0, "batches_joined_on_chip": 0,
         }
         self.watcher: Optional[_Watcher] = None
 
@@ -669,9 +678,11 @@ class IngestTrace:
         self._first_put: Optional[float] = None  # when it started
         self.counters = {
             # ``batches``: puts, i.e. slices; ``host_batches``: the batches
-            # the source yielded in the fit's walks
+            # the source yielded in the fit's walks;
+            # ``batches_joined_on_chip``: slices put as their column blocks
+            # and joined on the chip (``put``)
             "passes": 0, "batches": 0, "host_batches": 0, "rows_put": 0,
-            "bytes_put": 0,
+            "bytes_put": 0, "batches_joined_on_chip": 0,
             # counted by the source as it is walked (``staging_*``: by
             # ``staging``, which the source asks)
             **SOURCE_COUNTERS,
@@ -807,8 +818,8 @@ class IngestTrace:
         if len(chip.in_flight) < PUTS_IN_FLIGHT:
             return
         t0 = time.perf_counter()
-        x_dev, staged = chip.in_flight.popleft()
-        wait_for_landing(x_dev)
+        landing, staged = chip.in_flight.popleft()
+        wait_for_landing(landing)
         self._settle(staged)
         seconds = time.perf_counter() - t0
         for counters in (self.counters, chip.counters):
@@ -819,44 +830,63 @@ class IngestTrace:
         """The next host batch → the chip whose turn it is, whole and in
         one hop (``jnp.asarray`` would land it on device 0 whatever the
         chip is), once the chip's window has room (the wait is part of the
-        put stage). (chip index, device batch, device mask)."""
+        put stage). (chip index, device batch, device mask).
+
+        A ``ColumnBlocks`` slice (a labelled batch the source left as its
+        X and y) crosses as its blocks, each cast to ``dtype`` (a view
+        where it is that already) and put on its own, and the put stage
+        ends with the dispatch of ``join_columns``: the device batch is the
+        join, the ``[X | y]`` array a host join's put would have made. The
+        blocks are still one put: one window slot and one landing, which
+        the watcher times on the blocks themselves, and their bytes."""
         c = self.turn % len(self.chips)
         chip = self.chips[c]
         self.turn += 1
         if self._first_put is None:
             self._first_put = time.perf_counter()
+        joined = isinstance(batch, ColumnBlocks)
         with self.stage(SPAN_PUT, PHASE_PUT, "put_seconds_max"):
             self._await_window(chip)
-            x = np.asarray(batch, dtype=dtype)
-            x_dev = jax.device_put(x, chip.device)
+            if joined:
+                host = [np.asarray(block, dtype=dtype)
+                        for block in batch.blocks]
+                landing = tuple(jax.device_put(block, chip.device)
+                                for block in host)
+                x_dev = join_columns(landing)
+            else:
+                host = [np.asarray(batch, dtype=dtype)]
+                landing = x_dev = jax.device_put(host[0], chip.device)
             m_dev = None if mask is None else jax.device_put(mask, chip.device)
+        nbytes = sum(block.nbytes for block in host)
         if chip.watcher is None:
             chip.watcher = watcher_of(chip.stats_device())
-        chip.watcher.fifo.put((x_dev, x.nbytes, self.counters["batches"],
+        chip.watcher.fifo.put((landing, nbytes, self.counters["batches"],
                                self._first_put, obs_spans.current_trace_id(),
                                chip.counters))
         staged = None
         if self._lent is not None and self._lent[0] is batch:
             staged, self._lent = self._lent[1], None
-            if x is not batch:
-                # a cast made ``x`` another array: nobody reads the slice
+            if host[0] is not batch:
+                # a cast made another array: nobody reads the slice
                 self._settle(staged)
                 staged = None
             elif not put_copies(chip.stats_device()):
                 # the device array's from now on: never lent again
                 self._unsettled.pop(id(staged), None)
                 staged = None
-        chip.in_flight.append((x_dev, staged))
+        chip.in_flight.append((landing, staged))
+        rows = batch.shape[0]
         for counters in (self.counters, chip.counters):
             counters["puts_in_flight_max"] = max(
                 counters["puts_in_flight_max"], len(chip.in_flight))
-        self.put_rows = x.shape[0] if mask is None else int(mask.sum())
+        self.put_rows = rows if mask is None else int(mask.sum())
         self._count_rows(c, self.put_rows)
-        self.itemsize = x.itemsize
+        self.itemsize = host[0].itemsize
         self.counters["batches"] += 1
         for counters in (self.counters, chip.counters):
-            counters["rows_put"] += x.shape[0]  # padding crosses too
-            counters["bytes_put"] += x.nbytes
+            counters["rows_put"] += rows  # padding crosses too
+            counters["bytes_put"] += nbytes
+            counters["batches_joined_on_chip"] += joined
         return c, x_dev, m_dev
 
     def allow_keep(self, reserve_nbytes: int) -> None:

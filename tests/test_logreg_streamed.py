@@ -21,7 +21,7 @@ import jax
 import numpy as np
 import pytest
 
-from spark_rapids_ml_tpu import LogisticRegression
+from spark_rapids_ml_tpu import LogisticRegression, obs
 from spark_rapids_ml_tpu.data.batches import ColumnBlocks, auto_batch_rows
 from spark_rapids_ml_tpu.models import linear_regression
 from spark_rapids_ml_tpu.models import logistic_regression as lr_module
@@ -196,18 +196,158 @@ def test_labels_are_checked_on_the_first_walk():
 # -- the [X | y] source ---------------------------------------------------------
 
 
-def test_the_join_of_x_and_y_is_one_host_copy_a_batch():
+def _aligned(x, y, rows: int = 2 * BATCH):
+    """The rows as (X, y) chunks of ``rows`` rows: a multiple of BATCH,
+    so every full batch is a row slice of one chunk."""
+    return lambda: ((x[i:i + rows], y[i:i + rows])
+                    for i in range(0, len(y), rows))
+
+
+@pytest.mark.parametrize("chunk_rows, viewed, slices", [
+    (2 * BATCH, BATCHES - 1, 1), (CHUNK, 2, 1), (2 * BATCH, BATCHES - 1, 2)],
+    ids=["aligned", "uneven", "aligned-two-slices"])
+def test_the_join_of_x_and_y_is_on_the_chip_for_a_slice_of_one_chunk(
+        monkeypatch, chunk_rows, viewed, slices):
+    """A full batch that is a row slice of one (X, y) chunk crosses as its
+    X and y and is joined on the chip: viewed, no host copy. Batches
+    assembled from two chunks, and the padded tail, are still joined by
+    their one host copy. Cut into two slices for the link (the benchmark
+    cell's 1.57 GB batches), each slice is a put joined on the chip."""
+    if slices > 1:
+        monkeypatch.setattr(streaming, "PUT_BYTES_MAX",
+                            BATCH * WIDTH * 8 // slices)
     x, y = _rows()
-    model = _fit(x, y, maxIter=1)
+    factory = _aligned(x, y, chunk_rows)
+    model = _estimator(maxIter=1).fit(factory)
     ingest = model.fit_report_.extra["ingest"]
-    # every batch is written once from the chunks' X and y, none copied
-    # whole first: the chunks are the caller's arrays
-    assert ingest["chunks"] == ingest["chunks_viewed"] == -(-ROWS // CHUNK)
-    assert ingest["batches_copied"] == BATCHES and not ingest[
-        "batches_viewed"]
+    chunks = -(-ROWS // chunk_rows)
+    copied = BATCHES - viewed  # the tail among them
+    assert ingest["chunks"] == ingest["chunks_viewed"] == chunks
+    assert (ingest["batches_viewed"], ingest["batches_copied"]) == (
+        viewed, copied)
     assert ingest["bytes_reblocked"] == (
-        (BATCHES - 1) * BATCH + ROWS % BATCH) * WIDTH * 8
+        (copied - 1) * BATCH + ROWS % BATCH) * WIDTH * 8
+    # each slice of a viewed batch is one put joined on the chip, counted
+    # on its chip too
+    assert ingest["batches"] == slices * BATCHES
+    assert ingest["batches_joined_on_chip"] == slices * viewed
+    assert ingest["per_chip"][0]["batches_joined_on_chip"] == slices * viewed
+    assert ingest["bytes_put"] == BATCHES * BATCH * WIDTH * 8
     assert model.fit_timings_[streaming.PHASE_NEXT_PART["copy"]] > 0
+    # and it leaves through the reports' ring as the others do
+    ring = obs.recent_fit_reports(1, "logreg")[-1].extra["ingest"]
+    assert ring["batches_joined_on_chip"] == slices * viewed
+    _assert_plain_newton(model, x, y)
+
+
+def _blocks(x, y):
+    return linear_regression._zip_xy((x, y))
+
+
+@pytest.mark.parametrize("x_dtype, y_dtype, put_dtype", [
+    (np.float32, np.float64, np.float32),  # X a view, y cast
+    (np.float64, np.float64, np.float64),  # both views
+    (np.float32, np.int64, np.float64),    # both cast
+], ids=["x-view-y-cast", "both-views", "both-cast"])
+def test_a_put_of_column_blocks_is_the_joined_batch_bit_for_bit(
+        monkeypatch, x_dtype, y_dtype, put_dtype):
+    rng = np.random.default_rng(21)
+    x = (rng.normal(size=(BATCH, N)) / 3).astype(x_dtype)
+    y = rng.integers(0, 2, size=BATCH).astype(y_dtype)
+    if np.dtype(y_dtype).kind == "f":
+        y = y + np.asarray(0.1, y_dtype)  # rounds when cast to float32
+    put_from = []
+    real = streaming.jax.device_put
+
+    def device_put(value, device=None, **kwargs):
+        put_from.append(value)
+        return real(value, device, **kwargs)
+
+    monkeypatch.setattr(streaming.jax, "device_put", device_put)
+    ingest = streaming.IngestTrace()
+    _, x_dev, m_dev = ingest.put(_blocks(x, y), None, put_dtype)
+    assert m_dev is None
+    want = np.column_stack([x, y]).astype(put_dtype)
+    got = np.asarray(x_dev)
+    assert got.dtype == want.dtype and got.shape == (BATCH, N + 1)
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    # X crosses from the caller's rows where its dtype is the put's, the
+    # label column beside it
+    assert [a.shape for a in put_from] == [(BATCH, N), (BATCH, 1)]
+    assert np.shares_memory(put_from[0], x) == (x_dtype == put_dtype)
+
+
+def test_a_put_of_column_blocks_is_one_put(monkeypatch):
+    """One window slot, one landing (timed on the blocks, not on the
+    join), one ``batches``, the joined rows' bytes; a plain batch is
+    joined nowhere."""
+    for watcher in list(streaming._WATCHERS.values()):
+        # earlier fits' landings are not this put's
+        assert watcher.fence().wait(timeout=20)
+    seen = []
+
+    def landing(put):
+        seen.append(put)
+        jax.block_until_ready(put)
+
+    monkeypatch.setattr(streaming, "landing_of", landing)
+    x, y = _rows()
+    x, y = x[:BATCH].astype(np.float32), y[:BATCH]
+    ingest = streaming.IngestTrace()
+    c, x_dev, _ = ingest.put(_blocks(x, y), None, np.float32)
+    assert len(ingest.chips[c].in_flight) == 1
+    ingest.release()
+    ingest.all_landed()
+    counters = ingest.counters
+    assert counters["batches"] == counters["batches_joined_on_chip"] == 1
+    assert counters["per_chip"][c]["landings"] == 1
+    assert counters["bytes_put"] == BATCH * WIDTH * 4
+    assert counters["rows_put"] == BATCH
+    assert ingest.itemsize == 4
+    (put,) = seen
+    assert isinstance(put, tuple) and len(put) == 2
+    assert all(block is not x_dev for block in put)
+    assert [block.shape for block in put] == [(BATCH, N), (BATCH, 1)]
+
+    plain = streaming.IngestTrace()
+    plain.put(np.column_stack([x, y]), None, np.float32)
+    assert plain.counters["batches_joined_on_chip"] == 0
+    assert plain.counters["bytes_put"] == BATCH * WIDTH * 4
+
+
+@pytest.mark.parametrize("chunk_rows", [2 * BATCH, CHUNK],
+                         ids=["aligned", "uneven"])
+def test_the_chips_join_fits_what_the_hosts_join_fits(monkeypatch,
+                                                       chunk_rows):
+    """The same rows with every batch joined on the host (the chunks fed
+    as joined [X | y] arrays): the same device batches, so the same fit
+    bit for bit."""
+    x, y = _rows(seed=9)
+    on_chip = _estimator().fit(_aligned(x, y, chunk_rows))
+    assert on_chip.fit_report_.extra["ingest"]["batches_joined_on_chip"]
+    monkeypatch.setattr(linear_regression, "_zip_xy",
+                        lambda chunk: np.column_stack(chunk))
+    on_host = _estimator().fit(_aligned(x, y, chunk_rows))
+    ingest = on_host.fit_report_.extra["ingest"]
+    assert ingest["batches_joined_on_chip"] == 0
+    assert on_chip.n_iter_ == on_host.n_iter_ == 6
+    np.testing.assert_array_equal(on_chip.coefficients, on_host.coefficients)
+    assert on_chip.intercept == on_host.intercept
+
+
+def test_a_walk_nobody_traces_gets_joined_host_arrays():
+    """After a traced walk (views of the blocks) the same source walked
+    by a NumPy consumer gets joined arrays: the walk took its trace."""
+    x, y = _rows()
+    source = linear_regression._streaming_xy_source(_aligned(x, y), None,
+                                                    BATCH)
+    traced = [b for b, _ in streaming.IngestTrace().batches(source)]
+    assert sum(isinstance(b, ColumnBlocks) for b in traced) == BATCHES - 1
+    assert source.trace is None
+    untraced = list(source.batches())
+    assert not any(isinstance(b, ColumnBlocks) for b, _ in untraced)
+    z = np.concatenate([b if m is None else b[m] for b, m in untraced])
+    np.testing.assert_array_equal(z, np.column_stack([x, y]))
 
 
 @pytest.mark.parametrize("batch_rows", [0, 64], ids=["auto", "batchRows"])
@@ -289,6 +429,15 @@ def test_the_benchmarks_newton_names_are_what_the_program_emits():
     assert any(p in traced for p in work.PROGRAMS)
     assert not any(p in "jit_" + logreg_kernel.newton_step.__name__
                    for p in work.PROGRAMS)
+
+
+def test_no_roofline_reads_the_join_program():
+    """The chip's join of X and y is a copy, not a work module's program:
+    its traced name matches none of their ``PROGRAMS``."""
+    traced = "jit_" + streaming.join_columns.__name__
+    for name in ("gram", "lloyd", "newton", "collective"):
+        programs = _load(f"work/{name}.py").PROGRAMS
+        assert not any(p in traced for p in programs), name
 
 
 def test_the_in_memory_program_and_the_step_share_the_solve(monkeypatch):

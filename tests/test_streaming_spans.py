@@ -424,4 +424,8 @@ def test_no_other_tracked_accumulate_function_hides_in_streaming():
         "_update_stats_fused_blocked", "finalize_stats",
         # no accumulate program: one elementwise pass over the n x n sum
         # (``tests/test_streaming_shift.py`` holds its name off the list)
-        "recentre_gram"}
+        "recentre_gram",
+        # no accumulate program either: the chip's join of a labelled
+        # slice's X and y (``tests/test_logreg_streamed.py`` holds its name
+        # off every work module's list)
+        "join_columns"}
