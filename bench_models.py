@@ -9,7 +9,9 @@ RandomForest's histogram contractions depend on live-node occupancy, so
 it reports ``null`` rather than a made-up number.
 
 Methodology matches bench.py: on-device synthetic data, compile excluded
-by a warm-up run, a host read of the result as the completion fence. Run
+by a warm-up run, a host read of the result as the completion fence;
+LogisticRegression is timed as its estimator's fit of host rows, their
+crossing included. Run
 directly (``python bench_models.py``) in the one process that owns the
 chip.
 
@@ -85,20 +87,20 @@ def main() -> None:
     })
 
     # -- LogisticRegression: Newton-IRLS --------------------------------
-    from spark_rapids_ml_tpu.ops.logreg_kernel import logreg_fit_kernel
+    from spark_rapids_ml_tpu import LogisticRegression
 
     w_true = jax.random.normal(jax.random.PRNGKey(2), (cols,),
                                dtype=jnp.float32)
-    y = (x @ w_true > 0).astype(jnp.float32)
+    x_host = np.asarray(x)
+    y = np.asarray(x @ w_true > 0, dtype=np.float32)
     n_iter_cfg = 8
-    fence(logreg_fit_kernel(x, y, None, reg_param=1e-3,
-                            max_iter=n_iter_cfg, tol=0.0).coefficients)
+    est = (LogisticRegression().setRegParam(1e-3).setMaxIter(n_iter_cfg)
+           .setTol(0.0))
+    est.fit(x_host, y)  # compiles
     t0 = time.perf_counter()
-    r = logreg_fit_kernel(x, y, None, reg_param=1e-3,
-                          max_iter=n_iter_cfg, tol=0.0)
-    fence(r.coefficients)
+    r = est.fit(x_host, y)  # the fit's last read is its completion fence
     dt = time.perf_counter() - t0
-    it_done = int(np.asarray(r.n_iter))
+    it_done = r.n_iter_
     lr_rows = rows * max(it_done, 1) / dt
     # per iteration: XᵀWX (2nd²) + Xw, Xᵀr, Xᵀs (≈6nd)
     lr_flops = (2.0 * rows * cols * cols + 6.0 * rows * cols) * max(

@@ -55,6 +55,7 @@ print("logreg n_iter:", log.n_iter_)
 km = KMeans().setK(4).fit(chunks)
 print("kmeans cost:", round(km.training_cost_, 1))
 
-# An IN-MEMORY input needs no API change either: PCA.fit walks a matrix in
-# the same batches whatever its size; LinearRegression and KMeans stream one
+# An IN-MEMORY input needs no API change either: PCA, KMeans and binary
+# LogisticRegression walk a matrix (or a frame, its weightCol as the batches'
+# masks) in the same batches whatever its size; LinearRegression streams one
 # once it exceeds TPUML_STREAM_THRESHOLD_BYTES (default 1 GiB).

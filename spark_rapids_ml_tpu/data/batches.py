@@ -28,9 +28,10 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 # In-memory inputs larger than this stream through the device accumulator in
-# batch_rows buckets instead of one whole-matrix device_put (LinearRegression
-# and KMeans; PCA streams every input and does not ask). Default 1 GiB:
-# comfortably under a v5e chip's HBM while keeping small fits single-shot.
+# batch_rows buckets instead of one whole-matrix device_put (LinearRegression;
+# PCA, KMeans and binary LogisticRegression stream every input and do not
+# ask). Default 1 GiB: comfortably under a v5e chip's HBM while keeping small
+# fits single-shot.
 STREAM_THRESHOLD_ENV = "TPUML_STREAM_THRESHOLD_BYTES"
 DEFAULT_STREAM_THRESHOLD = 1 << 30
 
@@ -167,7 +168,8 @@ def streaming_source(dataset, batch_rows: int = 0,
     ``RecordBatch``) streams as its record batches, re-iterable.
 
     Materializable inputs (arrays, frames, pandas, lists of vectors) return
-    None — estimators decide separately whether to stream those by size.
+    None — the estimator densifies those and walks a ``BatchSource`` of the
+    matrix (LinearRegression only past ``stream_threshold_bytes``).
     """
     import pandas as pd
 
@@ -206,10 +208,17 @@ class BatchSource:
     for a full batch of a traced walk that is a row slice of the chunk:
     that one is yielded as its blocks, to be joined on the chip
     (``batches``).
+
+    A source with row ``weights`` (one a row, in the order the rows come)
+    yields each batch's slice of them as its mask instead, padded rows
+    weighing 0: the kernels multiply every row's part by the mask, so the
+    fit is the weighted one. Whoever reads the mask as "the rows that
+    count" reads ``mask > 0``.
     """
 
     def __init__(self, source, batch_rows: int = 0,
-                 n_features: Optional[int] = None, chunk_transform=None):
+                 n_features: Optional[int] = None, chunk_transform=None,
+                 weights: Optional[np.ndarray] = None):
         """``chunk_transform`` (chunk → 2-D array) runs on each raw chunk
         BEFORE re-blocking — callers with structured chunks (e.g.
         LinearRegression's (X, y) pairs) pass it here instead of wrapping
@@ -278,6 +287,11 @@ class BatchSource:
         )
         if self._matrix is not None:
             self.batch_rows = min(self.batch_rows, max(1, self._matrix.shape[0]))
+        self.weights = None  # padded to whole batches: batch i's are a view
+        if weights is not None:
+            self.weights = np.zeros(
+                -(-len(weights) // self.batch_rows) * self.batch_rows)
+            self.weights[:len(weights)] = weights
 
     @property
     def reiterable(self) -> bool:
@@ -361,7 +375,8 @@ class BatchSource:
         return batch
 
     def batches(self) -> Iterator[Tuple[np.ndarray, Optional[np.ndarray]]]:
-        """Yield fixed-shape ``(batch, mask)`` pairs; mask None = all valid.
+        """Yield fixed-shape ``(batch, mask)`` pairs; mask None = all valid,
+        a weighted source's mask its rows' weights (see the class).
 
         The walk takes ``self.trace`` (an ``ops.streaming.IngestTrace``,
         where a streamed fit has set one for it) and clears it, so a later
@@ -383,6 +398,15 @@ class BatchSource:
         shared, partially-exhausted underlying iterator (one the identity
         check in ``__init__`` cannot see, e.g. ``lambda: map(f, shared_gen)``)
         would otherwise silently zero out second-pass accumulations."""
+        walk = self._walk()
+        if self.weights is None:
+            yield from walk
+            return
+        b = self.batch_rows
+        for i, (batch, _) in enumerate(walk):
+            yield batch, self.weights[i * b:(i + 1) * b]
+
+    def _walk(self) -> Iterator[Tuple[np.ndarray, Optional[np.ndarray]]]:
         trace, self.trace = self.trace or _Untraced(), None
         traced = not isinstance(trace, _Untraced)
         counters = trace.counters
@@ -447,19 +471,27 @@ class BatchSource:
             )
 
 
+def weighted_rows(batch, mask) -> Tuple[np.ndarray, np.ndarray]:
+    """The rows of a host ``(batch, mask)`` pair that count (a padded row,
+    or one of weight 0, does not), as float64, and their weights: 1 each
+    where the source is unweighted."""
+    if mask is None:
+        return (np.asarray(batch, dtype=np.float64),
+                np.ones(batch.shape[0]))
+    counts = mask > 0
+    return (np.asarray(batch[counts], dtype=np.float64),
+            np.asarray(mask[counts], dtype=np.float64))
+
+
 def streamed_reduce(source, reducer, initial=None):
     """Fold valid rows of a streamed source through ``reducer(acc, rows)``
     — the one masked-iteration loop the host-streamed scaler fits share.
     ``rows`` arrives as float64 with padding removed; empty batches are
     skipped. Raises when the source held no rows at all."""
-    import numpy as np
-
     acc = initial
     seen = False
     for batch, mask in source.batches():
-        rows = np.asarray(
-            batch if mask is None else batch[mask], dtype=np.float64
-        )
+        rows, _ = weighted_rows(batch, mask)
         if rows.shape[0] == 0:
             continue
         acc = reducer(acc, rows)

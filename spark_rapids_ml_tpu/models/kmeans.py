@@ -2,19 +2,18 @@
 
 Second-algorithm coverage (BASELINE.md config 5). Param names follow Spark's
 ``org.apache.spark.ml.clustering.KMeans``: k, maxIter, tol, seed, initMode,
-featuresCol(=inputCol), predictionCol. An in-memory fit runs seeding +
-Lloyd entirely on device (one compiled program, ``ops/kmeans_kernel.py``);
-host fallback is a NumPy Lloyd with identical semantics for the
-no-accelerator case.
+featuresCol(=inputCol), predictionCol, weightCol.
 
-A re-iterable source (a callable of chunks, or a matrix over the streaming
-threshold) is fitted on the streamed loop PCA runs: ``data.batches
-.BatchSource`` in ``batchRows`` batches through ``ops.streaming.IngestTrace``
-(put window, staging, landing watcher). The rows cross once: the first walk
-puts every batch, keeps it on the chip while ``keep_budget_bytes`` has room
-and draws the initial centres; each Lloyd step then walks the kept batches
-(and puts the rest again) with one ``update_cluster_stats`` a batch and one
-host read, and a cost pass under the final centres ends the fit.
+Every fit runs on the streamed loop PCA runs: the input — a callable of
+chunks, or an array or frame walked as views of its matrix — is a
+``data.batches.BatchSource`` in ``batchRows`` batches through
+``ops.streaming.IngestTrace`` (put window, staging, landing watcher). The
+rows cross once: the first walk puts every batch, keeps it on the chip while
+``keep_budget_bytes`` has room and draws the initial centres; each Lloyd
+step then walks the kept batches (and puts the rest again) with one
+``update_cluster_stats`` a batch and one host read, and a cost pass under
+the final centres ends the fit. Row weights (``weightCol``) are the
+batches' masks. With ``useXlaDot`` off the same walks run in NumPy float64.
 """
 
 from __future__ import annotations
@@ -70,9 +69,9 @@ class KMeansParams(HasInputCol, HasDeviceId, HasWeightCol):
         "initMode",
         "initial centres: 'random' (k distinct rows drawn uniformly by "
         "seed, Spark's takeSample) or 'k-means||' (Spark's default name; "
-        "here k-means++ — on the device over an in-memory matrix, on the "
-        "host over a uniform sample of max(4096, 8k) rows of a streamed "
-        "source — the stand-in for Spark's parallel k-means||)",
+        "here k-means++ on the host over a uniform sample of up to "
+        "max(4096, 8k) rows — the stand-in for Spark's parallel "
+        "k-means||)",
         "k-means||", validator=lambda v: v in INIT_MODES)
     # weightCol (HasWeightCol): weighted Lloyd updates/cost and D^2*w
     # k-means++ sampling — Spark 3.0 weightCol semantics
@@ -111,52 +110,33 @@ class KMeans(KMeansParams):
         an iterable of row chunks (re-iterable — Lloyd walks the rows once
         a step, over the batches kept on the chip where it can)."""
         timer = PhaseTimer()
-        k = self.getK()
-
-        from spark_rapids_ml_tpu.data.batches import streaming_source
+        from spark_rapids_ml_tpu.data.batches import (
+            BatchSource,
+            streaming_source,
+        )
 
         source = streaming_source(dataset, self.getBatchRows())
-        weights = None
         if source is not None:
             self._reject_streamed_weights()
-        if source is None:
+        else:
             frame = as_vector_frame(dataset, self.getInputCol())
             with timer.phase("densify"):
                 x = frame.vectors_as_matrix(self.getInputCol())
-            weights = self._extract_weights(frame, x.shape[0])
-            from spark_rapids_ml_tpu.data.batches import (
-                BatchSource,
-                stream_threshold_bytes,
+            source = BatchSource(
+                x, batch_rows=self.getBatchRows(),
+                weights=self._extract_weights(frame, x.shape[0]))
+        if not source.reiterable:
+            raise ValueError(
+                "KMeans streaming requires a re-iterable source (a "
+                "zero-arg callable returning a fresh chunk iterator): "
+                "Lloyd makes one pass per iteration"
             )
-
-            if (self.getUseXlaDot() and weights is None
-                    and x.nbytes > stream_threshold_bytes()):
-                source = BatchSource(x, batch_rows=self.getBatchRows())
-
-        if source is not None:
-            if not source.reiterable:
-                raise ValueError(
-                    "KMeans streaming requires a re-iterable source (a "
-                    "zero-arg callable returning a fresh chunk iterator): "
-                    "Lloyd makes one pass per iteration"
-                )
-            rng = np.random.default_rng(self.getSeed())
-            init = (_RandomRows if self.getInitMode() == "random"
-                    else _SampledPlusPlus)(k, source.n_features, rng)
-            fit_streamed = (self._streamed_lloyd_xla if self.getUseXlaDot()
-                            else self._streamed_lloyd_host)
-            centers, cost, n_iter, initial = fit_streamed(source, init, timer)
-        else:
-            if k > x.shape[0]:
-                raise ValueError(
-                    f"k = {k} must be at most the number of rows {x.shape[0]}"
-                )
-            if self.getUseXlaDot():
-                centers, cost, n_iter, initial = self._fit_xla(
-                    x, k, timer, weights)
-            else:
-                centers, cost, n_iter, initial = self._fit_host(
-                    x, k, timer, weights)
+        rng = np.random.default_rng(self.getSeed())
+        init = (_RandomRows if self.getInitMode() == "random"
+                else _SampledPlusPlus)(self.getK(), source.n_features, rng)
+        fit = (self._streamed_lloyd_xla if self.getUseXlaDot()
+               else self._streamed_lloyd_host)
+        centers, cost, n_iter, initial = fit(source, init, timer)
         model = KMeansModel(cluster_centers=np.asarray(centers, dtype=np.float64))
         model.uid = self.uid
         model.copy_values_from(self)
@@ -166,50 +146,6 @@ class KMeans(KMeansParams):
         model.fit_timings_ = timer.as_dict()
         return model
 
-    def _random_centers(self, x, k, weights=None) -> np.ndarray:
-        """``initMode`` "random" over an in-memory matrix: the streamed
-        fit's draw (``_RandomRows``) over its rows with a positive weight,
-        as one batch."""
-        init = _RandomRows(k, x.shape[1], np.random.default_rng(self.getSeed()))
-        init.see(x if weights is None else x[np.asarray(weights) > 0], None)
-        return init.host_centers()
-
-    def _fit_xla(self, x, k, timer, weights=None):
-        import jax
-        import jax.numpy as jnp
-
-        from spark_rapids_ml_tpu.ops.kmeans_kernel import (
-            kmeans_fit_kernel,
-            kmeans_plus_plus_init,
-        )
-
-        device = _resolve_device(self.getDeviceId())
-        dtype = _resolve_dtype(self.getDtype())
-        with timer.phase("h2d"):
-            x_dev = jax.device_put(jnp.asarray(x, dtype=dtype), device)
-            # the kernels' mask slot multiplies the D^2 sampling logits,
-            # the one-hot cluster statistics, and the cost — passing the
-            # weights through it IS weighted k-means
-            w_dev = (
-                None
-                if weights is None
-                else jax.device_put(jnp.asarray(weights, dtype=dtype), device)
-            )
-        key = jax.random.PRNGKey(self.getSeed())
-        with timer.phase("fit_kernel"), TraceRange("kmeans lloyd", TraceColor.GREEN):
-            if self.getInitMode() == "random":
-                init = jax.device_put(jnp.asarray(
-                    self._random_centers(x, k, weights), dtype=dtype), device)
-            else:
-                init = kmeans_plus_plus_init(x_dev, k, key, mask=w_dev)
-            result = jax.block_until_ready(
-                kmeans_fit_kernel(
-                    x_dev, init, mask=w_dev,
-                    max_iter=self.getMaxIter(), tol=self.getTol()
-                )
-            )
-        return result.centers, result.cost, result.n_iter, init
-
     def _streamed_lloyd_xla(self, source, init, timer):
         """Lloyd on the streamed loop. The first walk (``lloyd:init``) puts
         every batch through ``IngestTrace.put`` and keeps it on the chip
@@ -217,14 +153,16 @@ class KMeans(KMeansParams):
         (``ops.kmeans_kernel.step_reserve_bytes``), not a Gram loop's — and
         ``init`` sees each batch, kept or not, to draw the initial centres.
         Each step (``lloyd:step``) walks the kept batches without letting
-        them go and puts the rest again (``IngestTrace.replay(drain=False)``,
-        pass 1's deal), one ``update_cluster_stats`` a batch into a donated
+        them go and puts the rest again (``IngestTrace.step_walk``, pass
+        1's deal), one ``update_cluster_stats`` a batch into a donated
         (Σx, count, cost) accumulator, then ``next_centers`` and one host
         read of the largest shift (``lloyd:sync``): the steps stop at
         ``maxIter`` or when every centre moved ≤ ``tol``. The reported cost
         is measured under the FINAL centers, by one more walk
         (``lloyd:cost``), as on the other fit paths. Counters under
-        ``fit_report_.extra["lloyd"]``; the ingest's under ``["ingest"]``."""
+        ``fit_report_.extra["lloyd"]``; the ingest's under ``["ingest"]``.
+        A weighted source's masks are its rows' weights: the accumulator's
+        counts are then weight sums, in ``dtype``."""
         import jax.numpy as jnp
 
         from spark_rapids_ml_tpu.obs.report import current_fit
@@ -234,61 +172,53 @@ class KMeans(KMeansParams):
         device = _resolve_device(self.getDeviceId())
         dtype = _resolve_dtype(self.getDtype())
         k, n = self.getK(), source.n_features
-        itemsize = jnp.dtype(dtype).itemsize
+        # int32 counts where every row weighs 1: exact past 2^24 rows per
+        # cluster
+        count_dtype = jnp.int32 if source.weights is None else dtype
         ingest = IngestTrace(timer, device)
         counters = {"steps": 0, "batches_from_kept": 0,
                     "batches_put_again": 0, "sync_seconds": 0.0}
         current_fit().note(lloyd=counters)  # filled as the fit goes
-        ingest.allow_keep(kk.step_reserve_bytes(source.batch_rows, k, n,
-                                                itemsize))
 
         def walk_stats(centers):
             """(Σx, count, cost) of every row under ``centers``: one walk."""
             carry = (jnp.zeros((k, n), dtype=dtype, device=device),
-                     # int32 counts: exact past 2^24 rows per cluster
-                     jnp.zeros((k,), dtype=jnp.int32, device=device),
+                     jnp.zeros((k,), dtype=count_dtype, device=device),
                      jnp.zeros((), dtype=dtype, device=device))
-            put_before, stepped = ingest.counters["batches"], 0
-            for _, x_dev, m_dev in ingest.replay(source, dtype, drain=False):
+            for _, x_dev, m_dev in ingest.step_walk(source, dtype, counters):
                 with ingest.stage(SPAN_DISPATCH, PHASE_DISPATCH):
                     carry = kk.update_cluster_stats(carry, centers, x_dev,
                                                     m_dev)
-                stepped += 1
-            put_again = ingest.counters["batches"] - put_before
-            counters["batches_put_again"] += put_again
-            counters["batches_from_kept"] += stepped - put_again
             return carry
 
-        try:
-            with timer.phase("fit_kernel"):
-                with timer.phase(PHASE_INIT), ingest.walk(SPAN_INIT):
-                    for batch, mask in ingest.batches(source):
-                        c, x_dev, m_dev = ingest.put(batch, mask, dtype)
-                        kept = ingest.keep(c, x_dev, m_dev)
-                        init.see(batch, mask,
-                                 len(ingest.kept) - 1 if kept else -1)
-                    centers = init.device_centers(ingest, dtype, device)
-                initial = centers
-                for _ in range(self.getMaxIter()):
-                    with ingest.walk(SPAN_STEP):
-                        centers, shift = kk.next_centers(
-                            walk_stats(centers), centers)
-                        with ingest.stage(SPAN_SYNC, PHASE_SYNC):
-                            moved = float(shift)
-                    counters["steps"] += 1
-                    counters["sync_seconds"] = timer.as_dict()[PHASE_SYNC]
-                    if moved <= self.getTol():
-                        break
-                with timer.phase(PHASE_COST), ingest.walk(SPAN_COST):
-                    cost = float(walk_stats(centers)[2])
-        finally:
-            ingest.release()  # no device batch outlives the walks over it
-        ingest.all_landed()  # the cost read every batch put
-        ingest.set_data(n)
+        reserve = kk.step_reserve_bytes(source.batch_rows, k, n,
+                                        jnp.dtype(dtype).itemsize)
+        with ingest.stepping(reserve, n), timer.phase("fit_kernel"):
+            with timer.phase(PHASE_INIT), ingest.walk(SPAN_INIT):
+                for batch, mask in ingest.batches(source):
+                    c, x_dev, m_dev = ingest.put(batch, mask, dtype)
+                    kept = ingest.keep(c, x_dev, m_dev)
+                    init.see(batch, mask, len(ingest.kept) - 1 if kept else -1)
+                centers = init.device_centers(ingest, dtype, device)
+            initial = centers
+            for _ in range(self.getMaxIter()):
+                with ingest.walk(SPAN_STEP):
+                    centers, shift = kk.next_centers(walk_stats(centers),
+                                                     centers)
+                    with ingest.stage(SPAN_SYNC, PHASE_SYNC):
+                        moved = float(shift)
+                counters["steps"] += 1
+                counters["sync_seconds"] = timer.as_dict()[PHASE_SYNC]
+                if moved <= self.getTol():
+                    break
+            with timer.phase(PHASE_COST), ingest.walk(SPAN_COST):
+                cost = float(walk_stats(centers)[2])
         return (np.asarray(centers), cost, counters["steps"],
                 np.asarray(initial))
 
     def _streamed_lloyd_host(self, source, init, timer):
+        from spark_rapids_ml_tpu.data.batches import weighted_rows
+
         with timer.phase("seed"), TraceRange("kmeans seed", TraceColor.ORANGE):
             for batch, mask in source.batches():
                 init.see(batch, mask)
@@ -301,22 +231,22 @@ class KMeans(KMeansParams):
             counts = np.zeros(k)
             cost = 0.0
             for batch, mask in source.batches():
-                b = np.asarray(batch if mask is None else batch[mask],
-                               dtype=np.float64)
+                b, w = weighted_rows(batch, mask)
                 d = _sqdist(b, c)
                 labels = d.argmin(axis=1)
-                np.add.at(sums, labels, b)
-                np.add.at(counts, labels, 1.0)
-                cost += float(d.min(axis=1).sum())
+                np.add.at(sums, labels, b * w[:, None])
+                np.add.at(counts, labels, w)
+                cost += float((d.min(axis=1) * w).sum())
             return sums, counts, cost
 
         n_iter = 0
         with timer.phase("fit_kernel"), TraceRange("kmeans host", TraceColor.ORANGE):
             for n_iter in range(1, self.getMaxIter() + 1):
                 sums, counts, _ = pass_stats(centers)
+                # by the cluster's whole weight, however small
                 new_centers = np.where(
                     counts[:, None] > 0,
-                    sums / np.maximum(counts, 1.0)[:, None],
+                    sums / np.where(counts > 0, counts, 1.0)[:, None],
                     centers,
                 )
                 moved = float(np.sqrt(
@@ -328,35 +258,6 @@ class KMeans(KMeansParams):
             _, _, cost = pass_stats(centers)
         return centers, cost, n_iter, initial
 
-    def _fit_host(self, x, k, timer, weights=None):
-        """NumPy Lloyd with the same init/update/empty-cluster semantics."""
-        rng = np.random.default_rng(self.getSeed())
-        w = np.ones(x.shape[0]) if weights is None else weights
-        with timer.phase("fit_kernel"), TraceRange("kmeans host", TraceColor.ORANGE):
-            if self.getInitMode() == "random":
-                centers = self._random_centers(x, k, weights)
-            else:
-                centers = _host_kmeans_pp(x, k, rng, weights=weights)
-            initial = centers
-            n_iter = 0
-            for n_iter in range(1, self.getMaxIter() + 1):
-                d = _sqdist(x, centers)
-                labels = d.argmin(axis=1)
-                new_centers = centers.copy()
-                for j in range(k):
-                    sel = labels == j
-                    wj = w[sel]
-                    if wj.sum() > 0:
-                        new_centers[j] = (
-                            (x[sel] * wj[:, None]).sum(axis=0) / wj.sum()
-                        )
-                moved = np.sqrt(((new_centers - centers) ** 2).sum(axis=1).max())
-                centers = new_centers
-                if moved <= self.getTol():
-                    break
-            cost = (_sqdist(x, centers).min(axis=1) * w).sum()
-        return centers, cost, n_iter, initial
-
 
 def _sqdist(x, centers):
     x2 = (x * x).sum(axis=1)[:, None]
@@ -364,20 +265,14 @@ def _sqdist(x, centers):
     return np.maximum(x2 + c2 - 2.0 * (x @ centers.T), 0.0)
 
 
-def _valid_rows(batch, mask) -> int:
-    """Rows of a ``BatchSource`` batch that are data: all of them, or the
-    head its mask marks (only a padded tail has one)."""
-    return batch.shape[0] if mask is None else int(np.count_nonzero(mask))
-
-
 class _RandomRows:
-    """``initMode`` "random", the one draw of every fit path: k distinct
-    rows drawn uniformly by ``seed`` while the rows are walked once —
-    Algorithm R over the row indices, one draw a row, a batch at a time (an
-    in-memory matrix is one batch) — so no walk is made for it. A row of a
-    batch a streamed fit keeps on the chip is remembered by
-    where it is and gathered there (``ops.kmeans_kernel.take_rows``); any
-    other is copied from the host batch while the walk has it."""
+    """``initMode`` "random", the one draw of every fit: k distinct rows
+    with a weight drawn uniformly by ``seed`` while the rows are walked
+    once — Algorithm R over the row indices, one draw a row, a batch at a
+    time — so no walk is made for it. A row of a batch the fit keeps on
+    the chip is remembered by where it is and gathered there
+    (``ops.kmeans_kernel.take_rows``); any other is copied from the host
+    batch while the walk has it."""
 
     def __init__(self, k: int, n_features: int, rng):
         self.k, self.rng, self.seen = k, rng, 0
@@ -387,19 +282,21 @@ class _RandomRows:
 
     def see(self, batch, mask, kept: int = -1) -> None:
         """The walk's next batch; ``kept``: its index among the batches the
-        fit keeps, -1 where it keeps it not."""
-        valid = _valid_rows(batch, mask)
-        t = self.seen + np.arange(valid)
+        fit keeps, -1 where it keeps it not. Its rows are those the mask
+        gives a weight: all, where it has none."""
+        valid = np.arange(batch.shape[0]) if mask is None \
+            else np.flatnonzero(mask > 0)
+        t = self.seen + np.arange(valid.size)
         slot = np.where(t < self.k, t, self.rng.integers(0, t + 1))
         hit = np.flatnonzero(slot < self.k)
         # a later row of the batch takes a slot over from an earlier one
         slots, last = np.unique(slot[hit][::-1], return_index=True)
-        rows = hit[::-1][last]
+        rows = valid[hit[::-1][last]]
         if kept < 0:
             self.host[slots] = batch[rows]
         self.kept[slots] = kept
         self.row[slots] = rows
-        self.seen += valid
+        self.seen += valid.size
 
     def _check(self) -> None:
         if self.k > self.seen:
@@ -426,10 +323,10 @@ class _RandomRows:
 
 
 class _SampledPlusPlus:
-    """``initMode`` "k-means||" on a streamed source — the stand-in for
-    Spark's parallel k-means||: a uniform sample of up to max(4096, 8k)
-    rows drawn while the rows are walked once, then k-means++ over it on
-    the host in float64 (``_host_kmeans_pp``).
+    """``initMode`` "k-means||" — the stand-in for Spark's parallel
+    k-means||: a uniform sample of up to max(4096, 8k) rows drawn while the
+    rows are walked once, then k-means++ over it on the host in float64
+    (``_host_kmeans_pp``), each sampled row with its weight.
 
     The sample is a vectorized batch reservoir: row t (0-based global
     index) replaces a random slot with probability size/(t+1) — per-batch
@@ -440,16 +337,24 @@ class _SampledPlusPlus:
         self.k, self.rng = k, rng
         self.size = max(4096, 8 * k)
         self.reservoir = np.empty((self.size, n_features), dtype=np.float64)
+        self.weights = np.ones(self.size)
+        self.weighted = False
         self.filled = self.seen = 0
 
     def see(self, batch, mask, kept: int = -1) -> None:
-        rows = batch if mask is None else batch[mask]
+        """The walk's next batch: its rows the mask gives a weight."""
+        rows, weights = batch, np.ones(batch.shape[0])
+        if mask is not None:
+            counts = mask > 0
+            rows, weights = batch[counts], np.asarray(mask[counts], float)
+            self.weighted |= mask.dtype != bool
         take = min(self.size - self.filled, rows.shape[0])
         if take > 0:
             self.reservoir[self.filled:self.filled + take] = rows[:take]
+            self.weights[self.filled:self.filled + take] = weights[:take]
             self.filled += take
             self.seen += take
-            rows = rows[take:]
+            rows, weights = rows[take:], weights[take:]
         if rows.shape[0] == 0:
             return
         t = self.seen + np.arange(rows.shape[0])
@@ -458,6 +363,7 @@ class _SampledPlusPlus:
         if idx.size:
             slots = self.rng.integers(0, self.size, size=idx.size)
             self.reservoir[slots] = rows[idx]
+            self.weights[slots] = weights[idx]
         self.seen += rows.shape[0]
 
     def host_centers(self) -> np.ndarray:
@@ -467,7 +373,9 @@ class _SampledPlusPlus:
                 f"k = {self.k} must be at most the number of rows "
                 f"{sample.shape[0]}"
             )
-        return _host_kmeans_pp(sample, self.k, self.rng)
+        return _host_kmeans_pp(
+            sample, self.k, self.rng,
+            weights=self.weights[:self.filled] if self.weighted else None)
 
     def device_centers(self, ingest, dtype, device):
         import jax

@@ -349,19 +349,24 @@ def _streaming_xy_source(dataset, labels, batch_rows: int = 0):
     return None
 
 
-def _xy_batch_source(x: np.ndarray, y: np.ndarray):
-    """Re-iterable Z=[X|y] source over big in-memory arrays, chunk-wise (no
-    whole-matrix hstack copy)."""
+def _xy_batch_source(x: np.ndarray, y: np.ndarray, batch_rows: int = 0,
+                     weights: Optional[np.ndarray] = None):
+    """Re-iterable Z=[X|y] source over in-memory arrays, chunk-wise (no
+    whole-matrix hstack copy): chunks of ``batch_rows`` rows (0:
+    auto-sized, ≈128 MiB; at most the rows there are), so a traced walk's
+    every full batch is a row slice of one chunk, put as its X and y and
+    joined on the chip. ``weights``: the rows' (``BatchSource``)."""
     from spark_rapids_ml_tpu.data.batches import BatchSource, auto_batch_rows
 
-    rows = auto_batch_rows(x.shape[1] + 1)
+    rows = min(batch_rows or auto_batch_rows(x.shape[1] + 1),
+               max(1, x.shape[0]))
 
     def chunks():
         for i in range(0, x.shape[0], rows):
             yield (x[i:i + rows], y[i:i + rows])
 
     return BatchSource(chunks, batch_rows=rows, n_features=x.shape[1] + 1,
-                       chunk_transform=_zip_xy)
+                       chunk_transform=_zip_xy, weights=weights)
 
 
 class LinearRegressionModel(LinearRegressionParams):

@@ -1,16 +1,18 @@
 """LogisticRegression Estimator / Model (binary, L2, Newton-IRLS).
 
 Spark ``org.apache.spark.ml.classification.LogisticRegression`` param
-surface subset: featuresCol(=inputCol), labelCol, predictionCol,
+surface subset: featuresCol(=inputCol), labelCol, weightCol, predictionCol,
 probabilityCol, maxIter, tol, regParam (L2 / elasticNetParam=0),
 fitIntercept — the same objective convention ((1/n)·logloss + λ/2·||w||²,
-intercept unpenalized). Accelerated path: Newton-IRLS compiled into one
-XLA program (``ops/logreg_kernel.py``); host fallback is a NumPy IRLS
-with identical math. A binary fit over a re-iterable source of ``(X, y)``
-chunks runs on the streamed loop PCA and KMeans run: the labelled rows
-cross once in ``batchRows`` batches and stay on the chip where they fit,
-and each Newton step sums its weighted Gram over them and solves on the
-chip (``_newton_streamed_xla``).
+intercept unpenalized). Every binary L2 fit runs on the streamed loop PCA
+and KMeans run (``_newton_streamed_xla``): the input — a re-iterable source
+of ``(X, y)`` chunks, or an array or frame walked as views of its X and y —
+crosses once as ``[X | y]`` batches of ``batchRows`` rows that stay on the
+chip where they fit, and each Newton step sums its weighted Gram over them
+and solves on the chip. Row weights (``weightCol``) are the batches' masks.
+With ``useXlaDot`` off the same walks run in NumPy float64. More than two
+classes select the softmax family; ``elasticNetParam`` > 0 the proximal
+Newton of ``_fit_elastic``.
 """
 
 from __future__ import annotations
@@ -111,37 +113,19 @@ class LogisticRegression(LogisticRegressionParams):
         timer = PhaseTimer()
         from spark_rapids_ml_tpu.models.linear_regression import (
             _streaming_xy_source,
+            _xy_batch_source,
         )
 
+        alpha = float(self.getElasticNetParam())
+        elastic = alpha > 0.0 and float(self.getRegParam()) > 0.0
         source = _streaming_xy_source(dataset, labels, self.getBatchRows())
         if source is not None:
             self._reject_streamed_weights()
-            if (float(self.getElasticNetParam()) > 0.0
-                    and float(self.getRegParam()) > 0.0):
+            if elastic:
                 raise ValueError(
                     "elasticNetParam > 0 is not supported on streamed/"
                     "out-of-core fits yet; fit in-memory or set "
                     "elasticNetParam=0"
-                )
-            # optimistic binary first — the common case pays no extra
-            # pass; Spark's family="auto" kicks in when iteration 1's
-            # label validation sees more than two classes
-            try:
-                coef, intercept, n_iter = self._fit_streamed(source, timer)
-            except _NonBinaryLabelsError:
-                classes = _streamed_classes(source)
-                if classes.size <= 2:
-                    # two or fewer distinct values that are not {0,1}:
-                    # genuinely bad binary labels, not a multiclass target
-                    raise
-                if classes.size > 100:
-                    raise ValueError(
-                        f"{classes.size} distinct label values: looks like "
-                        "a continuous target, not classes (multinomial "
-                        "supports up to 100)"
-                    )
-                return self._fit_multinomial_streamed(
-                    source, classes, timer
                 )
         else:
             frame = as_vector_frame(dataset, self.getInputCol())
@@ -175,15 +159,33 @@ class LogisticRegression(LogisticRegressionParams):
                     x, y, classes, weights, timer
                 )
             _check_binary(y)
-            alpha = float(self.getElasticNetParam())
-            if alpha > 0.0 and float(self.getRegParam()) > 0.0:
+            if elastic:
                 coef, intercept, n_iter = self._fit_elastic(
                     x, y, timer, weights, alpha
                 )
-            elif self.getUseXlaDot():
-                coef, intercept, n_iter = self._fit_xla(x, y, timer, weights)
             else:
-                coef, intercept, n_iter = self._fit_host(x, y, timer, weights)
+                source = _xy_batch_source(x, y, self.getBatchRows(), weights)
+        if source is not None:
+            # optimistic binary first — the common case pays no extra
+            # pass; Spark's family="auto" kicks in when iteration 1's
+            # label validation sees more than two classes
+            try:
+                coef, intercept, n_iter = self._fit_streamed(source, timer)
+            except _NonBinaryLabelsError:
+                classes = _streamed_classes(source)
+                if classes.size <= 2:
+                    # two or fewer distinct values that are not {0,1}:
+                    # genuinely bad binary labels, not a multiclass target
+                    raise
+                if classes.size > 100:
+                    raise ValueError(
+                        f"{classes.size} distinct label values: looks like "
+                        "a continuous target, not classes (multinomial "
+                        "supports up to 100)"
+                    )
+                return self._fit_multinomial_streamed(
+                    source, classes, timer
+                )
         model = LogisticRegressionModel(
             coefficients=np.asarray(coef, dtype=np.float64),
             intercept=float(intercept),
@@ -403,7 +405,8 @@ class LogisticRegression(LogisticRegressionParams):
                         z_dev, w, b, w_mask, device, dtype, fit_b
                     )
                 else:
-                    g, h = _full_grad_hess(x, y, w, b, 0.0, fit_b, weights)
+                    g, h = _assemble_newton(*_newton_partials(
+                        x, y, w, b, weights), w, 0.0, fit_b)
                 # curvature floor: on (near-)separable data the IRLS
                 # weights underflow and the lam=0 Hessian collapses,
                 # leaving the L1 subproblem unbounded along the
@@ -425,51 +428,6 @@ class LogisticRegression(LogisticRegressionParams):
                     break
         return w, b, n_iter
 
-    def _fit_xla(self, x, y, timer, weights=None):
-        import jax
-        import jax.numpy as jnp
-
-        from spark_rapids_ml_tpu.ops.logreg_kernel import logreg_fit_kernel
-
-        device = _resolve_device(self.getDeviceId())
-        dtype = _resolve_dtype(self.getDtype())
-        with timer.phase("h2d"):
-            x_dev = jax.device_put(jnp.asarray(x, dtype=dtype), device)
-            y_dev = jax.device_put(jnp.asarray(y, dtype=dtype), device)
-            # the kernel's mask multiplies residual, IRLS weights, and the
-            # count — exactly the weighted MLE (Spark's weightCol)
-            w_dev = (
-                None
-                if weights is None
-                else jax.device_put(jnp.asarray(weights, dtype=dtype), device)
-            )
-        with timer.phase("fit_kernel"), TraceRange("logreg newton", TraceColor.GREEN):
-            result = jax.block_until_ready(
-                logreg_fit_kernel(
-                    x_dev, y_dev, w_dev,
-                    reg_param=float(self.getRegParam()),
-                    fit_intercept=self.getFitIntercept(),
-                    max_iter=self.getMaxIter(),
-                    tol=float(self.getTol()),
-                )
-            )
-        return result.coefficients, result.intercept, result.n_iter
-
-    def _fit_host(self, x, y, timer, weights=None):
-        """NumPy Newton-IRLS, same objective and update rule."""
-        with timer.phase("fit_kernel"), TraceRange("logreg host", TraceColor.ORANGE):
-            coef, intercept, n_iter = _host_newton(
-                lambda w, b: _full_grad_hess(
-                    x, y, w, b, float(self.getRegParam()),
-                    self.getFitIntercept(), weights,
-                ),
-                x.shape[1],
-                self.getMaxIter(),
-                float(self.getTol()),
-                self.getFitIntercept(),
-            )
-        return coef, intercept, n_iter
-
     def _fit_streamed(self, source, timer):
         """Newton with one walk over a re-iterable source a step: on the
         streamed loop (``_newton_streamed_xla``), or in NumPy float64
@@ -482,30 +440,23 @@ class LogisticRegression(LogisticRegressionParams):
             )
         if self.getUseXlaDot():
             return self._newton_streamed_xla(source, timer)
+        from spark_rapids_ml_tpu.data.batches import weighted_rows
+
         n = source.n_features - 1     # the last column is the label
         lam = float(self.getRegParam())
         fit_b = self.getFitIntercept()
 
         def grad_hess(w, b):
-            carry = [np.zeros(n), np.zeros((n, n)), np.zeros(n),
-                     0.0, 0.0, 0.0]
+            total = [np.zeros(n), np.zeros((n, n)), np.zeros(n), 0.0, 0.0,
+                     0.0]
             for batch, mask in source.batches():
-                zb = np.asarray(batch if mask is None else batch[mask],
-                                dtype=np.float64)
-                xb, yb = zb[:, :n], zb[:, n]
+                zb, weights = weighted_rows(batch, mask)
                 # labels checked on every walk: the host plane is the slow
                 # one anyway, and the first walk is the one that raises
-                _check_binary(yb)
-                p = _sigmoid(xb @ w + b)
-                r = p - yb
-                s = p * (1.0 - p)
-                carry[0] += xb.T @ r
-                carry[1] += xb.T @ (xb * s[:, None])
-                carry[2] += xb.T @ s
-                carry[3] += float(r.sum())
-                carry[4] += float(s.sum())
-                carry[5] += float(len(yb))
-            return _assemble_newton(*carry, w, lam, fit_b)
+                _check_binary(zb[:, n])
+                total = [t + p for t, p in zip(total, _newton_partials(
+                    zb[:, :n], zb[:, n], w, b, weights))]
+            return _assemble_newton(*total, w, lam, fit_b)
 
         with timer.phase("fit_kernel"), TraceRange(
                 "logreg streamed", TraceColor.ORANGE):
@@ -524,13 +475,14 @@ class LogisticRegression(LogisticRegressionParams):
         step's own (``ops.logreg_kernel.step_reserve_bytes``) — and folds
         step 1's partials as it lands. Each later step (``newton:step``)
         walks the kept batches without letting them go and puts the rest
-        again (``IngestTrace.replay(drain=False)``). A step is one
+        again (``IngestTrace.step_walk``). A step is one
         ``update_logreg_stats`` a batch (``newton:dispatch``) into a
         donated accumulator, then ``newton_step``, which assembles the
         system and solves it on the chip, and one host read of max|Δ|
         (``newton:sync``): the steps stop at ``maxIter`` or when max|Δ| ≤
         ``tol``. w and b are fetched once, at the end. Counters under
         ``fit_report_.extra["newton"]``; the ingest's under ``["ingest"]``.
+        A weighted source's masks are its rows' weights.
         """
         import jax
         import jax.numpy as jnp
@@ -547,14 +499,8 @@ class LogisticRegression(LogisticRegressionParams):
         ingest = IngestTrace(timer, device)
         counters = {"steps": 0, "batches_from_kept": 0,
                     "batches_put_again": 0, "sync_seconds": 0.0,
-                    "solve_seconds": 0.0, "final_step_max": None,
-                    # the step's weighted Gram: its column panels, and
-                    # their MXU work ÷ the full n × n product's
-                    "gram_panels": len(lk.gram_panels(n)),
-                    "gram_work_share": lk.gram_work_share(n)}
+                    "solve_seconds": 0.0, "final_step_max": None}
         current_fit().note(newton=counters)  # filled as the fit goes
-        ingest.allow_keep(lk.step_reserve_bytes(
-            source.batch_rows, source.n_features, jnp.dtype(dtype).itemsize))
 
         def first_walk():
             for batch, mask in ingest.batches(source):
@@ -562,54 +508,42 @@ class LogisticRegression(LogisticRegressionParams):
                 # as a block of its own
                 y = batch.blocks[-1] if isinstance(batch, ColumnBlocks) \
                     else batch[:, -1:]
-                labels = y[:, 0] if mask is None else y[mask, 0]
+                labels = y[:, 0] if mask is None else y[mask > 0, 0]
                 _check_binary(np.asarray(labels, dtype=np.float64))
                 c, x_dev, m_dev = ingest.put(batch, mask, dtype)
                 ingest.keep(c, x_dev, m_dev)
-                yield x_dev, m_dev
-
-        def later_walk():
-            put_before, stepped = ingest.counters["batches"], 0
-            for _, x_dev, m_dev in ingest.replay(source, dtype, drain=False):
-                stepped += 1
-                yield x_dev, m_dev
-            put_again = ingest.counters["batches"] - put_before
-            counters["batches_put_again"] += put_again
-            counters["batches_from_kept"] += stepped - put_again
+                yield c, x_dev, m_dev
 
         coef = jnp.zeros((n,), dtype=dtype, device=device)
         b = jnp.zeros((), dtype=dtype, device=device)
-        try:
-            with timer.phase("fit_kernel"):
-                for step in range(self.getMaxIter()):
-                    first = step == 0
-                    with timer.phase(PHASE_INIT if first else PHASE_STEP), \
-                            ingest.walk(SPAN_INIT if first else SPAN_STEP):
-                        carry = lk.init_logreg_carry(n, dtype, device)
-                        for x_dev, m_dev in (first_walk() if first
-                                             else later_walk()):
-                            with ingest.stage(SPAN_DISPATCH, PHASE_DISPATCH):
-                                carry = lk.update_logreg_stats(
-                                    carry, x_dev, coef, b, m_dev)
-                        coef, b, moved = lk.newton_step(
-                            carry, coef, b, lam, fit_intercept=fit_b)
-                        with ingest.stage(SPAN_SYNC, PHASE_SYNC):
-                            jax.block_until_ready(carry)  # the step's Gram
-                            summed = time.perf_counter()
-                            moved = float(moved)
-                        counters["solve_seconds"] += \
-                            time.perf_counter() - summed
-                    counters["steps"] += 1
-                    counters["sync_seconds"] = timer.as_dict()[PHASE_SYNC]
-                    counters["final_step_max"] = moved
-                    if moved <= float(self.getTol()):
-                        break
-                with timer.phase("fetch"):
-                    coef, b = jax.device_get((coef, b))
-        finally:
-            ingest.release()  # no device batch outlives the walks over it
-        ingest.all_landed()  # max|Δ| was read behind every batch put
-        ingest.set_data(n)
+        reserve = lk.step_reserve_bytes(
+            source.batch_rows, source.n_features, jnp.dtype(dtype).itemsize)
+        with ingest.stepping(reserve, n), timer.phase("fit_kernel"):
+            for step in range(self.getMaxIter()):
+                first = step == 0
+                with timer.phase(PHASE_INIT if first else PHASE_STEP), \
+                        ingest.walk(SPAN_INIT if first else SPAN_STEP):
+                    carry = lk.init_logreg_carry(n, dtype, device)
+                    walk = first_walk() if first else ingest.step_walk(
+                        source, dtype, counters)
+                    for _, x_dev, m_dev in walk:
+                        with ingest.stage(SPAN_DISPATCH, PHASE_DISPATCH):
+                            carry = lk.update_logreg_stats(
+                                carry, x_dev, coef, b, m_dev)
+                    coef, b, moved = lk.newton_step(
+                        carry, coef, b, lam, fit_intercept=fit_b)
+                    with ingest.stage(SPAN_SYNC, PHASE_SYNC):
+                        jax.block_until_ready(carry)  # the step's Gram
+                        summed = time.perf_counter()
+                        moved = float(moved)
+                    counters["solve_seconds"] += time.perf_counter() - summed
+                counters["steps"] += 1
+                counters["sync_seconds"] = timer.as_dict()[PHASE_SYNC]
+                counters["final_step_max"] = moved
+                if moved <= float(self.getTol()):
+                    break
+            with timer.phase("fetch"):
+                coef, b = jax.device_get((coef, b))
         return np.asarray(coef), float(b), counters["steps"]
 
 
@@ -696,21 +630,20 @@ def _check_binary(y: np.ndarray, estimator: str = "LogisticRegression") -> None:
         )
 
 
-def _full_grad_hess(x, y, w, b, lam, fit_intercept, weights=None):
-    z = x @ w + b
-    p = _sigmoid(z)
+def _newton_partials(x, y, w, b, weights=None):
+    """One block of rows' Newton partials at (w, b): (Xᵀr, XᵀWX, Xᵀs, Σr,
+    Σs, n) with r = σ(Xw + b) − y and s = σ'(Xw + b), each row's part times
+    its weight — additive across blocks, as ``ops.logreg_kernel
+    .logreg_raw_stats`` on the chip."""
+    p = _sigmoid(x @ w + b)
     r = p - y
     s = p * (1.0 - p)
     if weights is not None:
         r = r * weights
         s = s * weights
-    gx = x.T @ r
-    hxx = x.T @ (x * s[:, None])
     cnt = float(len(y)) if weights is None else float(np.sum(weights))
-    return _assemble_newton(
-        gx, hxx, x.T @ s, float(r.sum()), float(s.sum()), cnt,
-        w, lam, fit_intercept,
-    )
+    return (x.T @ r, x.T @ (x * s[:, None]), x.T @ s, float(r.sum()),
+            float(s.sum()), cnt)
 
 
 def _assemble_newton(gx, hxx, hxb, rsum, ssum, cnt, w, lam, fit_intercept):

@@ -250,10 +250,13 @@ def update_cluster_stats(
 def next_centers(carry, centers: jnp.ndarray):
     """The end of one streamed Lloyd step: (each centre the mean of the rows
     the step gave it — an empty cluster keeps its centre —, the largest
-    distance a centre moved). One program, whose scalar the fit reads."""
+    distance a centre moved). One program, whose scalar the fit reads.
+    ``counts`` are weight sums where the rows are weighted: a centre is
+    divided by its cluster's whole weight, however far under 1."""
     sums, counts, _ = carry
     new = jnp.where(counts[:, None] > 0,
-                    sums / jnp.maximum(counts, 1).astype(sums.dtype)[:, None],
+                    sums / jnp.where(counts > 0, counts, 1).astype(
+                        sums.dtype)[:, None],
                     centers)
     return new, jnp.sqrt(jnp.max(jnp.sum((new - centers) ** 2, axis=1)))
 

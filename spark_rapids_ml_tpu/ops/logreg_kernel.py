@@ -11,12 +11,14 @@ regression with L2, in Spark ML's objective convention:
 solved by Newton-IRLS — each iteration is two MXU matmuls (the logits
 ``X·w`` and the weighted Hessian ``Xᵀdiag(σ')X``) plus an (n+1)² Cholesky
 solve, the same "big matmul + small dense solve" shape as every other
-algorithm here. The iteration is a ``lax.while_loop`` compiled into the
-program; masked (padding) rows contribute nothing to loss, gradient, or
-Hessian. A streamed fit runs the same step as two programs: the partials
-folded in a batch at a time (``update_logreg_stats``), then the system
-assembled and solved on the chip (``newton_step``) — the assembly and the
-Cholesky solve are ``newton_delta`` in both.
+algorithm here. A row's part is multiplied by its mask: 0 for padding,
+its weight for a weighted fit. The estimator's fit runs a step as two
+programs: the partials folded in a batch at a time
+(``update_logreg_stats``), then the system assembled and solved on the
+chip (``newton_step``). The mesh fit (``parallel.distributed_logreg``)
+compiles its iterations into one ``lax.while_loop`` program
+(``newton_iterations``); the assembly and the Cholesky solve are
+``newton_delta`` in both.
 """
 
 from __future__ import annotations
@@ -51,12 +53,6 @@ def gram_panels(n: int) -> tuple:
     features into: one panel where n ≤ ``GRAM_PANEL``."""
     return tuple((s, min(GRAM_PANEL, n - s))
                  for s in range(0, n, GRAM_PANEL))
-
-
-def gram_work_share(n: int) -> float:
-    """The panels' MXU work ÷ the full n × n product's (1.0 for one
-    panel)."""
-    return sum(w * (n - s) for s, w in gram_panels(n)) / (n * n)
 
 
 def weighted_gram(x, s):
@@ -97,7 +93,7 @@ def newton_delta(stats, w, reg_param, fit_intercept):
     row), by Cholesky: g and H scaled by 1/n, λ on the coefficients, the
     intercept unpenalised. ``w`` is (n+1,): coefficients ++ intercept slot
     (zero-pinned when ``fit_intercept`` is False: unit diagonal, zero
-    gradient). Shared by the in-memory program and the streamed fit's
+    gradient). Shared by the mesh fit's program and the streamed fit's
     finishing program."""
     gx, hxx, hxb, rsum, ssum, cnt = stats
     n_feat = gx.shape[0]
@@ -157,21 +153,6 @@ def newton_iterations(
             jnp.asarray(0, dtype=jnp.int32), jnp.asarray(False))
     w, _, n_iter, converged = lax.while_loop(cond, step, init)
     return LogRegResult(w[:n_feat], w[n_feat], n_iter, converged)
-
-
-@partial(jax.jit, static_argnames=("fit_intercept", "max_iter"))
-def logreg_fit_kernel(
-    x: jnp.ndarray,
-    y: jnp.ndarray,
-    mask: Optional[jnp.ndarray] = None,
-    reg_param: float = 0.0,
-    fit_intercept: bool = True,
-    max_iter: int = 100,
-    tol: float = 1e-8,
-) -> LogRegResult:
-    return newton_iterations(
-        x, y, mask, reg_param, fit_intercept, max_iter, tol
-    )
 
 
 def init_logreg_carry(n: int, dtype, device=None):

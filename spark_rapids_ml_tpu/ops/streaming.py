@@ -879,7 +879,8 @@ class IngestTrace:
         for counters in (self.counters, chip.counters):
             counters["puts_in_flight_max"] = max(
                 counters["puts_in_flight_max"], len(chip.in_flight))
-        self.put_rows = rows if mask is None else int(mask.sum())
+        # a mask of weights: a row of weight 0 counts as none
+        self.put_rows = rows if mask is None else int(np.count_nonzero(mask))
         self._count_rows(c, self.put_rows)
         self.itemsize = host[0].itemsize
         self.counters["batches"] += 1
@@ -939,6 +940,36 @@ class IngestTrace:
                 self.turn += 1
             else:
                 yield self.put(batch, mask, dtype)
+
+    def step_walk(self, source, dtype, counters: dict):
+        """A later walk of a fit that walks its rows once a step (a Lloyd
+        or Newton step): ``replay`` with the kept batches kept, counting
+        in the fit's ``counters`` how many of the walk's device batches
+        were kept ones (``batches_from_kept``) and how many were put again
+        (``batches_put_again``)."""
+        put_before, stepped = self.counters["batches"], 0
+        for item in self.replay(source, dtype, drain=False):
+            stepped += 1
+            yield item
+        put_again = self.counters["batches"] - put_before
+        counters["batches_put_again"] += put_again
+        counters["batches_from_kept"] += stepped - put_again
+
+    @contextlib.contextmanager
+    def stepping(self, reserve_nbytes: int, n_features: int):
+        """The life of a fit that walks its rows once a step: its keep
+        sized beside the step's own reserve (``allow_keep``), no device
+        batch outliving the walks (``release``, whatever happens), and at
+        the end every put landed (``all_landed``: the fit's last read, a
+        step's or the cost's, was of a value every put fed) and the
+        dataset counted (``set_data``)."""
+        self.allow_keep(reserve_nbytes)
+        try:
+            yield
+        finally:
+            self.release()
+        self.all_landed()
+        self.set_data(n_features)
 
     def release(self) -> None:
         """Let go of every device batch held here (kept for pass 2, or in a

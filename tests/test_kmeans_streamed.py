@@ -6,9 +6,10 @@ The CPU reports no ``memory_stats()``, so nothing is kept here unless a
 test stands in for the chip through the seam ``streaming.keep_budget_bytes``
 (as ``tests/test_streaming_keep.py`` does for PCA): all, some or none of the
 batches kept, the fit has to be plain Lloyd from its own initial centres —
-a NumPy float64 Lloyd over the same rows, step for step. The benchmark's
-names (``benchmarks/work/lloyd.py``) are held against what the program
-emits.
+a NumPy float64 Lloyd over the same rows, step for step. An array, a
+frame (with row weights or without) and a callable all walk that loop. The
+benchmark's names (``benchmarks/work/lloyd.py``) are held against what the
+program emits.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ import pytest
 
 from spark_rapids_ml_tpu import KMeans, PCA
 from spark_rapids_ml_tpu.data.batches import BatchSource
+from spark_rapids_ml_tpu.data.frame import as_vector_frame
 from spark_rapids_ml_tpu.models import kmeans as kmeans_module
 from spark_rapids_ml_tpu.obs import spans as obs_spans
 from spark_rapids_ml_tpu.ops import kmeans_kernel, streaming
@@ -53,11 +55,13 @@ def _fit(x, budget=None, monkeypatch=None, **params):
     return est.fit(_factory(x))
 
 
-def _plain_lloyd(x, centres, steps):
+def _plain_lloyd(x, centres, steps, weights=None):
     """(centres, cost) after ``steps`` NumPy float64 Lloyd steps from
-    ``centres`` (an empty cluster keeps its centre), the cost under the
-    last centres."""
+    ``centres`` (a cluster of no weight keeps its centre), the cost under
+    the last centres: each centre its rows' weighted mean and each row's
+    distance times its weight where rows have ``weights``."""
     c = np.array(centres, dtype=np.float64)
+    v = np.ones(len(x)) if weights is None else weights
 
     def d2(c):
         return ((x[:, None, :] - c[None]) ** 2).sum(-1)
@@ -65,13 +69,15 @@ def _plain_lloyd(x, centres, steps):
     for _ in range(steps):
         nearest = d2(c).argmin(1)
         for j in range(len(c)):
-            if np.any(nearest == j):
-                c[j] = x[nearest == j].mean(0)
-    return c, d2(c).min(1).sum()
+            mine = nearest == j
+            if v[mine].sum() > 0:
+                c[j] = (x[mine] * v[mine, None]).sum(0) / v[mine].sum()
+    return c, (d2(c).min(1) * v).sum()
 
 
-def _assert_plain_lloyd(model, x):
-    want, cost = _plain_lloyd(x, model.initial_centers_, model.n_iter_)
+def _assert_plain_lloyd(model, x, weights=None):
+    want, cost = _plain_lloyd(x, model.initial_centers_, model.n_iter_,
+                              weights)
     np.testing.assert_allclose(model.cluster_centers, want, rtol=0,
                                atol=1e-9)
     assert model.training_cost_ == pytest.approx(cost, rel=1e-9)
@@ -212,6 +218,108 @@ def test_random_init_on_an_in_memory_matrix():
         inits.append(model.initial_centers_)
     # one draw: the device and the host path start from the same rows
     np.testing.assert_array_equal(inits[0], inits[1])
+
+
+# -- one body: every input, weighted or not -----------------------------------
+
+
+def _weights(seed: int = 12):
+    """Weights 0..3 with fractions: some rows weigh nothing."""
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, 4, ROWS) * rng.uniform(0.5, 1.0, ROWS)
+
+
+def _fit_form(form, x, weights=None, **params):
+    est = KMeans().setK(K).setSeed(5).set("batchRows", BATCH)
+    for name, value in params.items():
+        est.set(name, value)
+    if form == "array":
+        return est.fit(x)
+    if form == "callable":
+        return est.fit(_factory(x))
+    frame = as_vector_frame(x, "features")
+    if weights is not None:
+        est.setWeightCol("w")
+        frame = frame.with_column("w", weights.tolist())
+    return est.fit(frame)
+
+
+@pytest.mark.parametrize("init_mode", list(kmeans_module.INIT_MODES))
+@pytest.mark.parametrize("use_xla", [True, False], ids=["xla", "host"])
+@pytest.mark.parametrize("form, weighted", [
+    ("array", False), ("frame", False), ("callable", False),
+    ("frame", True)], ids=["array", "frame", "callable", "weighted-frame"])
+def test_every_input_is_plain_lloyd_on_the_one_loop(form, weighted, use_xla,
+                                                    init_mode):
+    """An array, a frame and a chunk callable of the same rows give the
+    same model, and a frame with weightCol the weighted one: plain Lloyd —
+    weighted means, weighted cost — from the one initial draw, on both
+    planes."""
+    x = _rows(seed=9)
+    weights = _weights() if weighted else None
+    params = dict(useXlaDot=use_xla, initMode=init_mode, maxIter=4, tol=0.0)
+    model = _fit_form(form, x, weights, **params)
+    steps = model.n_iter_
+    assert 1 <= steps <= 4  # a fixed point meets tol 0 too
+    _assert_plain_lloyd(model, x, weights)
+    if weighted:  # drawn from rows with a weight
+        drawn = (model.initial_centers_[:, None] == x[None]).all(-1)
+        assert drawn.any(1).all()
+        assert weights[drawn.argmax(1)].min() > 0
+    else:
+        chunks = _fit_form("callable", x, **params)
+        np.testing.assert_array_equal(model.initial_centers_,
+                                      chunks.initial_centers_)
+        np.testing.assert_allclose(model.cluster_centers,
+                                   chunks.cluster_centers, rtol=0, atol=1e-12)
+    if use_xla:  # the streamed loop's report, whatever the input
+        assert model.fit_report_.extra["ingest"]["passes"] == 1 + steps + 1
+        assert model.fit_report_.extra["lloyd"]["steps"] == steps
+
+
+@pytest.mark.parametrize("init_mode", list(kmeans_module.INIT_MODES))
+def test_an_in_memory_matrix_draws_the_same_centres_on_both_planes(
+        init_mode):
+    x = _rows()
+    inits = [_fit_form("array", x, useXlaDot=use_xla, initMode=init_mode,
+                       maxIter=1).initial_centers_
+             for use_xla in (True, False)]
+    np.testing.assert_array_equal(inits[0], inits[1])
+
+
+def test_a_weighted_frame_walks_the_loop():
+    """``rows_put`` counts every row that crosses, padding too; the rows
+    the fit reports are those with a weight; the counts are weight sums."""
+    x, weights = _rows(), _weights()
+    model = _fit_form("frame", x, weights, maxIter=2, tol=0.0)
+    ingest = model.fit_report_.extra["ingest"]
+    walks = 1 + 2 + 1
+    assert ingest["batches"] == walks * BATCHES  # nothing kept on the CPU
+    assert ingest["rows_put"] == walks * BATCHES * BATCH
+    assert model.fit_report_.rows == np.count_nonzero(weights) < ROWS
+    assert model.fit_report_.extra["lloyd"]["batches_put_again"] == \
+        3 * BATCHES
+
+
+@pytest.mark.parametrize("form", ["array", "frame", "callable"])
+def test_an_unweighted_fit_puts_no_mask_or_a_padding_mask(monkeypatch, form):
+    masks = []
+    real = streaming.IngestTrace.put
+
+    def put(self, batch, mask, dtype):
+        masks.append(mask)
+        return real(self, batch, mask, dtype)
+
+    monkeypatch.setattr(streaming.IngestTrace, "put", put)
+    x = _rows()
+    _fit_form(form, x, maxIter=0)  # the first walk and the cost walk
+    assert len(masks) == 2 * BATCHES
+    for walk in (masks[:BATCHES], masks[BATCHES:]):
+        assert all(m is None for m in walk[:-1])
+        assert walk[-1].dtype == bool and walk[-1].sum() == ROWS % BATCH
+    masks.clear()
+    _fit_form("frame", x, _weights(), maxIter=0)
+    assert [m.dtype.kind for m in masks] == ["f"] * 2 * BATCHES
 
 
 def test_a_one_shot_iterator_still_raises():

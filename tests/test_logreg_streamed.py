@@ -7,9 +7,10 @@ The CPU reports no ``memory_stats()``, so nothing is kept here unless a
 test stands in for the chip through the seam ``streaming.keep_budget_bytes``
 (as ``tests/test_kmeans_streamed.py`` does for Lloyd): all, some or none of
 the batches kept, the fit has to be plain Newton from w = 0 — a NumPy
-float64 Newton over the same rows, step for step — and the in-memory
-program's answer. The benchmark's names (``benchmarks/work/newton.py``)
-are held against what the program emits.
+float64 Newton over the same rows, step for step. An array, a frame (with
+row weights or without) and a callable all walk that loop. The
+benchmark's names (``benchmarks/work/newton.py``) are held against what
+the program emits.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ import pytest
 
 from spark_rapids_ml_tpu import LogisticRegression, obs
 from spark_rapids_ml_tpu.data.batches import ColumnBlocks, auto_batch_rows
+from spark_rapids_ml_tpu.data.frame import as_vector_frame
 from spark_rapids_ml_tpu.models import linear_regression
 from spark_rapids_ml_tpu.models import logistic_regression as lr_module
 from spark_rapids_ml_tpu.obs import spans as obs_spans
@@ -65,18 +67,21 @@ def _fit(x, y, budget=None, monkeypatch=None, **params):
     return _estimator(**params).fit(_factory(x, y))
 
 
-def _numpy_newton(x, y, lam, steps, fit_intercept=True):
-    """``steps`` float64 Newton steps from w = 0 on Spark's objective."""
+def _numpy_newton(x, y, lam, steps, fit_intercept=True, weights=None):
+    """``steps`` float64 Newton steps from w = 0 on Spark's objective: the
+    weighted MLE where rows have ``weights`` (each row's loss times its
+    weight, over the weights' sum)."""
     n = x.shape[1]
+    v = np.ones(len(y)) if weights is None else weights
     w = np.zeros(n + 1)
     for _ in range(steps):
         p = 1.0 / (1.0 + np.exp(-(x @ w[:n] + w[n])))
-        r, s = p - y, p * (1.0 - p)
-        g = np.append(x.T @ r / len(y) + lam * w[:n], r.mean())
+        r, s = v * (p - y), v * p * (1.0 - p)
+        g = np.append(x.T @ r / v.sum() + lam * w[:n], r.sum() / v.sum())
         h = np.zeros((n + 1, n + 1))
-        h[:n, :n] = x.T @ (x * s[:, None]) / len(y) + lam * np.eye(n)
-        h[:n, n] = h[n, :n] = x.T @ s / len(y)
-        h[n, n] = s.mean()
+        h[:n, :n] = x.T @ (x * s[:, None]) / v.sum() + lam * np.eye(n)
+        h[:n, n] = h[n, :n] = x.T @ s / v.sum()
+        h[n, n] = s.sum() / v.sum()
         if not fit_intercept:
             g[n], h[:n, n], h[n, :n], h[n, n] = 0.0, 0.0, 0.0, 1.0
         w = w - np.linalg.solve(h, g)
@@ -191,6 +196,109 @@ def test_labels_are_checked_on_the_first_walk():
     three[ROWS - 5] = 2.0
     model = _fit(x, three)
     assert model.num_classes == 3
+
+
+# -- one body: every input, weighted or not -----------------------------------
+
+
+def _weights(seed: int = 12):
+    """Integer weights 0..3: some rows weigh nothing, the padded tail's
+    rows among them."""
+    return np.random.default_rng(seed).integers(0, 4, ROWS).astype(float)
+
+
+def _frame(x, y, weights=None):
+    frame = as_vector_frame(x, "features").with_column("label", y.tolist())
+    return frame if weights is None else frame.with_column(
+        "w", weights.tolist())
+
+
+def _fit_form(form, x, y, weights=None, **params):
+    est = _estimator(**params)
+    if weights is not None:
+        est.setWeightCol("w")
+    if form == "array":
+        return est.fit(x, y)
+    if form == "frame":
+        return est.fit(_frame(x, y, weights))
+    return est.fit(_factory(x, y))
+
+
+@pytest.mark.parametrize("fit_intercept", [True, False],
+                         ids=["intercept", "no-intercept"])
+@pytest.mark.parametrize("use_xla", [True, False], ids=["xla", "host"])
+@pytest.mark.parametrize("form, weighted", [
+    ("array", False), ("frame", False), ("callable", False),
+    ("frame", True)], ids=["array", "frame", "callable", "weighted-frame"])
+def test_every_input_is_plain_newton_on_the_one_loop(form, weighted,
+                                                     use_xla, fit_intercept):
+    """An array, a frame and a chunk callable of the same rows, and a frame
+    with weightCol: each the same Newton — the weighted MLE where weighted
+    — on both planes, with and without an intercept."""
+    x, y = _rows(seed=10)
+    weights = _weights() if weighted else None
+    model = _fit_form(form, x, y, weights, useXlaDot=use_xla,
+                      fitIntercept=fit_intercept)
+    assert model.n_iter_ == 6
+    coef, b = _numpy_newton(x, y, LAM, 6, fit_intercept, weights)
+    np.testing.assert_allclose(model.coefficients, coef, rtol=0, atol=1e-9)
+    assert model.intercept == pytest.approx(b, abs=1e-9)
+    if use_xla:  # the streamed loop's report, whatever the input
+        ingest = model.fit_report_.extra["ingest"]
+        assert ingest["passes"] == 6
+        assert model.fit_report_.extra["newton"]["steps"] == 6
+
+
+@pytest.mark.parametrize("use_xla", [True, False], ids=["xla", "host"])
+def test_integer_weights_are_the_rows_repeated(use_xla):
+    x, y = _rows(seed=13)
+    weights = _weights(seed=14)
+    weighted = _fit_form("frame", x, y, weights, useXlaDot=use_xla)
+    times = weights.astype(int)
+    repeated = _estimator(useXlaDot=use_xla).fit(np.repeat(x, times, 0),
+                                                 np.repeat(y, times))
+    np.testing.assert_allclose(weighted.coefficients, repeated.coefficients,
+                               rtol=0, atol=1e-10)
+    assert weighted.intercept == pytest.approx(repeated.intercept, abs=1e-10)
+
+
+def test_a_weighted_frame_walks_the_loop():
+    """``rows_put`` counts every row that crosses, padding too; the rows
+    the fit reports are those with a weight."""
+    x, y = _rows()
+    weights = _weights()
+    model = _fit_form("frame", x, y, weights, maxIter=2)
+    ingest = model.fit_report_.extra["ingest"]
+    assert ingest["batches"] == 2 * BATCHES  # nothing kept on the CPU
+    assert ingest["rows_put"] == 2 * BATCHES * BATCH
+    assert model.fit_report_.rows == np.count_nonzero(weights) < ROWS
+    assert model.fit_report_.extra["newton"]["batches_put_again"] == BATCHES
+
+
+@pytest.mark.parametrize("form", ["array", "frame", "callable"])
+def test_an_unweighted_fit_puts_no_mask_or_a_padding_mask(monkeypatch, form):
+    masks = []
+    real = streaming.IngestTrace.put
+
+    def put(self, batch, mask, dtype):
+        masks.append(mask)
+        return real(self, batch, mask, dtype)
+
+    monkeypatch.setattr(streaming.IngestTrace, "put", put)
+    x, y = _rows()
+    _fit_form(form, x, y, maxIter=1)
+    assert len(masks) == BATCHES
+    assert all(m is None for m in masks[:-1])
+    assert masks[-1].dtype == bool and masks[-1].sum() == ROWS % BATCH
+    masks.clear()
+    _fit_form("frame", x, y, _weights(), maxIter=1)
+    assert [m.dtype.kind for m in masks] == ["f"] * BATCHES
+
+
+def test_a_weighted_callable_is_still_refused():
+    x, y = _rows()
+    with pytest.raises(ValueError, match="weightCol"):
+        _estimator().setWeightCol("w").fit(_factory(x, y))
 
 
 # -- the [X | y] source ---------------------------------------------------------
@@ -395,10 +503,7 @@ def test_the_fit_emits_the_newton_spans_in_order(monkeypatch):
         assert timings[phase] > 0, phase
     newton = model.fit_report_.extra["newton"]
     assert set(newton) == {"steps", "batches_from_kept", "batches_put_again",
-                           "sync_seconds", "solve_seconds", "final_step_max",
-                           "gram_panels", "gram_work_share"}
-    # N features fit one panel: the full product
-    assert newton["gram_panels"] == 1 and newton["gram_work_share"] == 1.0
+                           "sync_seconds", "solve_seconds", "final_step_max"}
     assert newton["steps"] == 3
     assert newton["sync_seconds"] == timings[lr_module.PHASE_SYNC]
     assert 0 < newton["solve_seconds"] <= newton["sync_seconds"]
@@ -441,7 +546,8 @@ def test_no_roofline_reads_the_join_program():
 
 
 def test_the_in_memory_program_and_the_step_share_the_solve(monkeypatch):
-    """One assembly and Cholesky step, ``newton_delta``, in both."""
+    """One assembly and Cholesky step, ``newton_delta``, in the mesh fit's
+    one-program iterations and in the streamed step."""
     calls = []
     real = logreg_kernel.newton_delta
 
@@ -452,7 +558,8 @@ def test_the_in_memory_program_and_the_step_share_the_solve(monkeypatch):
     monkeypatch.setattr(logreg_kernel, "newton_delta", delta)
     x, y = _rows(seed=8)
     x, n = x[:100, :5], 5  # shapes no other test traces: both trace here
-    logreg_kernel.logreg_fit_kernel(x, y[:100], max_iter=2)
+    jax.jit(lambda x, y: logreg_kernel.newton_iterations(
+        x, y, None, 0.0, True, 2, 1e-8))(x, y[:100])
     carry = logreg_kernel.update_logreg_stats(
         logreg_kernel.init_logreg_carry(n, np.float64),
         np.column_stack([x, y[:100]]), np.zeros(n), np.float64(0.0))
@@ -463,13 +570,6 @@ def test_the_in_memory_program_and_the_step_share_the_solve(monkeypatch):
 # -- the weighted Gram's upper panels ---------------------------------------
 
 PANEL = logreg_kernel.GRAM_PANEL
-
-
-def _computed_share(n: int) -> float:
-    """The entries a panelled Gram computes, counted one by one: row i's
-    from its panel's first column on."""
-    i, j = np.indices((n, n))
-    return float(np.mean(j >= (i // PANEL) * PANEL))
 
 
 @pytest.mark.parametrize("n, masked", [
@@ -492,8 +592,9 @@ def test_the_weighted_gram_is_the_full_product_mirrored(n, masked):
     np.testing.assert_array_equal(h, h.T)  # bit for bit
     assert len(panels) == -(-n // PANEL)
     assert sum(w for _, w in panels) == n
-    assert logreg_kernel.gram_work_share(n) == pytest.approx(
-        _computed_share(n), rel=1e-12)
+    # the panels tile the columns in order, each PANEL wide but the last
+    assert [s for s, _ in panels] == list(range(0, n, PANEL))
+    assert all(w == PANEL for _, w in panels[:-1])
 
     # a batch through the step's program: padded rows (garbage behind a
     # false mask) add nothing, and the carry keeps its shapes
@@ -523,23 +624,23 @@ def test_the_weighted_gram_is_the_full_product_mirrored(n, masked):
 
 
 def test_a_wide_streamed_fit_reports_its_panels():
-    """Past one panel's width the streamed fit sums the upper panels, says
-    so, and is still the in-memory program's Newton."""
+    """Past one panel's width the step's Gram is two upper panels, one
+    product each, and the streamed fit is still plain Newton."""
     n = PANEL + 16
     rng = np.random.default_rng(11)
     x = rng.normal(size=(ROWS, n))
     y = (x @ rng.normal(size=n) / np.sqrt(n) + 0.3 * rng.normal(size=ROWS)
          > 0).astype(np.float64)
+    assert len(logreg_kernel.gram_panels(n)) == 2
+    jaxpr = jax.make_jaxpr(logreg_kernel.weighted_gram)(x, np.ones(ROWS))
+    assert len(_dot_precisions(jaxpr.jaxpr)) == 2
     streamed = _estimator(maxIter=3).fit(_factory(x, y))
-    newton = streamed.fit_report_.extra["newton"]
-    assert newton["gram_panels"] == 2
-    assert newton["gram_work_share"] == pytest.approx(_computed_share(n))
-    assert newton["gram_work_share"] < 1.0
     oneshot = _estimator(maxIter=3).fit(x, y)
     assert streamed.n_iter_ == oneshot.n_iter_ == 3
     np.testing.assert_allclose(streamed.coefficients, oneshot.coefficients,
                                rtol=0, atol=1e-10)
     assert streamed.intercept == pytest.approx(oneshot.intercept, abs=1e-10)
+    _assert_plain_newton(streamed, x, y)
 
 
 def _dot_precisions(jaxpr) -> list:
